@@ -2,8 +2,9 @@
 
 The inference-stack analogue of request batching, applied to DNS: at the
 small/medium grids that dominate parameter sweeps and optimal-perturbation
-campaigns a single 129² step fills ~4% of the chip (BENCH_FULL.json
-``rbc129.mfu``), so K independent members are stacked on a leading axis and
+campaigns a single 129² step leaves most of the chip idle (0.046 ms of
+device work per step when last recorded, 2026-07-31), so K independent
+members are stacked on a leading axis and
 advanced by ONE vmapped, jitted, chunked ``lax.scan`` dispatch.  Design
 points:
 

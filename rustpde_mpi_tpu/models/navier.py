@@ -281,9 +281,9 @@ class Navier2D(CampaignModelBase, Integrate):
         # jit with closure-converted constants: the dense transform / solver
         # matrices are hoisted out of the traced program and passed as
         # device-resident runtime arguments instead of being embedded in the
-        # HLO — at 2049^2 the embedded-constant program exceeds what the TPU
-        # compile service accepts (hundreds of MB), while the hoisted program
-        # is a few hundred KB for any grid size.
+        # HLO — at 2049^2 the embedded-constant program is hundreds of MB to
+        # parse, hash and cache, while the hoisted program is a few hundred
+        # KB for any grid size.
         self._compile_entry_points()
 
         with self._scope():
@@ -303,7 +303,8 @@ class Navier2D(CampaignModelBase, Integrate):
         routes through by space identity (None: the unfused dense chain).
 
         * no mesh + ``RUSTPDE_CONV_KERNEL=pallas``: the VMEM-tiled Pallas
-          kernel (ops/pallas_conv.py; interpreter mode off-TPU);
+          kernel (ops/pallas_conv.py; interpreted on the CPU, native on a
+          TPU or a typed refusal at build);
         * active mesh on the split-sep periodic layout (default mode
           "manual"): the manually-partitioned shard_map region
           (parallel/decomp.ShardedConv) — explicit per-pencil GEMMs +
@@ -347,7 +348,10 @@ class Navier2D(CampaignModelBase, Integrate):
         self._manual_poisson = None
         if pallas_conv.conv_kernel_choice() != "pallas":
             return None
-        return pallas_conv.build_model_convs(self)
+        specs = pallas_conv.build_model_convs(self)
+        for fc in specs.values():
+            fc.check_compiles()  # typed refusal at build on a TPU
+        return specs
 
     def _build_step_kernels(self):
         """Fused implicit-half stage kernels the step routes through
@@ -360,7 +364,10 @@ class Navier2D(CampaignModelBase, Integrate):
             return None
         if pallas_step.step_kernel_choice() != "pallas":
             return None
-        return pallas_step.build_model_step(self)
+        stages = pallas_step.build_model_step(self)
+        for stage in stages.values():
+            stage.check_compiles()  # typed refusal at build on a TPU
+        return stages
 
     def _split_sep_poisoned(self) -> bool:
         """The layout the upstream GSPMD bug miscompiles: split Re/Im
@@ -846,8 +853,9 @@ class Navier2D(CampaignModelBase, Integrate):
 
         def step(state: NavierState) -> NavierState:
             # pin the implicit-solve inputs to the spectral x-pencil layout
-            # (no-op without a mesh, and on non-divisible extents — current
-            # JAX rounds those constraints to replicated): asserts the pencil
+            # (no-op without a mesh; a non-divisible extent is padded inside
+            # the jit and only a jit OUTPUT comes back replicated,
+            # parallel/mesh.py): asserts the pencil
             # discipline at the solve boundaries so GSPMD propagation cannot
             # drift the solve internals onto other layouts on real
             # (divisible) meshes.  NOTE it does NOT cure the fused split-sep

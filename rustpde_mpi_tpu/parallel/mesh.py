@@ -88,18 +88,18 @@ def sharding(spec: tuple, ndim: int | None = None) -> NamedSharding | None:
     return pencil_sharding(mesh, spec, ndim)
 
 
-# Small arrays whose sharded dim does not divide the mesh are PLACED fully
-# replicated instead of being left uncommitted: a tiny parameter (the
-# f64[9,17] `pres` in MULTICHIP_r05.json) that enters a dispatch with a
-# leftover compiler-chosen partial sharding (e.g. [2,1,4]
-# last_tile_dim_replicate) that the executable's parameter layout cannot
-# consume forces an "[SPMD] Involuntary full rematerialization" — a full
-# replicate-then-repartition on EVERY dispatch.  An explicitly replicated
-# input is the one layout every executable can consume with at worst a
-# local slice.  Large non-divisible arrays (the odd spectral sizes 129,
-# 1025, ...) are still left to the in-jit padded constraints — replication
-# there would be real memory.
-REPLICATE_MAX_ELEMS = 1 << 14
+# Arrays whose sharded dim does not divide the mesh are PLACED fully
+# replicated over the whole mesh, and a warning says so.  JAX cannot hold an
+# unevenly sharded array between dispatches: device_put rejects the layout,
+# and a jit output whose constraint does not divide comes back replicated
+# (the pencils are cut, padded, INSIDE the step — measured on 4 devices at
+# 1025^2: f32[257,1025] dots, all-gathers at the flips, replicated result).
+# Committing the replicated layout from construction is therefore the steady
+# state made explicit: nothing sits uncommitted on device 0, and no
+# executable has to repartition a leftover compiler-chosen partial sharding
+# (the "[SPMD] Involuntary full rematerialization" a 17^2 `pres` on 8 devices
+# used to trigger on every dispatch).  Distributing such a state for real
+# needs padded storage (1023 -> 1024 columns); ROADMAP Queue 1 item 5.
 
 
 def constrain(x, spec: tuple):
@@ -110,12 +110,11 @@ def constrain(x, spec: tuple):
     Arrays with more dims than the spec treat the extra leading dims as
     replicated batch.
 
-    NOTE in-jit constraints deliberately do NOT take the small-array
-    replicated pin below: the pencil-flip constraint pattern inside the
-    transforms is what the serial==sharded 1e-12 equality tests validate,
-    and rewriting it for small grids changes GSPMD's fusion choices (the
-    17^2/33x32 sharded test grids all sit under any useful size
-    threshold).  Only EAGER placement (``device_put``) canonicalizes."""
+    NOTE in-jit constraints deliberately do NOT take the replicated pin of
+    ``device_put``: inside a jit a non-divisible constraint pads, and the
+    pencil-flip constraint pattern inside the transforms is what the
+    serial==sharded 1e-12 equality tests validate.  Only EAGER placement
+    (``device_put``) canonicalizes."""
     s = sharding(spec, np.ndim(x))
     if s is None:
         return x
@@ -124,32 +123,22 @@ def constrain(x, spec: tuple):
     return device_put(x, spec)
 
 
-_TRACER_TYPE = getattr(jax.core, "Tracer", None)  # deprecated home; may vanish
-
-
 def _is_tracer(x) -> bool:
-    if _TRACER_TYPE is not None:
-        return isinstance(x, _TRACER_TYPE)
-    # fallback for JAX releases that drop jax.core.Tracer: concrete arrays
-    # expose addressable shards, while a tracer's accessor raises (a
-    # ConcretizationTypeError, i.e. TypeError — hasattr doesn't swallow it)
-    if not isinstance(x, jax.Array):
-        return False
-    try:
-        x.addressable_shards
-    except Exception:
-        return True
-    return False
+    return isinstance(x, jax.core.Tracer)
+
+
+class ReplicatedPencilWarning(UserWarning):
+    """A pencil-sharded placement fell back to full replication because the
+    sharded extent does not divide the mesh (see ``device_put``)."""
 
 
 def device_put(x, spec: tuple):
     """Place an array in pencil layout (host->device with sharding).
 
     Spectral grid sizes are typically odd (129, 1025, ...), so sharded dims
-    are often not divisible by the mesh.  Explicit placement (device_put /
-    out_shardings) rejects that in JAX; only in-jit sharding constraints pad.
-    Non-divisible arrays are therefore left as-is here — the constraints
-    inside the first jitted step distribute them."""
+    are often not divisible by the mesh, which explicit placement rejects.
+    Such an array is committed REPLICATED over the whole mesh instead (see
+    the note above) with a one-time warning per shape."""
     mesh = active_mesh()
     if mesh is None:
         return x
@@ -165,11 +154,15 @@ def device_put(x, spec: tuple):
     )
     if divisible:
         return jax.device_put(arr, s)
-    if arr.size <= REPLICATE_MAX_ELEMS:
-        # explicit replication is always a legal placement; it also matches
-        # the in-jit constraint for the same array (see constrain), so no
-        # executable ever has to repartition it involuntarily
-        return jax.device_put(
-            arr, NamedSharding(mesh, PartitionSpec(*([None] * arr.ndim)))
-        )
-    return arr
+    import warnings
+
+    warnings.warn(
+        f"pencil layout {tuple(s.spec)} does not divide shape {arr.shape} "
+        f"over {mesh.size} devices: the array is kept REPLICATED on every "
+        "device between dispatches (pencils are cut inside the step)",
+        ReplicatedPencilWarning,
+        stacklevel=2,
+    )
+    return jax.device_put(
+        arr, NamedSharding(mesh, PartitionSpec(*([None] * arr.ndim)))
+    )

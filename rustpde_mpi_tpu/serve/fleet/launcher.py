@@ -128,9 +128,10 @@ class LocalProcessLauncher(ReplicaLauncher):
         # replicas must share the fleet's persistent compile cache: a
         # scale-out spawn then deserializes the executables peers already
         # built instead of recompiling them (cold-start elimination) —
-        # seed the arming vars into any custom ``env`` snapshot that lacks
-        # them (an env=None copy of os.environ already carries them when
-        # the parent armed the cache before constructing the launcher)
+        # seed this process's cache variables into any custom ``env``
+        # snapshot that lacks them (an env=None copy of os.environ already
+        # carries them; with none set, parent and child share the
+        # repo-fixed default directory)
         from ... import config as _config
 
         for name, val in _config.compile_cache_env().items():

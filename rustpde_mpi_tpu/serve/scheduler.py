@@ -110,6 +110,11 @@ from .fleet.gang import GangMemberLost
 from .queue import DurableQueue
 from .request import AdmissionError, RequestFailed, SimRequest
 
+_MFU_HELP = (
+    "model-flops utilization per compat bucket, against the bf16 peak of the "
+    "attached device_kind"
+)
+
 
 class _ServedEnsemble(NavierEnsemble):
     """Ensemble whose checkpoints are self-describing for the scheduler:
@@ -286,6 +291,7 @@ class SimServer:
         self._slots_state: tuple[int, int] = (0, int(self.cfg.slots))
         self._rate_mark: tuple[float, int] = (time.monotonic(), 0)
         self._flops_member: float | None = None
+        self._peak_flops: float | None = None
         # compile/device attribution bookkeeping (telemetry/compile_log):
         # the active bucket's label, the campaign-open stamp the
         # time-to-first-chunk histogram measures from, and its one-shot flag
@@ -774,12 +780,11 @@ class SimServer:
         ``plan.place``); an integrity quarantine drops the cached plan
         (:meth:`_contain_integrity`) to force the same re-carve."""
         if self._submesh_plan is None:
-            try:
-                import jax
+            import jax
 
-                devices = jax.devices()
-            except Exception:
-                devices = []
+            # a backend that will not initialise is fatal here: carving an
+            # empty fleet would let the service start with nothing to run on
+            devices = jax.devices()
             bad = self._quarantined_devices()
             if bad and devices:
                 keep = [
@@ -909,6 +914,13 @@ class SimServer:
         # recompiling the fleet from scratch (RUSTPDE_COMPILE_CACHE=0 opts
         # out; see config.ensure_compile_cache)
         _config.ensure_compile_cache()
+        # the serving process takes its device(s) NOW: a server that cannot
+        # have one (the host's chips belong to another process) must fail
+        # here, in seconds and in the backend's own words — not after it
+        # has claimed a lease whose requests then wait out the TTL
+        import jax
+
+        devices = jax.devices()
         self._install_signals()
         if root:
             self._start_http()
@@ -927,6 +939,9 @@ class SimServer:
                 "slots": self.cfg.slots,
                 "max_queue": self.cfg.max_queue,
                 "processes": self._nproc(),
+                "platform": devices[0].platform,
+                "device_kind": devices[0].device_kind,
+                "device_count": len(devices),
                 "recovered": recovered,
                 "unclean_shutdown": unclean,
                 "replica": self._replica_id or None,
@@ -1521,11 +1536,17 @@ class SimServer:
                 }
             )
         # per-member step flops for the live MFU gauge: the trace-only jaxpr
-        # dot count (no extra compile; the entry points were just built)
-        try:
-            from ..utils.profiling import step_flops
+        # dot count (no extra compile; the entry points were just built).
+        # The gauge divides by the published bf16 peak of the attached
+        # device_kind; a chip without a table entry leaves it UNSET.
+        from ..utils import profiling
 
-            self._flops_member = step_flops(model, method="jaxpr")
+        try:
+            self._peak_flops = profiling.device_peak().bf16_flops
+        except profiling.UnknownDevicePeak:
+            self._peak_flops = None
+        try:
+            self._flops_member = profiling.step_flops(model, method="jaxpr")
         except Exception:
             self._flops_member = None
         rcfg = self.cfg.resilience
@@ -1646,6 +1667,10 @@ class SimServer:
                         "restored": runner.resumed,
                         "fleet": ens.k,
                         "slots_restored": sum(1 for s in slots if s.running),
+                        # where the ensemble state actually lives: a
+                        # campaign that landed on the wrong backend is
+                        # visible in the journal, not inferred
+                        "devices": self._state_devices(ens),
                     }
                 )
                 self._fill_slots(runner, ens, slots, key)
@@ -1704,11 +1729,12 @@ class SimServer:
             # (a labeled gauge left at its last in-flight value would read
             # as phantom utilization on every later scrape)
             _rt.clear_active()
-            _tm.gauge(
-                "serve_mfu",
-                "model-flops utilization per compat bucket",
-                bucket=self._bucket_tag,
-            ).set(0.0)
+            if self._peak_flops:
+                _tm.gauge(
+                    "serve_mfu",
+                    _MFU_HELP,
+                    bucket=self._bucket_tag,
+                ).set(0.0)
             _tm.gauge(
                 "serve_fleet_utilization",
                 "running-slot fraction of the fleet (0 between campaigns)",
@@ -1859,11 +1885,12 @@ class SimServer:
         info, self._gang_active = self._gang_active, None
         if self._fault is not None:
             self._fault.bind_gang(None, None)
-        _tm.gauge(
-            "serve_gang_mfu",
-            "model-flops utilization per gang sub-mesh",
-            gang=str(info["gang"]),
-        ).set(0.0)
+        if self._peak_flops:
+            _tm.gauge(
+                "serve_gang_mfu",
+                "model-flops utilization per gang sub-mesh",
+                gang=str(info["gang"]),
+            ).set(0.0)
         if self._fleet is None:
             return
         from .fleet.lease import LeaseLost
@@ -2457,6 +2484,14 @@ class SimServer:
                 }
             )
 
+    @staticmethod
+    def _state_devices(ens) -> list:
+        """``platform:id`` of every device holding the ensemble state."""
+        import jax
+
+        leaf = jax.tree.leaves(ens.state)[0]
+        return sorted(f"{d.platform}:{d.id}" for d in leaf.sharding.device_set)
+
     def _boundary_gauges(self) -> None:
         """Refresh the live queue/throughput gauges at one chunk boundary —
         host-side bookkeeping the scheduler already holds (slot occupancy
@@ -2476,15 +2511,11 @@ class SimServer:
                 "serve_member_steps_per_sec",
                 "aggregate member-steps/s across running slots",
             ).set(rate)
-            if self._flops_member:
-                from ..utils.profiling import PEAK_FLOPS, peak_flops_key
-
-                mfu = (
-                    self._flops_member * rate / PEAK_FLOPS[peak_flops_key()]
-                )
+            if self._flops_member and self._peak_flops:
+                mfu = self._flops_member * rate / self._peak_flops
                 _tm.gauge(
                     "serve_mfu",
-                    "model-flops utilization per compat bucket",
+                    _MFU_HELP,
                     bucket=self._bucket_tag,
                 ).set(mfu)
                 if self._gang_active is not None:

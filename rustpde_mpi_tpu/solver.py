@@ -243,7 +243,7 @@ class _AxisSolver:
 
 def default_method() -> str:
     """Execution path for the 1-D axis solves.  Measured on v5e at the
-    1025^2 shapes (ops/pallas_banded.bench_banded_paths, BASELINE.md): the
+    1025^2 shapes (ops/pallas_banded.bench_banded_paths, 2026-07): the
     precomputed dense-inverse GEMM (~1.10 ms/solve fused) beats both the
     Pallas VMEM recurrence (~1.38 ms) and by 3 orders of magnitude the
     lax.scan substitution — the MXU wins despite O(n/(p+q)) more flops.  The

@@ -3,8 +3,10 @@
 The spectral framework's jitted programs close over large dense operator
 matrices (transforms, solver factorizations).  Tracing embeds those as HLO
 literals, which (a) bloats the serialized program to O(n^2) per matrix —
-~900 MB at 2049^2, more than the TPU compile service accepts — and (b)
-re-uploads them on every recompile.  ``hoist_constants`` converts a closure
+~900 MB at 2049^2 that every compile has to parse, constant-fold, hash for
+the persistent cache and carry inside the executable — and (b) re-uploads
+them on every recompile, while (c) the ensemble engine needs the SAME
+constants shared by every vmapped member.  ``hoist_constants`` converts a closure
 into an equivalent function taking the captured constants as explicit
 device-resident arguments: trace once with ``make_jaxpr``, then replay the
 jaxpr with ``eval_jaxpr`` feeding the constants as parameters.

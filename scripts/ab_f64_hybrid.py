@@ -29,9 +29,6 @@ sys.path.insert(0, REPO)
 PARITY_CHILD = r"""
 import json, os, sys
 sys.path.insert(0, %(repo)r)
-import jax
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
 from rustpde_mpi_tpu import Navier2D, config
 config.enable_compilation_cache()
 model = Navier2D(129, 129, 1e7, 1.0, 2e-3, 1.0, "rbc", periodic=False)

@@ -40,7 +40,6 @@ import json, os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, %(repo)r)
 import jax
-jax.config.update("jax_platforms", "cpu")
 from rustpde_mpi_tpu import Navier2D
 
 cfg = json.loads(%(cfg)r)

@@ -448,7 +448,6 @@ import json, os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, %(repo)r)
 import jax
-jax.config.update("jax_platforms", "cpu")
 from rustpde_mpi_tpu.workloads import solo_ensemble_parity
 print("WORKLOADS_JSON " + json.dumps(solo_ensemble_parity(steps=6)))
 """
@@ -503,7 +502,6 @@ os.environ.setdefault("RUSTPDE_X64", "1")
 sys.path.insert(0, %(repo)r)
 import numpy as np
 import jax
-jax.config.update("jax_platforms", "cpu")
 import jax.numpy as jnp
 from rustpde_mpi_tpu.bases import (
     Space2, cheb_dirichlet, chebyshev, fourier_r2c, fourier_r2c_split,
@@ -556,7 +554,6 @@ os.environ.setdefault("RUSTPDE_X64", "1")
 sys.path.insert(0, %(repo)r)
 import numpy as np
 import jax
-jax.config.update("jax_platforms", "cpu")
 import rustpde_mpi_tpu as rp
 
 def build(periodic, nx, ny, kernel):
@@ -621,7 +618,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, %(repo)r)
 import numpy as np
 import jax
-jax.config.update("jax_platforms", "cpu")
 from rustpde_mpi_tpu import Navier2D, Statistics
 from rustpde_mpi_tpu.config import StatsConfig
 
@@ -665,7 +661,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("RUSTPDE_X64", "1")
 sys.path.insert(0, %(repo)r)
 import jax
-jax.config.update("jax_platforms", "cpu")
 from rustpde_mpi_tpu import Navier2D, ResilientRunner, telemetry
 from rustpde_mpi_tpu.config import StabilityConfig
 

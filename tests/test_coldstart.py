@@ -41,7 +41,6 @@ _CACHE_VARS = (
     "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES",
     "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
     "RUSTPDE_COMPILE_CACHE",
-    "RUSTPDE_COMPILE_CACHE_DIR",
 )
 
 
@@ -80,14 +79,17 @@ def _cfg(tmp_path, **kw):
 def test_ensure_compile_cache_arms_once(tmp_path, cache_env):
     config._cache_armed = None
     os.environ.pop("RUSTPDE_COMPILE_CACHE", None)
-    os.environ["RUSTPDE_COMPILE_CACHE_DIR"] = str(tmp_path / "cache")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
     first = config.ensure_compile_cache()
+    # the directory is placed from OUTSIDE: the variable, when set, IS the
+    # directory, and arming leaves it exactly as it found it
     assert first == str(tmp_path / "cache")
     assert os.environ["JAX_COMPILATION_CACHE_DIR"] == first
     # idempotent: the second call returns the latched path without
-    # re-reading the knobs (a changed dir mid-process must not re-arm)
-    os.environ["RUSTPDE_COMPILE_CACHE_DIR"] = str(tmp_path / "elsewhere")
+    # re-reading the environment (a changed dir mid-process must not re-arm)
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "elsewhere")
     assert config.ensure_compile_cache() == first
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == str(tmp_path / "elsewhere")
 
 
 def test_ensure_compile_cache_knob_off_is_inert(tmp_path, cache_env):
@@ -102,29 +104,32 @@ def test_ensure_compile_cache_knob_off_is_inert(tmp_path, cache_env):
 def test_compile_cache_env_snapshot(tmp_path, cache_env):
     config._cache_armed = None
     os.environ.pop("RUSTPDE_COMPILE_CACHE", None)
-    os.environ["RUSTPDE_COMPILE_CACHE_DIR"] = str(tmp_path / "cache")
-    config.ensure_compile_cache()
+    # unset: the repo-fixed default, and arming exports nothing
+    os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+    assert config.ensure_compile_cache() == os.path.join(_REPO, ".jax_cache")
+    assert "JAX_COMPILATION_CACHE_DIR" not in config.compile_cache_env()
+    assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
+    # set: the snapshot carries it so a child resolves the same directory
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    os.environ["RUSTPDE_COMPILE_CACHE"] = "1"
     env = config.compile_cache_env()
     assert env["JAX_COMPILATION_CACHE_DIR"] == str(tmp_path / "cache")
-    assert "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES" in env
+    assert env["RUSTPDE_COMPILE_CACHE"] == "1"
 
 
 def test_launcher_seeds_cache_env_into_custom_snapshot(tmp_path, cache_env):
     from rustpde_mpi_tpu.serve.fleet.launcher import LocalProcessLauncher
 
-    config._cache_armed = None
-    os.environ.pop("RUSTPDE_COMPILE_CACHE", None)
-    os.environ["RUSTPDE_COMPILE_CACHE_DIR"] = str(tmp_path / "cache")
-    armed = config.ensure_compile_cache()
-    # a custom env snapshot missing the arming vars gets them seeded, so
-    # every spawned replica shares the fleet cache; an explicit value in
-    # the snapshot wins (setdefault)
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    # a custom env snapshot missing the cache variables gets them seeded
+    # from this process's environment, so every spawned replica shares the
+    # fleet cache; an explicit value in the snapshot wins (setdefault)
     launcher = LocalProcessLauncher(
         str(tmp_path / "fleet"),
         env={"PATH": os.environ.get("PATH", ""),
              "RUSTPDE_COMPILE_CACHE": "0"},
     )
-    assert launcher.env["JAX_COMPILATION_CACHE_DIR"] == armed
+    assert launcher.env["JAX_COMPILATION_CACHE_DIR"] == str(tmp_path / "cache")
     assert launcher.env["RUSTPDE_COMPILE_CACHE"] == "0"
 
 
@@ -514,7 +519,7 @@ def test_restarted_server_boots_warm_from_shared_cache(tmp_path):
         **os.environ,
         "JAX_PLATFORMS": "cpu",
         "RUSTPDE_COMPILE_CACHE": "1",
-        "RUSTPDE_COMPILE_CACHE_DIR": cache,
+        "JAX_COMPILATION_CACHE_DIR": cache,
         "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
     }
 

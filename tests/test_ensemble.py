@@ -145,8 +145,9 @@ def test_profiling_reports_member_rate_and_mfu():
     assert res["ensemble_size"] == 2
     assert res["member_steps_per_sec"] == pytest.approx(2 * res["steps_per_sec"])
     # ensemble step FLOPs carry the K factor (vmapped batched dot_generals)
-    solo_flops = mfu_estimate(_model(), 1.0)["flops_per_step"]
-    ens_flops = mfu_estimate(ens, 1.0)["flops_per_step"]
+    kind = "TPU v5 lite"  # the CPU has no peak-table entry
+    solo_flops = mfu_estimate(_model(), 1.0, device_kind=kind)["flops_per_step"]
+    ens_flops = mfu_estimate(ens, 1.0, device_kind=kind)["flops_per_step"]
     assert ens_flops == pytest.approx(2 * solo_flops, rel=0.05)
 
 

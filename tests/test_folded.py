@@ -106,7 +106,7 @@ def test_space_transform_equivalence_folded_vs_plain(monkeypatch):
 import os, sys, json
 os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, %r)
-import jax; jax.config.update("jax_platforms", "cpu")
+import jax
 import numpy as np, jax.numpy as jnp
 from rustpde_mpi_tpu import Space2, cheb_dirichlet, cheb_neumann
 space = Space2(cheb_dirichlet(17), cheb_neumann(16), method="matmul")

@@ -166,7 +166,7 @@ def test_navier_step_fast_vs_dense_transforms():
 import os, sys, json
 os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, %r)
-import jax; jax.config.update("jax_platforms", "cpu")
+import jax
 import numpy as np
 from rustpde_mpi_tpu import Navier2D
 m = Navier2D.new_confined(33, 33, 1e6, 1.0, 1e-3, 1.0, "rbc")

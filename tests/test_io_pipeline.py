@@ -324,7 +324,6 @@ import os, sys
 sys.path.insert(0, {repo!r})
 os.environ["RUSTPDE_X64"] = "1"
 import jax
-jax.config.update("jax_platforms", "cpu")
 from rustpde_mpi_tpu import Navier2D, ResilientRunner
 from rustpde_mpi_tpu.utils import checkpoint as cp
 

@@ -4,7 +4,7 @@ PARITY.json (written by scripts/record_parity.py) holds the f64 golden
 Nusselt trajectory for the reference's flagship config
 (/root/reference/src/main.rs:37-58: confined RBC 129^2, Ra=1e7, dt=2e-3) and
 the recorded f32-vs-f64 drift.  This test re-runs the head of that trajectory
-and asserts reproduction to the 1e-6 parity tolerance (BASELINE.md
+and asserts reproduction to the 1e-6 parity tolerance (BASELINE.json
 north-star), making parity a number the suite enforces rather than an
 aspiration.
 """

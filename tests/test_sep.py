@@ -199,7 +199,6 @@ def test_periodic_model_forced_sep_matches_default():
 
     code = (
         "import jax, json\n"
-        "jax.config.update('jax_platforms', 'cpu')\n"
         "from rustpde_mpi_tpu import Navier2D\n"
         "m = Navier2D.new_periodic(16, 17, 1e4, 1.0, 1e-2, 1.0, 'rbc')\n"
         "import sys; print('sep', m.temp_space.sep, file=sys.stderr)\n"

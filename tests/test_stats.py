@@ -241,7 +241,6 @@ sys.path.insert(0, {repo!r})
 os.environ["RUSTPDE_X64"] = "1"
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
-jax.config.update("jax_platforms", "cpu")
 from rustpde_mpi_tpu import Navier2D, ResilientRunner, config
 from rustpde_mpi_tpu.config import StatsConfig
 config.enable_compilation_cache()

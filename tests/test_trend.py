@@ -70,13 +70,13 @@ def test_trend_flags_synthetic_regression_and_ack_clears_it(tmp_path):
     # acked with a reason: the gate passes, the ack is recorded in TREND.json
     proc = _run(
         ["--repo", str(tmp_path), "--out", out, "--json", "--gate",
-         "--ack", "rbc129", "--reason", "relay slowdown, tracked upstream"]
+         "--ack", "rbc129", "--reason", "shared-host slowdown, tracked upstream"]
     )
     assert proc.returncode == 0, proc.stderr
     payload = json.loads(proc.stdout)
     assert payload["regressions"] == ["rbc129"]
     assert payload["regressions_unacked"] == []
-    assert payload["acks"]["rbc129"]["reason"].startswith("relay slowdown")
+    assert payload["acks"]["rbc129"]["reason"].startswith("shared-host slowdown")
 
     # the ack persists across runs (it lives inside TREND.json)...
     proc = _run(["--repo", str(tmp_path), "--out", out, "--json", "--gate"])
@@ -142,16 +142,14 @@ def test_trend_recovers_final_json_line_from_tail(tmp_path):
 
 
 def test_trend_real_repo_history_parses_clean(tmp_path):
-    """The acceptance criterion: the checked-in BENCH_r01–r05 +
-    BENCH_FULL history produces a TREND.json (written to a scratch path —
-    the committed artifact is regenerated by record_tests.py)."""
+    """Whatever bench history the checkout holds — none is committed since
+    PR 21 (the driver's PERF_LEDGER.jsonl is the record; bench.py's
+    BENCH_FULL.json is a run-time artifact) — parses clean into a
+    TREND.json (written to a scratch path) with no un-acked regression."""
     out = str(tmp_path / "TREND.json")
     proc = _run(["--json", "--out", out])
     assert proc.returncode == 0, proc.stderr
     payload = json.loads(proc.stdout)
-    # the known rounds parse: the flagship trajectory spans r01/r02 and
-    # BENCH_FULL contributes the per-config points
-    assert "flagship" in payload["configs"]
-    assert len(payload["configs"]) >= 5
+    assert isinstance(payload["configs"], dict)
     assert payload["regressions_unacked"] == []
     assert os.path.exists(out)

@@ -1,6 +1,7 @@
 """Config dataclass, diagnostics map, profiling API, BC helper coverage."""
 
 import numpy as np
+import pytest
 
 from rustpde_mpi_tpu import Navier2D
 from rustpde_mpi_tpu.config import NavierConfig
@@ -10,6 +11,7 @@ from rustpde_mpi_tpu.models.boundary_conditions import (
 )
 from rustpde_mpi_tpu.utils.profiling import (
     StepTimer,
+    UnknownDevicePeak,
     benchmark_steps,
     mfu_estimate,
     step_flops,
@@ -49,8 +51,13 @@ def test_benchmark_steps_and_mfu():
     assert res["ms_per_step"] > 0
     flops = step_flops(m)
     assert flops and flops > 1e5
-    mfu = mfu_estimate(m, res["steps_per_sec"])
-    assert 0 < mfu["mfu"] < 1.5  # sane fraction of assumed peak
+    # the CPU is not in the peak table: an error, never a default
+    with pytest.raises(UnknownDevicePeak):
+        mfu_estimate(m, res["steps_per_sec"])
+    mfu = mfu_estimate(m, res["steps_per_sec"], device_kind="TPU v5 lite")
+    assert mfu["peak_flops"] == 197e12 and "TPU v5e" in mfu["peak_source"]
+    assert mfu["peak"] == "bf16" and mfu["device_kind"] == "TPU v5 lite"
+    assert mfu["mfu"] == pytest.approx(flops * res["steps_per_sec"] / 197e12)
 
 
 def test_step_timer():
