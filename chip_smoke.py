@@ -11,10 +11,13 @@ own means.  It fails when JAX finds no TPU; there is no CPU fallback.
 
 The parent imports neither jax nor rustpde_mpi_tpu: one process per chip.
 It runs two children one after the other (precision is an import-time
-switch), fails if either fails, and prints ONE JSON object as the last line
-of its standard output.  Any time in it is a SMOKE timing: compile
-included or apart as labelled, one reading, never a benchmark result.
-Details (the full JSON, each child's stderr) land in ``chiprun_out/``.
+switch) and fails if either fails.  Its standard output is two lines: the
+report (``{"report": {...}}``: versions, cache directory, every leg), then
+as the LAST line the verdict, exactly
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}``.
+Any time in the report is a SMOKE timing: compile included or apart as
+labelled, one reading, never a benchmark result.  A copy of the report and
+each child's stderr land in ``chiprun_out/``.
 
 The legs are plain functions with size arguments so that a builder, or
 tests/test_chip_smoke.py, can call them at 17^2 on the CPU; the script
@@ -508,9 +511,9 @@ def main() -> int:
         for leg in child["legs"].values()
     ) and not any(c["rematerialization_on_stderr"] for c in children.values())
     first = children["f32"]
-    result = {
-        "ok": bool(ok),
-        "device": first["device"],
+    verdict = {"ok": bool(ok), "device": first["device"]}
+    report = {
+        **verdict,
         "versions": first["versions"],
         "compile_cache_dir": first["compile_cache_dir"],
         "timings": "smoke timings: one reading each, never a benchmark result",
@@ -520,8 +523,11 @@ def main() -> int:
         "wall_s": round(time.monotonic() - t0, 1),
     }
     with open(os.path.join(_OUT, "chip_smoke.json"), "w", encoding="utf-8") as fh:
-        json.dump(result, fh, indent=1)
-    print(json.dumps(result))
+        json.dump(report, fh, indent=1)
+    # the report first; the LAST line is the verdict and holds exactly
+    # {"ok", "device": {"platform", "kind", "count"}} — the driver parses it
+    print(json.dumps({"report": report}))
+    print(json.dumps(verdict), flush=True)
     return 0 if ok else 1
 
 

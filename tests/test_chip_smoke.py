@@ -3,6 +3,7 @@ non-zero exit that names the platform and prints no result, the parent
 holds no backend, the leg functions run (at 17^2) and gate what they say
 they gate, and the compile cache is placed from outside."""
 
+import json
 import os
 import subprocess
 import sys
@@ -41,6 +42,28 @@ def test_no_chip_fails_fast_and_parent_stays_off_jax():
     # the only stdout line is this test's own probe: no result was printed
     assert proc.stdout.split() == ["PARENT", "3", "False", "False"]
     assert "platform is 'cpu'" in proc.stderr and "not 'tpu'" in proc.stderr
+
+
+@pytest.mark.parametrize("passed", [True, False])
+def test_last_stdout_line_is_exactly_the_verdict(monkeypatch, tmp_path, capsys, passed):
+    # the driver parses the last line and refuses any key beyond these
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+
+    def child(precision, deadline):
+        legs = {"parity": {"passed": passed}}
+        if precision == "f32":
+            legs["mesh"] = {"skipped": "1 device"}
+        return {"precision": precision, "device": device, "versions": {},
+                "compile_cache_dir": "x", "legs": legs,
+                "rematerialization_on_stderr": False}
+
+    monkeypatch.setattr(chip_smoke, "_run_child", child)
+    monkeypatch.setattr(chip_smoke, "_OUT", str(tmp_path))
+    assert chip_smoke.main() == (0 if passed else 1)
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert json.loads(lines[-1]) == {"ok": passed, "device": device}
+    report = json.loads(lines[-2])["report"]
+    assert report["mesh"] == {"skipped": "1 device"} and "f64" in report
 
 
 def test_parity_leg_gates_on_the_reference(meter):
