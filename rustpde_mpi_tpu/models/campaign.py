@@ -53,6 +53,58 @@ from __future__ import annotations
 import numpy as np
 
 from .. import config
+from ..telemetry import tracing as _tr
+
+
+class DispatchSpans:
+    """The host seams of one ``update_n`` call, as spans of ``layer``
+    (PERF.md section 3): ``<prefix>.update_n`` around the call, inside it
+    ``<prefix>.carry_copy`` around the eager per-leaf copy of the carry (the
+    chunk donates its input) and one ``<prefix>.launch`` per bucket that
+    ``run_scanned`` dispatches.  The outer span's ``launches`` counts the
+    device programs the call enqueued: leaves copied plus buckets launched.
+    ``prefix`` is ``model`` here and ``ensemble`` in models/ensemble.py."""
+
+    def __init__(self, prefix: str, layer: str, **args):
+        self.prefix, self.layer, self.launches = prefix, layer, 0
+        self._span = _tr.span(prefix + ".update_n", layer=layer, **args)
+
+    def __enter__(self):
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self._span.set(launches=self.launches)
+        return self._span.__exit__(exc_type, exc, tb)
+
+    def copy(self, carry):
+        """``jax.tree.map(jnp.copy, carry)`` under the ``carry_copy`` span."""
+        import jax
+        import jax.numpy as jnp
+
+        with _tr.span(self.prefix + ".carry_copy", layer=self.layer) as sp:
+            leaves, treedef = jax.tree.flatten(carry)
+            out = treedef.unflatten([jnp.copy(leaf) for leaf in leaves])
+            sp.set(leaves=len(leaves))
+        self.launches += len(leaves)
+        return out
+
+    def launcher(self, step_n, aot=()):
+        """``step_n`` as ``run_scanned`` calls it, each bucket under a
+        ``launch`` span; ``aot`` holds the bucket sizes a prebuilt
+        executable serves."""
+
+        def launch(carry, k):
+            self.launches += 1
+            with _tr.span(
+                self.prefix + ".launch", layer=self.layer, steps=int(k), aot=int(k) in aot
+            ):
+                return step_n(carry, k)
+
+        return launch
+
+
+_LAYER = "model step"
 
 #: the attribute surface the workloads registry validates a campaign model
 #: against (see module docstring) — kept as data so the check and the docs
@@ -556,30 +608,31 @@ class CampaignModelBase:
         still finite, the chunk is rolled back in memory and ``exit()``
         latches True until a governor acknowledges
         (:meth:`clear_pre_divergence`)."""
-        import jax
-        import jax.numpy as jnp
-
         from ..utils.jit import run_scanned
 
         if self._step_n_sent is not None:
             return self._update_n_sentinel(n)
-        with self._scope():
+        with DispatchSpans("model", _LAYER, steps=int(n)) as seams, self._scope():
             # the chunked dispatch donates its input buffers; hand it a copy
             # so a state reference the caller retained stays readable, while
             # every inter-bucket hand-off inside the chain is donated
-            state = jax.tree.map(jnp.copy, self.state)
             if self._step_n_stats is not None:
-                ss = jax.tree.map(jnp.copy, self.stats_state)
-                tick = jnp.copy(self._stats_tick)
+                carry = seams.copy((self.state, self.stats_state, self._stats_tick))
                 st, ss, tick = run_scanned(
-                    lambda c, k: self._step_n_stats(c[0], c[1], c[2], k)[:3],
-                    (state, ss, tick),
+                    seams.launcher(
+                        lambda c, k: self._step_n_stats(c[0], c[1], c[2], k)[:3]
+                    ),
+                    carry,
                     n,
                 )
                 self.state, self.stats_state, self._stats_tick = st, ss, tick
             else:
                 self.state = run_scanned(
-                    lambda s, k: self._step_n(s, k)[0], state, n
+                    seams.launcher(
+                        lambda s, k: self._step_n(s, k)[0], aot=self._aot_step_n
+                    ),
+                    seams.copy(self.state),
+                    n,
                 )
         self.time += n * self.dt
         return None
@@ -599,7 +652,6 @@ class CampaignModelBase:
         advance or restores the chunk-start snapshot (+ latches ``exit()``)
         — exactly the synchronous :meth:`update_n` outcome, decided one host
         round-trip later."""
-        import jax
         import jax.numpy as jnp
 
         from ..utils.governor import ChunkStatus
@@ -614,10 +666,17 @@ class CampaignModelBase:
         self._pre_div_latch = False
         rdt = config.real_dtype()
         stats_on = self._stats_cc is not None
-        with self._scope():
-            state = jax.tree.map(jnp.copy, self.state)
+        with DispatchSpans("model", _LAYER, steps=int(n)) as seams, self._scope():
+            # the running sums + tick ride the sentinel carry (and the
+            # rollback snapshot below — a tripped chunk's samples are
+            # discarded with its steps)
+            copied = seams.copy(
+                (self.state, self.stats_state, self._stats_tick)
+                if stats_on
+                else (self.state,)
+            )
             carry = (
-                state,
+                copied[0],
                 jnp.asarray(True),
                 jnp.asarray(True),
                 jnp.asarray(0, jnp.int32),
@@ -625,16 +684,10 @@ class CampaignModelBase:
                 jnp.asarray(0.0, rdt),  # ke growth max
                 jnp.asarray(0.0, rdt),  # |div| max
                 jnp.asarray(0.0, rdt),  # previous-step ke
+            ) + copied[1:]
+            carry = run_scanned(
+                seams.launcher(lambda c, k: self._step_n_sent(c, k)), carry, n
             )
-            if stats_on:
-                # the running sums + tick ride the sentinel carry (and the
-                # rollback snapshot below — a tripped chunk's samples are
-                # discarded with its steps)
-                carry = carry + (
-                    jax.tree.map(jnp.copy, self.stats_state),
-                    jnp.copy(self._stats_tick),
-                )
-            carry = run_scanned(lambda c, k: self._step_n_sent(c, k), carry, n)
         st, fin, cok, done, cflm, gm, dvm, ke = carry[:8]
         snapshot = (self.state, self.time, self.stats_state, self._stats_tick)
         self.state = st  # provisional: resolve() confirms or restores
@@ -1036,7 +1089,7 @@ class CampaignModelBase:
         from ..utils.io_pipeline import ObservableFuture
 
         if self._obs_cache is None or self._obs_cache[0] is not self.state:
-            with self._scope():
+            with _tr.span("model.observe_launch", layer=_LAYER), self._scope():
                 fut = ObservableFuture(
                     self._obs_fn(self.state),
                     convert=lambda vals: tuple(float(v) for v in vals),
@@ -1047,7 +1100,13 @@ class CampaignModelBase:
     def get_observables(self) -> tuple[float, float, float, float]:
         """The four per-model scalars (:attr:`observable_names`) — one fused
         device dispatch, cached per state, fetched in ONE host transfer."""
-        return self.get_observables_async().result()
+        with _tr.span("model.observe", layer=_LAYER) as sp:
+            before = self._obs_cache
+            fut = self.get_observables_async()
+            sp.set(cached=self._obs_cache is before)  # no launch was needed
+            # the one place the host blocks on the device
+            with _tr.span("model.observe_fetch", layer=_LAYER):
+                return fut.result()
 
     def device_fence(self) -> None:
         """Block until every dispatched device computation whose output this

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..utils.integrate import Integrate
+from .campaign import DispatchSpans
 from .navier import Navier2D, NavierState
 
 
@@ -649,21 +650,22 @@ class NavierEnsemble(Integrate):
 
         if self._step_n_sent is not None:
             return self._update_n_sentinel(n)
-        with self.model._scope():
+        with self._seams(n) as seams, self.model._scope():
             if self._step_n_stats is not None:
-                carry = jax.tree.map(
-                    jnp.copy,
+                carry = seams.copy(
                     (
                         self.state,
                         self.stats_state,
                         self._stats_tick,
                         self.mask,
                         self.steps_done,
-                    ),
+                    )
                 )
                 carry = run_scanned(
-                    lambda c, k: self._step_n_stats(
-                        c[0], c[1], c[2], c[3], c[4], k
+                    seams.launcher(
+                        lambda c, k: self._step_n_stats(
+                            c[0], c[1], c[2], c[3], c[4], k
+                        )
                     ),
                     carry,
                     n,
@@ -676,16 +678,24 @@ class NavierEnsemble(Integrate):
                     self.steps_done,
                 ) = carry
             else:
-                carry = jax.tree.map(
-                    jnp.copy, (self.state, self.mask, self.steps_done)
-                )
+                carry = seams.copy((self.state, self.mask, self.steps_done))
                 carry = run_scanned(
-                    lambda c, k: self._step_n(c[0], c[1], c[2], k), carry, n
+                    seams.launcher(
+                        lambda c, k: self._step_n(c[0], c[1], c[2], k),
+                        aot=self._aot_step_n,
+                    ),
+                    carry,
+                    n,
                 )
                 self.state, self.mask, self.steps_done = carry
         self.time += n * self.dt
         self._obs_cache = None
         return None
+
+    def _seams(self, n: int) -> DispatchSpans:
+        """The spans ``ensemble.update_n`` / ``.carry_copy`` / ``.launch``
+        of one chunk (models/campaign.DispatchSpans)."""
+        return DispatchSpans("ensemble", "ensemble", steps=int(n), members=self.k)
 
     def _update_n_sentinel(self, n: int):
         """Sentinel-armed batched chunk (see :meth:`update_n`)."""
@@ -715,25 +725,26 @@ class NavierEnsemble(Integrate):
         rdt = config.real_dtype()
         stats_on = self.model._stats_cc is not None
         done_before = self.steps_done  # fetched with the sentinel scalars
-        with self.model._scope():
+        with self._seams(n) as seams, self.model._scope():
             # distinct buffers per slot: the dispatch donates the whole
             # carry, and donation rejects the same buffer appearing twice
+            copied = seams.copy(
+                (self.state, self.mask, self.steps_done)
+                + ((self.stats_state, self._stats_tick) if stats_on else ())
+            )
             carry = (
-                jax.tree.map(jnp.copy, self.state),
-                jnp.copy(self.mask),
+                copied[0],
+                copied[1],
                 jnp.ones((self.k,), bool),
-                jnp.copy(self.steps_done),
+                copied[2],
                 jnp.zeros((self.k,), rdt),  # per-member cfl max
                 jnp.zeros((self.k,), rdt),  # per-member ke growth max
                 jnp.zeros((self.k,), rdt),  # per-member |div| max
                 jnp.zeros((self.k,), rdt),  # per-member previous-step ke
+            ) + copied[3:]
+            carry = run_scanned(
+                seams.launcher(lambda c, k: self._step_n_sent(c, k)), carry, n
             )
-            if stats_on:
-                carry = carry + (
-                    jax.tree.map(jnp.copy, self.stats_state),
-                    jnp.copy(self._stats_tick),
-                )
-            carry = run_scanned(lambda c, k: self._step_n_sent(c, k), carry, n)
         st, fin, cok, dn, cflm, gm, dvm, kep = carry[:8]
         snapshot = (
             self.state,

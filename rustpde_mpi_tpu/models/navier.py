@@ -813,9 +813,14 @@ class Navier2D(CampaignModelBase, Integrate):
 
         conv_impl = self._conv_impl
         step_impl = self._step_impl
+        # one name per step stage on every device operation it lowers to
+        # (instruction metadata only: the compiled program is unchanged), so
+        # a device trace reads by stage and not by fusion number
+        stage = jax.named_scope
         manual_synth = getattr(self, "_manual_synth", None)
         manual_poisson = getattr(self, "_manual_poisson", None)
 
+        @stage("convection")  # named under each caller's stage
         def conv(ux, uy, space, vhat, with_bc=False):
             """u . grad(v), dealiased, in scratch-ortho space
             (/root/reference/src/navier_stokes/functions.rs:56-69 +
@@ -870,25 +875,28 @@ class Navier2D(CampaignModelBase, Integrate):
                 state.temp, state.velx, state.vely, state.pres, state.pseu
             )
             # buoyancy (full ortho space, includes the lift field)
-            that = sp_t.to_ortho(temp) + tb_ortho
+            with stage("buoyancy"):
+                that = sp_t.to_ortho(temp) + tb_ortho
             # convection velocity in physical space (old time level; fast
             # 3-pass synthesis — feeds only the dealiased products); the
             # manual split-sep path runs these through their own shard_map
             # region (decomp.ShardedSynthesis)
-            if manual_synth is not None:
-                ux = manual_synth[id(sp_u)].apply(velx)
-                uy = manual_synth[id(sp_v)].apply(vely)
-            else:
-                ux = sp_u.backward_fast(velx)
-                uy = sp_v.backward_fast(vely)
+            with stage("synthesis"):
+                if manual_synth is not None:
+                    ux = manual_synth[id(sp_u)].apply(velx)
+                    uy = manual_synth[id(sp_v)].apply(vely)
+                else:
+                    ux = sp_u.backward_fast(velx)
+                    uy = sp_v.backward_fast(vely)
 
             if with_sentinels:
                 # sentinels of the consumed state, from the velocities the
                 # convection terms need anyway (no extra transforms)
-                cfl = dt * jnp.max(
-                    jnp.abs(ux) * inv_dx[:, None] + jnp.abs(uy) * inv_dy[None, :]
-                )
-                ke = 0.5 * jnp.sum((ux**2 + uy**2) * w0s[:, None] * w1s[None, :])
+                with stage("sentinels"):
+                    cfl = dt * jnp.max(
+                        jnp.abs(ux) * inv_dx[:, None] + jnp.abs(uy) * inv_dy[None, :]
+                    )
+                    ke = 0.5 * jnp.sum((ux**2 + uy**2) * w0s[:, None] * w1s[None, :])
 
             if step_impl is not None:
                 # fused implicit half (ops/pallas_step.py): each stage ONE
@@ -900,109 +908,126 @@ class Navier2D(CampaignModelBase, Integrate):
                 # RUSTPDE_CONV_KERNEL); the stage dots pin HIGHEST matmul
                 # precision themselves, so no solve_scope here.  Mesh-free
                 # by construction (_build_step_kernels), hence no pins.
-                cx = conv(ux, uy, sp_u, velx)
-                args = (velx, pres, cx) + ((vely,) if coriolis else ())
-                velx_n = step_impl["velx"].apply(*args)
-                cy = conv(ux, uy, sp_v, vely)
-                args = (vely, pres, temp, cy) + ((velx,) if coriolis else ())
-                vely_n = step_impl["vely"].apply(*args)
-                div = step_impl["div"].apply(velx_n, vely_n)
-                pseu_n = sp_q.pin_zero_mode(step_impl["poisson"].apply(div))
-                velx_n = velx_n - step_impl["projx"].apply(pseu_n)
-                vely_n = vely_n - step_impl["projy"].apply(pseu_n)
-                pres_n = pres - nu * div + sp_q.to_ortho(pseu_n) / dt
-                ct = conv(ux, uy, sp_t, temp, with_bc=True)
-                temp_n = step_impl["temp"].apply(temp, ct)
+                with stage("momentum_x"):
+                    cx = conv(ux, uy, sp_u, velx)
+                    args = (velx, pres, cx) + ((vely,) if coriolis else ())
+                    velx_n = step_impl["velx"].apply(*args)
+                with stage("momentum_y"):
+                    cy = conv(ux, uy, sp_v, vely)
+                    args = (vely, pres, temp, cy) + ((velx,) if coriolis else ())
+                    vely_n = step_impl["vely"].apply(*args)
+                with stage("divergence"):
+                    div = step_impl["div"].apply(velx_n, vely_n)
+                with stage("poisson"):
+                    pseu_n = sp_q.pin_zero_mode(step_impl["poisson"].apply(div))
+                with stage("projection"):
+                    velx_n = velx_n - step_impl["projx"].apply(pseu_n)
+                    vely_n = vely_n - step_impl["projy"].apply(pseu_n)
+                with stage("pressure"):
+                    pres_n = pres - nu * div + sp_q.to_ortho(pseu_n) / dt
+                with stage("temperature"):
+                    ct = conv(ux, uy, sp_t, temp, with_bc=True)
+                    temp_n = step_impl["temp"].apply(temp, ct)
                 if has_scal:
-                    cs = conv(ux, uy, sp_t, state.scal, with_bc=True)
-                    scal_n = step_impl["scal"].apply(state.scal, cs)
+                    with stage("scalar"):
+                        cs = conv(ux, uy, sp_t, state.scal, with_bc=True)
+                        scal_n = step_impl["scal"].apply(state.scal, cs)
             else:
                 # horizontal momentum (navier_eq.rs:176-187)
-                rhs = sp_u.to_ortho(velx)
-                rhs = rhs - dt * sp_p.gradient(pres, (1, 0), scale)
-                rhs = rhs - dt * conv(ux, uy, sp_u, velx)
-                if coriolis:
-                    # rotating-frame f-plane term +f*v (velx/vely share one
-                    # space, so the cross-coupling is a plain ortho-space
-                    # add); in exactly incompressible 2-D flow this force is
-                    # irrotational and absorbed by the pressure — the
-                    # scenario's analytic validation case
-                    # (tests/test_workloads.py)
-                    rhs = rhs + dt * coriolis * sp_v.to_ortho(vely)
-                with solve_scope():
-                    velx_n = sol_u.solve(pin(rhs))
+                with stage("momentum_x"):
+                    rhs = sp_u.to_ortho(velx)
+                    rhs = rhs - dt * sp_p.gradient(pres, (1, 0), scale)
+                    rhs = rhs - dt * conv(ux, uy, sp_u, velx)
+                    if coriolis:
+                        # rotating-frame f-plane term +f*v (velx/vely share one
+                        # space, so the cross-coupling is a plain ortho-space
+                        # add); in exactly incompressible 2-D flow this force is
+                        # irrotational and absorbed by the pressure — the
+                        # scenario's analytic validation case
+                        # (tests/test_workloads.py)
+                        rhs = rhs + dt * coriolis * sp_v.to_ortho(vely)
+                    with solve_scope():
+                        velx_n = sol_u.solve(pin(rhs))
 
                 # vertical momentum + buoyancy (navier_eq.rs:190-203)
-                rhs = sp_v.to_ortho(vely)
-                rhs = rhs - dt * sp_p.gradient(pres, (0, 1), scale)
-                rhs = rhs + dt * that
-                rhs = rhs - dt * conv(ux, uy, sp_v, vely)
-                if coriolis:
-                    rhs = rhs - dt * coriolis * sp_u.to_ortho(velx)
-                with solve_scope():
-                    vely_n = sol_v.solve(pin(rhs))
+                with stage("momentum_y"):
+                    rhs = sp_v.to_ortho(vely)
+                    rhs = rhs - dt * sp_p.gradient(pres, (0, 1), scale)
+                    rhs = rhs + dt * that
+                    rhs = rhs - dt * conv(ux, uy, sp_v, vely)
+                    if coriolis:
+                        rhs = rhs - dt * coriolis * sp_u.to_ortho(velx)
+                    with solve_scope():
+                        vely_n = sol_v.solve(pin(rhs))
 
                 # pressure projection
                 # (navier_eq.rs:19-25,117-125,137-143,158-162)
-                div = sp_u.gradient(velx_n, (1, 0), scale) + sp_v.gradient(
-                    vely_n, (0, 1), scale
-                )
-                with solve_scope():
-                    if manual_poisson is not None:
-                        # the manually-partitioned fast-diag region — the
-                        # one stage whose GSPMD fusion miscompiles on the
-                        # split-sep layout (parallel/decomp.ShardedPoisson
-                        # bisection)
-                        pseu_n = manual_poisson.solve(div)
+                with stage("divergence"):
+                    div = sp_u.gradient(velx_n, (1, 0), scale) + sp_v.gradient(
+                        vely_n, (0, 1), scale
+                    )
+                with stage("poisson"):
+                    with solve_scope():
+                        if manual_poisson is not None:
+                            # the manually-partitioned fast-diag region — the
+                            # one stage whose GSPMD fusion miscompiles on the
+                            # split-sep layout (parallel/decomp.ShardedPoisson
+                            # bisection)
+                            pseu_n = manual_poisson.solve(div)
+                        else:
+                            pseu_n = sol_p.solve(pin(div))
+                    pseu_n = sp_q.pin_zero_mode(pseu_n)  # remove singularity
+                with stage("projection"):
+                    if proj_grad is not None:
+                        gx0, gx1, gy0, gy1 = proj_grad
+                        ax = pseu_n.ndim - 2
+                        velx_n = velx_n - gx1.apply(gx0.apply(pseu_n, ax), ax + 1) / scale[0]
+                        vely_n = vely_n - gy1.apply(gy0.apply(pseu_n, ax), ax + 1) / scale[1]
                     else:
-                        pseu_n = sol_p.solve(pin(div))
-                pseu_n = sp_q.pin_zero_mode(pseu_n)  # remove singularity
-                if proj_grad is not None:
-                    gx0, gx1, gy0, gy1 = proj_grad
-                    ax = pseu_n.ndim - 2
-                    velx_n = velx_n - gx1.apply(gx0.apply(pseu_n, ax), ax + 1) / scale[0]
-                    vely_n = vely_n - gy1.apply(gy0.apply(pseu_n, ax), ax + 1) / scale[1]
-                else:
-                    velx_n = velx_n - sp_u.from_ortho(
-                        sp_q.gradient(pseu_n, (1, 0), scale)
-                    )
-                    vely_n = vely_n - sp_v.from_ortho(
-                        sp_q.gradient(pseu_n, (0, 1), scale)
-                    )
-                pres_n = pres - nu * div + sp_q.to_ortho(pseu_n) / dt
+                        velx_n = velx_n - sp_u.from_ortho(
+                            sp_q.gradient(pseu_n, (1, 0), scale)
+                        )
+                        vely_n = vely_n - sp_v.from_ortho(
+                            sp_q.gradient(pseu_n, (0, 1), scale)
+                        )
+                with stage("pressure"):
+                    pres_n = pres - nu * div + sp_q.to_ortho(pseu_n) / dt
 
                 # temperature (navier_eq.rs:209-224)
-                rhs = sp_t.to_ortho(temp)
-                rhs = rhs + tb_diff
-                rhs = rhs - dt * conv(ux, uy, sp_t, temp, with_bc=True)
-                with solve_scope():
-                    temp_n = sol_t.solve(pin(rhs))
+                with stage("temperature"):
+                    rhs = sp_t.to_ortho(temp)
+                    rhs = rhs + tb_diff
+                    rhs = rhs - dt * conv(ux, uy, sp_t, temp, with_bc=True)
+                    with solve_scope():
+                        temp_n = sol_t.solve(pin(rhs))
 
                 if has_scal:
-                    # passive scalar (scenario modifier): the temperature's
-                    # advection-diffusion at the scalar diffusivity, same BC
-                    # lift — with matched diffusivity a scalar released
-                    # equal to the temperature stays identically equal
-                    # (exact validation case); the buoyancy never reads it
-                    # (one-way coupling, hence "passive")
-                    rhs = sp_t.to_ortho(state.scal)
-                    rhs = rhs + kc_over_ka * tb_diff  # dt*kc*lap(bc lift)
-                    rhs = rhs - dt * conv(ux, uy, sp_t, state.scal, with_bc=True)
-                    with solve_scope():
-                        scal_n = sol_c.solve(pin(rhs))
+                    with stage("scalar"):
+                        # passive scalar (scenario modifier): the temperature's
+                        # advection-diffusion at the scalar diffusivity, same BC
+                        # lift — with matched diffusivity a scalar released
+                        # equal to the temperature stays identically equal
+                        # (exact validation case); the buoyancy never reads it
+                        # (one-way coupling, hence "passive")
+                        rhs = sp_t.to_ortho(state.scal)
+                        rhs = rhs + kc_over_ka * tb_diff  # dt*kc*lap(bc lift)
+                        rhs = rhs - dt * conv(ux, uy, sp_t, state.scal, with_bc=True)
+                        with solve_scope():
+                            scal_n = sol_c.solve(pin(rhs))
 
             if solid is not None:
                 # implicit pointwise Brinkman penalization (set_solid):
                 # elementwise in physical space, exact for the sub-step
                 fac, temp_add = solid["fac"], solid["temp_add"]
-                velx_n = sp_u.forward(sp_u.backward(velx_n) * fac)
-                vely_n = sp_v.forward(sp_v.backward(vely_n) * fac)
-                temp_n = sp_t.forward(sp_t.backward(temp_n) * fac + temp_add)
-                if has_scal:
-                    # the solid enforces the same target on the scalar
-                    scal_n = sp_t.forward(
-                        sp_t.backward(scal_n) * fac + temp_add
-                    )
+                with stage("solid"):
+                    velx_n = sp_u.forward(sp_u.backward(velx_n) * fac)
+                    vely_n = sp_v.forward(sp_v.backward(vely_n) * fac)
+                    temp_n = sp_t.forward(sp_t.backward(temp_n) * fac + temp_add)
+                    if has_scal:
+                        # the solid enforces the same target on the scalar
+                        scal_n = sp_t.forward(
+                            sp_t.backward(scal_n) * fac + temp_add
+                        )
 
             # pin the step outputs too: the next step's transforms assume the
             # x-pencil layout, and XLA's sharding propagation is free to emit
@@ -1021,7 +1046,8 @@ class Navier2D(CampaignModelBase, Integrate):
             if with_sentinels:
                 # |div| of the uncorrected velocities — the residual the
                 # projection removes this step; its blow-up tracks the flow's
-                return state_n, (cfl, ke, norm_l2(div))
+                with stage("sentinels"):
+                    return state_n, (cfl, ke, norm_l2(div))
             return state_n
 
         return step

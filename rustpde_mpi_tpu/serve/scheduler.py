@@ -1626,7 +1626,8 @@ class SimServer:
         # forward would misattribute that work to THIS campaign's file
         _rt.LOG.drain()
         ck_k = self._peek_checkpoint_members(self._campaign_dir(key))
-        runner, ens = self._build_runner(key, k=ck_k)
+        with _tr.span("serve_build_runner", layer="service loop"):
+            runner, ens = self._build_runner(key, k=ck_k)
         self._runner = runner
         self._arm_device_fence(ens)
         self._last_bucket = key  # round-robin cursor
@@ -2557,7 +2558,7 @@ class SimServer:
                 {s.index: s.req.trace_id for s in running if s.req.trace_id}
             )
             t0_wall = time.time()
-            with _tr.span("serve_chunk", steps=n, slots=len(running)):
+            with _tr.span("serve_chunk", layer="service loop", steps=n, slots=len(running)):
                 runner.advance(n)
             advanced = runner.step - before
             if self._first_chunk_done is False and advanced > 0:
@@ -2589,7 +2590,7 @@ class SimServer:
                 # still finite: re-bucket the pinned requests down the
                 # per-bucket dt ladder (proactive — no NaN, no checkpoint)
                 self._settle_predivergence(runner, ens, slots, key)
-            with _tr.span("serve_settle", step=runner.step):
+            with _tr.span("serve_settle", layer="service loop", step=runner.step):
                 self._settle_boundary(runner, ens, slots, key)
             if self._fleet is not None:
                 # fleet boundary work (config-aligned guard: every host
@@ -2600,7 +2601,8 @@ class SimServer:
                 if self._fence_check(ens, slots, key):
                     return
                 self._maybe_preempt(runner, ens, slots, key)
-                self._persist_running_continuations(ens, slots)
+                with _tr.span("serve_persist", layer="service loop"):
+                    self._persist_running_continuations(ens, slots)
             self._refresh_slot_state(slots, ens.k)
             self._boundary_gauges()
             # boundary housekeeping: deferred sharded commit + cadence
@@ -2612,7 +2614,8 @@ class SimServer:
                 self._drain = True
                 self._drain_campaign(runner, ens, slots, key)
                 return
-            self._fill_slots(runner, ens, slots, key)
+            with _tr.span("serve_refill", layer="service loop"):
+                self._fill_slots(runner, ens, slots, key)
             self._refresh_slot_state(slots, ens.k)
             if root:
                 self._flush_results()

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -300,17 +301,18 @@ class HholtzAdi:
                 "(a rank-1 rhs would silently solve both axes over the same "
                 "axis; batch dims go in front)"
             )
-        ax = rhs.ndim - 2
-        out = constrain(rhs, SPEC)
-        if self.matvec[0] is not None:
-            out = self.matvec[0].apply(out, ax)
-        out = constrain(out, PHYS)
-        if self.matvec[1] is not None:
-            out = self.matvec[1].apply(out, ax + 1)
-        out = self.solvers[1].solve(out, ax + 1)  # axis-1 recurrence
-        out = constrain(out, SPEC)
-        out = self.solvers[0].solve(out, ax)  # axis-0 recurrence
-        return constrain(out, SPEC)
+        with jax.named_scope("helmholtz"):
+            ax = rhs.ndim - 2
+            out = constrain(rhs, SPEC)
+            if self.matvec[0] is not None:
+                out = self.matvec[0].apply(out, ax)
+            out = constrain(out, PHYS)
+            if self.matvec[1] is not None:
+                out = self.matvec[1].apply(out, ax + 1)
+            out = self.solvers[1].solve(out, ax + 1)  # axis-1 recurrence
+            out = constrain(out, SPEC)
+            out = self.solvers[0].solve(out, ax)  # axis-0 recurrence
+            return constrain(out, SPEC)
 
 
 class TensorSolver:
@@ -381,18 +383,19 @@ class TensorSolver:
                 "(a rank-1 rhs would silently solve both axes over the same "
                 "axis; batch dims go in front)"
             )
-        ax = rhs.ndim - 2
-        out = constrain(rhs, SPEC)
-        if self.matvec1 is not None:
-            out = self.matvec1.apply(constrain(out, PHYS), ax + 1)
-        out = constrain(out, SPEC)
-        if self.fwd is not None:
-            out = self.fwd.apply(out, ax)
-        out = self.banded.solve(constrain(out, PHYS), ax + 1)
-        out = constrain(out, SPEC)
-        if self.bwd is not None:
-            out = self.bwd.apply(out, ax)
-        return constrain(out, SPEC)
+        with jax.named_scope("tensor_solve"):
+            ax = rhs.ndim - 2
+            out = constrain(rhs, SPEC)
+            if self.matvec1 is not None:
+                out = self.matvec1.apply(constrain(out, PHYS), ax + 1)
+            out = constrain(out, SPEC)
+            if self.fwd is not None:
+                out = self.fwd.apply(out, ax)
+            out = self.banded.solve(constrain(out, PHYS), ax + 1)
+            out = constrain(out, SPEC)
+            if self.bwd is not None:
+                out = self.bwd.apply(out, ax)
+            return constrain(out, SPEC)
 
 
 class FastDiag:
@@ -448,20 +451,21 @@ class FastDiag:
                 "(a rank-1 rhs would silently solve both axes over the same "
                 "axis; batch dims go in front)"
             )
-        ax = rhs.ndim - 2
-        out = constrain(rhs, SPEC)
-        if self.fwd[0] is not None:
-            out = self.fwd[0].apply(out, ax)
-        out = constrain(out, PHYS)
-        if self.fwd[1] is not None:
-            out = self.fwd[1].apply(out, ax + 1)
-        out = out / self.denom.astype(out.dtype)
-        if self.bwd[1] is not None:
-            out = self.bwd[1].apply(out, ax + 1)
-        out = constrain(out, SPEC)
-        if self.bwd[0] is not None:
-            out = self.bwd[0].apply(out, ax)
-        return constrain(out, SPEC)
+        with jax.named_scope("fastdiag"):
+            ax = rhs.ndim - 2
+            out = constrain(rhs, SPEC)
+            if self.fwd[0] is not None:
+                out = self.fwd[0].apply(out, ax)
+            out = constrain(out, PHYS)
+            if self.fwd[1] is not None:
+                out = self.fwd[1].apply(out, ax + 1)
+            out = out / self.denom.astype(out.dtype)
+            if self.bwd[1] is not None:
+                out = self.bwd[1].apply(out, ax + 1)
+            out = constrain(out, SPEC)
+            if self.bwd[0] is not None:
+                out = self.bwd[0].apply(out, ax)
+            return constrain(out, SPEC)
 
 
 class _TensorBased:

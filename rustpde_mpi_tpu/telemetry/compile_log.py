@@ -210,6 +210,19 @@ def update_device_memory_gauges() -> int:
 # -- on-demand / auto jax.profiler capture ------------------------------------
 
 
+def _start_trace(logdir: str) -> None:
+    """Start a capture with the Python tracer off.  The host side of the
+    profile is named by the program's own ``rustpde:`` spans
+    (telemetry/tracing.py; the host TraceMe level stays at its default so
+    they are kept), and stopping a capture that recorded every Python call
+    of a busy server holds the host for about a minute."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(logdir, profiler_options=options)
+
+
 class ProfilerCapture:
     """Bounded, single-flight ``jax.profiler`` capture.
 
@@ -266,13 +279,12 @@ class ProfilerCapture:
         return dict(status)
 
     def _run(self, logdir: str, seconds: float, status: dict) -> None:
-        start = self._start_fn
+        start = self._start_fn or _start_trace
         stop = self._stop_fn
-        if start is None or stop is None:
+        if stop is None:
             import jax
 
-            start = start or jax.profiler.start_trace
-            stop = stop or jax.profiler.stop_trace
+            stop = jax.profiler.stop_trace
         try:
             os.makedirs(logdir, exist_ok=True)
             start(logdir)

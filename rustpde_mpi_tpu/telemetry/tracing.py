@@ -16,20 +16,36 @@ so every incident ships with the timeline of its last few thousand events
 instead of a bare traceback.  The ring bounds memory (default 4096 events,
 ``RUSTPDE_TRACE_EVENTS``); dumping never clears it.
 
+Every span also carries an ``id``, the ``parent`` that was open on its
+thread when it began and (where the call site gives one) the ``layer`` it
+belongs to, so a layer's self time is its span less its children; and
+while it is open it holds a ``jax.profiler.TraceAnnotation`` named
+``rustpde:<name>``.  That costs an atomic load while no profiler session is
+open; inside one it puts the span into the trace's host plane, on the clock
+the device planes use, which is the only way to lay a span of the program
+beside an idle gap of the chip (trace times count from the session's start,
+so a ``perf_counter`` reading cannot be matched to them afterwards).
+:func:`spans` is the read side for code in the same process.
+
 Overhead contract: with tracing disabled (:func:`set_enabled` or
 ``RUSTPDE_TRACE=0``) :func:`span` returns a shared no-op context manager —
-one function call and one branch (~ns, no allocation); enabled spans cost
-two ``perf_counter`` reads and one deque append.  Spans wrap HOST-side
-seams only and never add device work, so traced runs stay bit-identical
-(CI-asserted together with the metrics layer)."""
+one function call and one branch (~ns, no allocation, no annotation);
+enabled spans cost two ``perf_counter`` reads, one TraceAnnotation and one
+deque append.  Spans wrap HOST-side seams only and never add device work,
+so traced runs stay bit-identical (CI-asserted together with the metrics
+layer)."""
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time as _time
 from collections import deque
+
+from jax.profiler import TraceAnnotation
+
 from .. import config as _config
 
 # RUSTPDE_TELEMETRY=0 is the master kill switch; RUSTPDE_TRACE=0 turns off
@@ -80,12 +96,11 @@ class FlightRecorder:
 
     def _tid(self) -> int:
         ident = threading.get_ident()
-        with self._lock:
-            tid = self._tids.get(ident)
-            if tid is None:
-                tid = len(self._tids)
-                self._tids[ident] = tid
-            return tid
+        tid = self._tids.get(ident)  # a thread's own entry never changes
+        if tid is None:
+            with self._lock:
+                tid = self._tids.setdefault(ident, len(self._tids))
+        return tid
 
     def now_us(self) -> float:
         return (_time.perf_counter() - self._t0) * 1e6
@@ -94,8 +109,8 @@ class FlightRecorder:
         event = {
             "name": name,
             "ph": "X",
-            "ts": round(t0_us, 3),
-            "dur": round(dur_us, 3),
+            "ts": t0_us,  # not rounded: this is the per-span hot path
+            "dur": dur_us,
             "pid": self._pid,
             "tid": self._tid(),
         }
@@ -166,33 +181,60 @@ def set_span_annotator(fn) -> None:
     _ANNOTATOR = fn
 
 
-class _Span:
-    __slots__ = ("name", "args", "_t0")
+#: span ids are process-wide; the stack of open spans is per thread, so a
+#: span's parent is always one its own thread opened
+_IDS = itertools.count(1)
 
-    def __init__(self, name: str, args: dict | None):
+
+class _OpenSpans(threading.local):
+    def __init__(self):
+        self.stack: list[int] = []
+
+
+_OPEN = _OpenSpans()
+
+
+class _Span:
+    __slots__ = ("name", "args", "id", "parent", "_t0", "_annotation")
+
+    def __init__(self, name: str, args: dict):
         self.name = name
-        self.args = args or None
+        self.args = args
+
+    def set(self, **args) -> None:
+        """Add counts that are known only once the work is under way."""
+        self.args.update(args)
 
     def __enter__(self):
+        stack = _OPEN.stack
+        self.parent = stack[-1] if stack else None
+        self.id = next(_IDS)
+        stack.append(self.id)
+        self._annotation = TraceAnnotation("rustpde:" + self.name)
+        self._annotation.__enter__()
         self._t0 = RECORDER.now_us()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        args = self.args
+        dur = RECORDER.now_us() - self._t0
+        self._annotation.__exit__(exc_type, exc, tb)
+        _OPEN.stack.pop()
+        args = {"id": self.id, "parent": self.parent, **self.args}
         if exc_type is not None:
-            args = dict(args or {})
             args["error"] = exc_type.__name__
         if _ANNOTATOR is not None:
             extra = _ANNOTATOR()
             if extra:
-                args = dict(args or {})
                 args.update(extra)
-        RECORDER.add_complete(self.name, self._t0, RECORDER.now_us() - self._t0, args)
+        RECORDER.add_complete(self.name, self._t0, dur, args)
         return False
 
 
 class _NullSpan:
     __slots__ = ()
+
+    def set(self, **args) -> None:
+        pass
 
     def __enter__(self):
         return self
@@ -204,12 +246,34 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-def span(name: str, **args):
+def span(name: str, layer: str | None = None, **args):
     """Context manager recording one complete trace event; the shared
-    no-op object when tracing is disabled (one branch, no allocation)."""
+    no-op object when tracing is disabled (one branch, no allocation).
+    ``layer`` is the layer's name as PERF.md section 3 spells it."""
     if not _ENABLED:
         return _NULL_SPAN
-    return _Span(name, args or None)
+    if layer is not None:
+        args["layer"] = layer
+    return _Span(name, args)
+
+
+def spans(name: str) -> list[tuple]:
+    """The ring's completed spans called ``name``, oldest first, as
+    ``(t0_ns, dur_ns, id, parent, args)`` on the recorder's own clock."""
+    out = []
+    for ev in RECORDER.events():
+        if ev["ph"] == "X" and ev["name"] == name:
+            args = ev.get("args") or {}
+            out.append(
+                (
+                    round(ev["ts"] * 1e3),
+                    round(ev["dur"] * 1e3),
+                    args.get("id"),
+                    args.get("parent"),
+                    args,
+                )
+            )
+    return out
 
 
 def instant(name: str, **args) -> None:

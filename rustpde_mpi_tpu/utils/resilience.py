@@ -737,7 +737,7 @@ class ResilientRunner:
         drains the writer first, so recovery can never target a file that
         is still being written."""
         t0 = _time.monotonic()
-        with _tr.span("checkpoint_stage", reason=reason, step=self.step):
+        with _tr.span("checkpoint_stage", layer="runner", reason=reason, step=self.step):
             if self._is_ensemble:
                 snap = checkpoint.ensemble_snapshot_to_host(self.pde, step=self.step)
             else:
@@ -826,7 +826,7 @@ class ResilientRunner:
         checkpoints (anchor/final/preempt) write and commit inline."""
         self._commit_pending()  # at most one deferred commit in flight
         t0 = _time.monotonic()
-        with _tr.span("checkpoint_stage", reason=reason, step=self.step):
+        with _tr.span("checkpoint_stage", layer="runner", reason=reason, step=self.step):
             snap = checkpoint.sharded_snapshot_to_host(self.pde, step=self.step)
         snapshot_s = _time.monotonic() - t0
         self._io_snapshot_s += snapshot_s
@@ -904,7 +904,7 @@ class ResilientRunner:
         manifest), rotate on success, journal the ``checkpoint_sharded``
         telemetry (shard count, bytes/host, barrier wait seconds)."""
         w0 = _time.monotonic()
-        with _tr.span("checkpoint_commit", step=self.step):
+        with _tr.span("checkpoint_commit", layer="runner", step=self.step):
             stats = checkpoint.commit_sharded_snapshot(snap, path, local_ok=local_ok)
         _tm.counter(
             "checkpoint_barrier_seconds_total",
@@ -1050,7 +1050,7 @@ class ResilientRunner:
                     jax.block_until_ready(state)
             return result
 
-        with _tr.span("dispatch", steps=n, step=self.step):
+        with _tr.span("dispatch", layer="runner", steps=n, step=self.step):
             return call_with_watchdog(
                 work, self.dispatch_timeout_s, label=f"update_n({n}) @ step {self.step}"
             )
@@ -1209,7 +1209,7 @@ class ResilientRunner:
                 _time.sleep(max(2.0 * (self.dispatch_timeout_s or 0.0), 1.0))
             return pde.update_n_pending(k)
 
-        with _tr.span("dispatch_pending", steps=k, step=self.step):
+        with _tr.span("dispatch_pending", layer="runner", steps=k, step=self.step):
             return call_with_watchdog(
                 work,
                 self.dispatch_timeout_s,
@@ -1219,7 +1219,7 @@ class ResilientRunner:
     def _resolve_pending(self, chunk, k: int):
         """Watchdog-guarded resolve: a wedged device materializes here, at
         the sentinel fetch, instead of at the dispatch."""
-        with _tr.span("resolve", steps=k, step=self.step):
+        with _tr.span("resolve", layer="runner", steps=k, step=self.step):
             return call_with_watchdog(
                 chunk.resolve,
                 self.dispatch_timeout_s,
@@ -1376,7 +1376,7 @@ class ResilientRunner:
         start_step, start_fut, snap, disp_dt = rec
         prev = self._integ_prev
         if live is None:
-            with _tr.span("integrity_digest", step=self.step):
+            with _tr.span("integrity_digest", layer="runner", step=self.step):
                 live = pde.state_digest_async()
         self._integ_prev = (self.step, live)
         self._integ_chunks += 1
@@ -1393,7 +1393,7 @@ class ResilientRunner:
             # the shadow re-execution run at the wrong dt — skip it for
             # this chunk (the chain check above still ran); the driver is
             # about to re-plan anyway
-            with _tr.span("integrity_shadow", steps=k, step=self.step):
+            with _tr.span("integrity_shadow", layer="runner", steps=k, step=self.step):
                 d_shadow = np.asarray(  # lint-ok: RPD005 digest scalar
                     pde.shadow_digest_async(snap, k).result()
                 )

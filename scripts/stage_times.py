@@ -1,0 +1,191 @@
+"""Device time per solver step by step stage, from a profiler trace.
+
+    python scripts/stage_times.py --workload rbc513_f32.solo [--dispatches 2]
+    python scripts/stage_times.py --workload swarm129_f32.batch --dispatches 6
+
+Builds the cell's own model (``BENCHMARK.json`` and ``benchmark/`` say what a
+cell is), warms one dispatch, traces a few under ``utils/profiling.trace`` and
+reduces the trace with the benchmark's own reducer.  Every device operation of
+the trace is then looked up, by instruction name and result shape, in the
+compiled text of the same chunk program, whose ``op_name`` metadata carries
+the ``jax.named_scope`` of the step stage it was traced under
+(``Navier2D._make_step``).  A fusion is counted under the stage of the
+instruction XLA took its metadata from.  Printed: ms per step by stage and
+solve, the share under no stage, and the share of device time whose
+instruction the chunk's text does not hold (the carry's copies, the
+observables).  Also: the program's ``rustpde:`` spans the trace's host plane
+holds, and how long after a launch span opens the device starts on its chunk.
+
+``--size N`` shrinks the grid for a rehearsal on the CPU, where the table is
+empty (the CPU backend writes no device plane) and only control flow is shown.
+"""
+
+import argparse
+import bisect
+import glob
+import json
+import os
+import re
+import sys
+import tempfile
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+STAGES = ("buoyancy", "synthesis", "sentinels", "momentum_x", "momentum_y", "divergence",
+          "poisson", "projection", "pressure", "temperature", "scalar", "solid")
+INNER = ("convection", "helmholtz", "fastdiag", "tensor_solve")
+
+
+def stage_of(op_name: str) -> str | None:
+    # a transform wraps the scopes it maps over: "vmap(poisson)" in the ensemble
+    parts = [re.sub(r"^(?:\w+\()+([^()]*)\)+$", r"\1", p) for p in op_name.split("/")]
+    stage = next((p for p in parts if p in STAGES), None)
+    if stage is None:
+        return None
+    inner = next((p for p in parts if p in INNER), None)
+    return f"{stage}/{inner}" if inner else stage
+
+
+def scopes_of(text: str, short) -> dict:
+    """``{"fusion.12 f32[513,513]": op_name}`` for every instruction of a
+    compiled module's text (``""`` where it carries no metadata)."""
+    out = {}
+    for line in text.splitlines():
+        line = re.sub(r"^\s*(ROOT )?", "", line)
+        if line.startswith("%") and " = " in line:
+            m = re.search(r'op_name="([^"]*)"', line)
+            out[short(line)] = m.group(1) if m else ""
+    return out
+
+
+def compiled_text(lowered) -> str:
+    """Compiled afresh: the persistent compile cache keys a program without
+    its metadata, so a hit hands back the names of whichever build compiled
+    it first (a checkout without scopes, say)."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return lowered.compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", before)
+        compilation_cache.reset_cache()
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--dispatches", type=int, default=2)
+    ap.add_argument("--size", type=int, default=None)
+    ap.add_argument("--out", default=None, help="also write the table as JSON here")
+    args = ap.parse_args(argv)
+    from benchmark import reduce as reducer
+    from benchmark.run import load_cell
+
+    _, _, cfg, traffic = load_cell(args.workload)
+    for key, value in cfg.get("env", {}).items():
+        os.environ[key] = str(value)
+    import jax
+    import numpy as np
+
+    from rustpde_mpi_tpu import Navier2D, NavierEnsemble
+    from rustpde_mpi_tpu.utils import profiling
+
+    g, ph = cfg["grid"], cfg["physics"]
+    nx, ny = (args.size, args.size) if args.size else (g["nx"], g["ny"])
+    n, k = int(traffic["steps_per_interval"]), int(traffic.get("members", 0))
+    model = Navier2D.new_confined(nx, ny, ph["ra"], ph["pr"], ph["dt"], ph["aspect"], ph["bc"])
+    if k:
+        sim = NavierEnsemble.from_seeds(model, seeds=list(range(k)), amp=traffic["amp"])
+        lowered = sim._step_n_jit.lower(model._step_consts, sim.state, sim.mask, sim.steps_done, n=n)
+        read = lambda: np.asarray(sim.steps_done)  # noqa: E731
+    else:
+        sim = model
+        model.init_random(0.1, seed=0)
+        lowered = model._step_n_jit.lower(model._step_consts, model.state, n=n)
+        read = model.get_observables
+    scopes = scopes_of(compiled_text(lowered), reducer.short)
+    sim.update_n(n), read()
+    logdir = tempfile.mkdtemp(prefix="stage_times_")
+    with profiling.trace(logdir):
+        for _ in range(args.dispatches):
+            sim.update_n(n), read()
+    path = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"), recursive=True)[0]
+    red = reducer.reduce_xplane(path)
+    steps = args.dispatches * n
+    total = sum(red["ops"].values())
+    table, unknown = {}, 0.0
+    for name, seconds in red["ops"].items():
+        if name not in scopes:
+            unknown += seconds
+            continue
+        stage = stage_of(scopes[name]) or "(no stage)"
+        table[stage] = table.get(stage, 0.0) + seconds
+    dev = jax.devices()[0]
+    print(f"stage_times: {args.workload} at {nx} x {ny}" + (f" x {k} members" if k else "")
+          + f", {args.dispatches} dispatches of {n} steps on {dev.device_kind}; device busy "
+          f"{red['busy_s']:.4f} s of {red['window_s']:.4f} s, sum of operations {total:.4f} s")
+    for stage, seconds in sorted(table.items(), key=lambda kv: -kv[1]):
+        print(f"  {stage:28s} {1e3 * seconds / steps:9.5f} ms/step  {100 * seconds / total:6.2f} %")
+    named = sum(v for s, v in table.items() if s != "(no stage)")
+    if total:
+        print(f"  under a named stage {100 * named / total:.2f} %; in the chunk's text but under no "
+              f"stage {100 * table.get('(no stage)', 0.0) / total:.2f} %; not in the chunk's text "
+              f"{100 * unknown / total:.2f} %")
+    host = host_spans(path)
+    for name, found in sorted(host["spans"].items()):
+        print(f"  host plane: {name} x {len(found)}, mean {1e-6 * sum(e - s for s, e in found) / len(found):.4f} ms")
+    if host["launch_to_device_us"]:
+        print(f"  launch span's start to the chunk program's start on the device: "
+              f"{host['launch_to_device_us']} us")
+    if args.out:
+        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+        with open(args.out, "w", encoding="utf-8") as fh:
+            json.dump({"workload": args.workload, "device": dev.device_kind, "steps": steps,
+                       "busy_s": red["busy_s"], "window_s": red["window_s"], "ops_s": total,
+                       "stages_s": table, "not_in_text_s": unknown,
+                       "launch_to_device_us": host["launch_to_device_us"]}, fh, indent=1)
+    return 0
+
+
+def host_spans(path: str) -> dict:
+    """The program's ``rustpde:`` spans on the trace's host plane as
+    ``{name: [(start_ns, end_ns)]}``, and for each ``.launch`` span the time
+    from its start to the start of the chunk program nearest to it on the
+    device (the ``XLA Modules`` row; the carry's copies are other modules).
+    Read with ``ProfileData`` itself: the reducer's ``load`` keeps only
+    ``bench:`` spans and Python frames once the Python tracer is on."""
+    from jax.profiler import ProfileData
+
+    from benchmark.reduce import DEVICE_PLANE
+
+    spans: dict = {}
+    chunks: list = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            if plane.name.startswith("/host:"):
+                for ev in line.events:
+                    if ev.name.startswith("rustpde:"):
+                        spans.setdefault(ev.name, []).append(
+                            (ev.start_ns, ev.start_ns + ev.duration_ns))
+            elif DEVICE_PLANE.match(plane.name) and line.name == "XLA Modules":
+                chunks.extend(ev.start_ns for ev in line.events if "step_n" in ev.name)
+    chunks.sort()
+    gaps = []
+    for name, found in spans.items():
+        if name.endswith(".launch") and chunks:
+            for start, _ in found:
+                # the nearest chunk start, signed: host and device clocks are
+                # aligned to some tens of microseconds only
+                i = bisect.bisect_left(chunks, start)
+                near = min(chunks[max(i - 1, 0):i + 1], key=lambda c: abs(c - start))
+                gaps.append(round((near - start) * 1e-3, 1))
+    return {"spans": spans, "launch_to_device_us": gaps}
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,0 +1,320 @@
+"""The program's spans at the model-step and ensemble boundaries
+(telemetry/tracing.py, models/campaign.py, models/ensemble.py), the named
+scopes of the step stages (models/navier.py, solver.py) and the benchmark's
+readers of the span ring (benchmark/layer_metrics/).
+
+What is held here: a span opened inside a profiler session is in the trace's
+host plane under ``rustpde:<name>``, nested as the calls are; ids and parents;
+the ``launches`` count; the scopes change no instruction of the compiled
+chunk; states are bit-identical with the recorder on and off, and off opens
+no annotation; each reader gives the mean it should.  No wall-clock cost is
+asserted."""
+
+import contextlib
+import glob
+import importlib
+import os
+import re
+import threading
+
+import numpy as np
+import pytest
+
+import jax
+
+from rustpde_mpi_tpu import Navier2D, NavierEnsemble
+from rustpde_mpi_tpu.telemetry import FlightRecorder, compile_log
+from rustpde_mpi_tpu.telemetry import tracing as ttracing
+from rustpde_mpi_tpu.utils.jit import scan_buckets
+
+MODEL_SPANS = ("model.update_n", "model.carry_copy", "model.launch", "model.observe",
+               "model.observe_launch", "model.observe_fetch")
+ENSEMBLE_SPANS = ("ensemble.update_n", "ensemble.carry_copy", "ensemble.launch")
+PARENT = {"model.carry_copy": "model.update_n", "model.launch": "model.update_n",
+          "model.observe_launch": "model.observe", "model.observe_fetch": "model.observe",
+          "ensemble.carry_copy": "ensemble.update_n", "ensemble.launch": "ensemble.update_n"}
+
+
+def _model(seed=0):
+    m = Navier2D(17, 17, 1e4, 1.0, 0.01, 1.0, "rbc", periodic=False)
+    m.init_random(0.1, seed=seed)
+    return m
+
+
+@pytest.fixture
+def ring(monkeypatch):
+    """A recorder of the test's own, recording on."""
+    rec = FlightRecorder(capacity=512)
+    monkeypatch.setattr(ttracing, "RECORDER", rec)
+    monkeypatch.setattr(ttracing, "_ENABLED", True)
+    return rec
+
+
+# -- (a) the device trace's clock ----------------------------------------------
+
+
+def test_spans_land_in_the_profilers_host_plane(ring, tmp_path):
+    """Every span of the model-step and ensemble seams is in the host plane
+    of a trace taken the way the service's own capture takes it (Python
+    tracer off), the child inside its parent, as long as the ring says."""
+    from jax.profiler import ProfileData
+
+    m = _model()
+    ens = NavierEnsemble.from_seeds(_model(), seeds=[1, 2], amp=0.1)
+    m.update_n(4), m.get_observables(), ens.update_n(4)  # compiled outside the trace
+    ring.clear()
+    compile_log._start_trace(str(tmp_path))
+    try:
+        m.update_n(4)
+        m.get_observables()
+        ens.update_n(4)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"), recursive=True)
+    found: dict = {}
+    python_frames = 0
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                python_frames += ev.name.startswith("$")
+                if ev.name.startswith("rustpde:"):
+                    found.setdefault(ev.name[len("rustpde:"):], []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    assert python_frames == 0  # the capture's options switch the Python tracer off
+    for name in MODEL_SPANS + ENSEMBLE_SPANS:
+        assert len(found.get(name, ())) == 1, (name, sorted(found))
+        (start, end), = found[name]
+        (_, dur_ns, *_), = ttracing.spans(name)
+        assert abs((end - start) - dur_ns) < 1e6, name
+        if name in PARENT:
+            (p0, p1), = found[PARENT[name]]
+            assert p0 <= start and end <= p1, name
+
+
+def test_profiler_capture_default_start_switches_the_python_tracer_off(monkeypatch, tmp_path):
+    seen = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d, profiler_options=None: seen.append((d, profiler_options)))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: seen.append("stop"))
+    cap = compile_log.ProfilerCapture()
+    assert cap.start(str(tmp_path / "p"), 0.01)["started"] is True
+    for _ in range(500):
+        if not cap.busy:
+            break
+        threading.Event().wait(0.01)
+    assert cap.last.get("done") is True and seen[-1] == "stop"
+    logdir, options = seen[0]
+    assert logdir == str(tmp_path / "p") and options.python_tracer_level == 0
+
+
+# -- (b) identity ----------------------------------------------------------------
+
+
+def test_span_ids_parents_and_layers(ring):
+    m = _model()
+    m.update_n(4)
+    m.get_observables()
+    by_name = {name: ttracing.spans(name) for name in MODEL_SPANS}
+    ids = [s[2] for found in by_name.values() for s in found]
+    assert len(set(ids)) == len(ids) and None not in ids
+    for name, parent in PARENT.items():
+        if name.startswith("model."):
+            (_, _, parent_id, _, _), = by_name[parent]
+            assert [s[3] for s in by_name[name]] == [parent_id], name
+    for name in ("model.update_n", "model.observe"):
+        assert by_name[name][0][3] is None
+        assert by_name[name][0][4]["layer"] == "model step"
+    assert by_name["model.observe"][0][4]["cached"] is False
+    m.get_observables()  # the same state again: no launch, and the span says so
+    assert ttracing.spans("model.observe")[-1][4]["cached"] is True
+    assert len(ttracing.spans("model.observe_launch")) == 1
+    # the ring's events stay Perfetto's: the identity rides in args
+    ev = ring.events()[-1]
+    assert ev["ph"] == "X" and {"id", "parent", "layer"} <= set(ev["args"])
+
+
+def test_sibling_threads_do_not_adopt_each_others_parents(ring):
+    inside, release = threading.Event(), threading.Event()
+
+    def other():
+        with ttracing.span("other_outer", layer="runner"):
+            inside.set()
+            release.wait(10)
+
+    thread = threading.Thread(target=other)
+    thread.start()
+    assert inside.wait(10)
+    with ttracing.span("main_outer"):
+        with ttracing.span("main_inner"):
+            pass
+    release.set()
+    thread.join(10)
+    assert not thread.is_alive()
+    (outer,), (inner,), (sibling,) = (
+        ttracing.spans(n) for n in ("main_outer", "main_inner", "other_outer"))
+    assert outer[3] is None and sibling[3] is None  # opened while the other was open
+    assert inner[3] == outer[2]
+
+
+# -- (c) the count at the boundary ---------------------------------------------
+
+
+@pytest.mark.parametrize("n", [8, 11])
+@pytest.mark.parametrize("kind", ["model", "ensemble"])
+def test_launches_counts_leaves_copied_and_buckets(ring, kind, n):
+    if kind == "model":
+        sim = _model()
+        leaves = len(jax.tree.leaves(sim.state))
+    else:
+        sim = NavierEnsemble.from_seeds(_model(), seeds=[1, 2, 3], amp=0.1)
+        leaves = len(jax.tree.leaves((sim.state, sim.mask, sim.steps_done)))
+    sim.update_n(n)
+    (_, _, _, _, args), = ttracing.spans(f"{kind}.update_n")
+    assert args["steps"] == n
+    assert args["launches"] == leaves + len(scan_buckets(n))
+    assert ttracing.spans(f"{kind}.carry_copy")[0][4]["leaves"] == leaves
+    launched = ttracing.spans(f"{kind}.launch")
+    assert [s[4]["steps"] for s in launched] == scan_buckets(n)
+    assert not any(s[4]["aot"] for s in launched)
+    if kind == "ensemble":
+        assert args["members"] == 3 and args["layer"] == "ensemble"
+
+
+def test_launch_span_says_when_a_prebuilt_executable_served_it(ring):
+    m = _model()
+    assert m.aot_compile(8) == 1
+    m.update_n(8)
+    assert [s[4]["aot"] for s in ttracing.spans("model.launch")] == [True]
+
+
+# -- (d) the scopes alter no device code ----------------------------------------
+
+
+@pytest.fixture
+def no_compile_cache():
+    """The persistent compile cache keys a program without its metadata, so
+    a hit hands back whatever names the first compilation had: compile
+    afresh where the names are what is read."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@contextlib.contextmanager
+def _no_scope(name):
+    yield
+
+
+def _chunk_text(named: bool, monkeypatch) -> str:
+    with monkeypatch.context() as mp:
+        if not named:
+            mp.setattr(jax, "named_scope", _no_scope)
+        m = _model()
+        return m._step_n_jit.lower(m._step_consts, m.state, n=8).compile().as_text()
+
+
+@pytest.mark.parametrize("path", ["dense", "step_impl"])
+def test_named_scopes_change_metadata_only(monkeypatch, no_compile_cache, path):
+    if path == "step_impl":
+        monkeypatch.setenv("RUSTPDE_STEP_KERNEL", "pallas")
+    named, bare = _chunk_text(True, monkeypatch), _chunk_text(False, monkeypatch)
+
+    def strip(text):
+        """The module without what only names it: each instruction's
+        ``metadata`` and the tables of files, functions and stack frames the
+        metadata points into."""
+        blocks = [b for b in text.split("\n\n") if b.split("\n", 1)[0] not in
+                  ("FileNames", "FunctionNames", "FileLocations", "StackFrames")]
+        return re.sub(r",? ?metadata=\{[^}]*\}", "", "\n\n".join(blocks))
+
+    assert strip(named) == strip(bare)
+    scopes = set(re.findall(r'op_name="([^"]*)"', named))
+    for stage in ("synthesis", "momentum_x", "momentum_y", "divergence", "poisson",
+                  "projection", "pressure", "temperature"):
+        assert any(f"/{stage}/" in s for s in scopes), stage
+    assert any("/momentum_x/convection/" in s for s in scopes)
+    assert any("/temperature/convection/" in s for s in scopes)
+    if path == "dense":
+        assert any("/temperature/helmholtz/" in s for s in scopes)
+    assert not any("/momentum_x/" in s for s in re.findall(r'op_name="([^"]*)"', bare))
+
+
+# -- (e) off is off, and on changes no state -----------------------------------
+
+
+def test_states_bit_identical_and_off_opens_no_annotation(monkeypatch):
+    opened = []
+
+    class Counting(ttracing.TraceAnnotation):
+        def __init__(self, name, **kw):
+            opened.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(ttracing, "TraceAnnotation", Counting)
+    monkeypatch.setattr(ttracing, "RECORDER", FlightRecorder(capacity=512))
+    out = {}
+    for on in (True, False):
+        monkeypatch.setattr(ttracing, "_ENABLED", on)
+        opened.clear()
+        ttracing.RECORDER.clear()
+        m = _model(seed=3)
+        ens = NavierEnsemble.from_seeds(_model(seed=3), seeds=[4, 5], amp=0.1)
+        m.update_n(11)
+        obs = m.get_observables()
+        ens.update_n(11)
+        out[on] = (jax.device_get(m.state), obs, jax.device_get(ens.state),
+                   np.asarray(ens.steps_done))
+        if on:
+            assert {"rustpde:" + n for n in MODEL_SPANS + ENSEMBLE_SPANS} <= set(opened)
+            assert ttracing.spans("ensemble.update_n")
+        else:
+            assert opened == [] and ttracing.RECORDER.events() == []
+            assert ttracing.span("anything") is ttracing._NULL_SPAN
+    for a, b in zip(jax.tree.leaves(out[True]), jax.tree.leaves(out[False])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# -- (f) the benchmark's readers -------------------------------------------------
+
+READERS = {
+    # reader: (span it reads, what the hand-made ring must give)
+    "update_n_host_ms": ("model.update_n", 2.5),
+    "chunk_host_ms": ("ensemble.update_n", 2.5),
+    "chunk_copy_ms": ("ensemble.carry_copy", 2.5),
+    "launches_per_chunk": ("ensemble.update_n", 8.5),
+}
+
+
+@pytest.mark.parametrize("reader", sorted(READERS))
+def test_reader_means_the_last_traced_spans(ring, reader):
+    mod = importlib.import_module(f"benchmark.layer_metrics.{reader}")
+    name, want = READERS[reader]
+    run = {"traced_dispatches": 2}
+    assert mod.read({}, run) is None  # an empty ring reads nothing, not 0
+    # an untraced dispatch first (40 ms, 99 launches), then the two traced ones
+    for dur_us, launches in ((40000.0, 99), (2000.0, 8), (3000.0, 9)):
+        ring.add_complete(name, ring.now_us(), dur_us, {"id": 1, "parent": None,
+                                                        "launches": launches})
+    ring.add_complete("something.else", ring.now_us(), 7.0)
+    assert mod.read({}, run) == pytest.approx(want)
+    assert mod.read({}, {"traced_dispatches": 0}) is None
+    assert mod.read({}, {"traced_dispatches": 4}) is None  # fewer spans than dispatches
+    ttracing.set_enabled(False)
+    assert mod.read({}, run) is None  # the recorder is off
+
+
+# -- (g) the yardstick's own checks ----------------------------------------------
+
+
+def test_benchmark_selfcheck_passes(capsys):
+    from benchmark import selfcheck
+
+    assert selfcheck.main([]) == 0
+    assert "8 readers agree" in capsys.readouterr().out

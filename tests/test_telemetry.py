@@ -191,8 +191,11 @@ def test_span_records_and_annotates_errors():
     events = ttracing.RECORDER.events()
     assert len(events) >= before + 2
     named = {e["name"]: e for e in events[-4:]}
-    assert named["outer"]["args"] == {"step": 3}
+    # the call site's args, beside the span's identity (id, parent)
+    assert named["outer"]["args"]["step"] == 3
+    assert named["outer"]["args"]["parent"] is None
     assert named["failing"]["args"]["error"] == "RuntimeError"
+    assert named["failing"]["args"]["id"] > named["outer"]["args"]["id"]
 
 
 def test_metrics_dumper_cadence_and_reader(tmp_path):
@@ -252,10 +255,12 @@ def test_instrumented_run_bit_identical_to_telemetry_off(tmp_path):
     programs — the full runner path with metrics+tracing ON produces a
     final state byte-identical to the same run with telemetry OFF."""
     states = {}
+    seams = {}
     prev = tmetrics.enabled()
     try:
         for key, on in (("on", True), ("off", False)):
             telemetry.set_enabled(on)
+            ttracing.RECORDER.clear()
             m = _model(seed=3)
             runner = ResilientRunner(
                 m,
@@ -267,8 +272,18 @@ def test_instrumented_run_bit_identical_to_telemetry_off(tmp_path):
             summary = runner.run()
             assert summary["outcome"] == "done"
             states[key] = jax.device_get(m.state)
+            seams[key] = {
+                n: ttracing.spans(n)
+                for n in ("dispatch", "model.update_n", "model.carry_copy", "model.launch")
+            }
     finally:
         telemetry.set_enabled(prev)
+    # the model-step seams ran on this path: recorded under the runner's
+    # dispatch span when ON, nothing at all when OFF
+    dispatched = {s[2] for s in seams["on"]["dispatch"]}
+    assert dispatched and {s[3] for s in seams["on"]["model.update_n"]} == dispatched
+    assert seams["on"]["model.carry_copy"] and seams["on"]["model.launch"]
+    assert not any(seams["off"].values())
     for a, b in zip(states["on"], states["off"]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # the ON run left live telemetry behind; the OFF run left none
