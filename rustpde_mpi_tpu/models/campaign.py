@@ -30,7 +30,7 @@ resilience stack.  The contract has two halves:
 
 **The machinery** (:class:`CampaignModelBase`): everything in that list that
 is generic over the step function is implemented HERE, once — the scanned
-chunk with divergence early-exit and buffer donation, the sentinel-armed
+chunk with divergence early-exit, the sentinel-armed
 variant, the deferred-commit pending chunk, the dt-rung cache, the cached
 observable future, exit/exit_future.  A model supplies the physics hooks:
 
@@ -59,11 +59,15 @@ from ..telemetry import tracing as _tr
 class DispatchSpans:
     """The host seams of one ``update_n`` call, as spans of ``layer``
     (PERF.md section 3): ``<prefix>.update_n`` around the call, inside it
-    ``<prefix>.carry_copy`` around the eager per-leaf copy of the carry (the
-    chunk donates its input) and one ``<prefix>.launch`` per bucket that
-    ``run_scanned`` dispatches.  The outer span's ``launches`` counts the
-    device programs the call enqueued: leaves copied plus buckets launched.
-    ``prefix`` is ``model`` here and ``ensemble`` in models/ensemble.py."""
+    ``<prefix>.carry_copy`` where the carry is assembled and handed over, and
+    one ``<prefix>.launch`` per bucket that ``run_scanned`` dispatches.  The
+    outer span's ``launches`` counts the device programs the call enqueued:
+    the buckets.  ``prefix`` is ``model`` here and ``ensemble`` in
+    models/ensemble.py.
+
+    The chunk programs donate nothing, so the carry the caller can still see
+    (``self.state`` and what rides with it) goes to the first bucket as it
+    is, uncopied: XLA writes each bucket's result to fresh buffers."""
 
     def __init__(self, prefix: str, layer: str, **args):
         self.prefix, self.layer, self.launches = prefix, layer, 0
@@ -77,31 +81,30 @@ class DispatchSpans:
         self._span.set(launches=self.launches)
         return self._span.__exit__(exc_type, exc, tb)
 
-    def copy(self, carry):
-        """``jax.tree.map(jnp.copy, carry)`` under the ``carry_copy`` span."""
-        import jax
-        import jax.numpy as jnp
+    def handover(self, fresh: int = 0):
+        """The ``carry_copy`` span, held while the carry is assembled.
+        ``leaves`` is the number of the caller's leaves copied: 0.  ``fresh``
+        is the number of arrays built eagerly on the device inside it (the
+        sentinel branch's initial flags and maxima), which ``launches`` does
+        not count."""
+        return _tr.span(
+            self.prefix + ".carry_copy", layer=self.layer, leaves=0, fresh=fresh
+        )
 
-        with _tr.span(self.prefix + ".carry_copy", layer=self.layer) as sp:
-            leaves, treedef = jax.tree.flatten(carry)
-            out = treedef.unflatten([jnp.copy(leaf) for leaf in leaves])
-            sp.set(leaves=len(leaves))
-        self.launches += len(leaves)
-        return out
+    def run(self, step_n, carry, n: int, aot=()):
+        """``run_scanned`` over ``step_n(carry, k)``, each bucket under a
+        ``launch`` span; ``aot`` holds the bucket sizes a prebuilt executable
+        serves."""
+        from ..utils.jit import run_scanned
 
-    def launcher(self, step_n, aot=()):
-        """``step_n`` as ``run_scanned`` calls it, each bucket under a
-        ``launch`` span; ``aot`` holds the bucket sizes a prebuilt
-        executable serves."""
-
-        def launch(carry, k):
+        def launch(c, k):
             self.launches += 1
             with _tr.span(
                 self.prefix + ".launch", layer=self.layer, steps=int(k), aot=int(k) in aot
             ):
-                return step_n(carry, k)
+                return step_n(c, k)
 
-        return launch
+        return run_scanned(launch, carry, n)
 
 
 _LAYER = "model step"
@@ -296,7 +299,7 @@ class CampaignModelBase:
         """Hoist + jit the step/observables entry points (see Navier2D's
         original docstring: closure-converted constants keep the HLO small
         at large grids) and build the chunked ``step_n`` with the in-chunk
-        early-exit and buffer donation.
+        early-exit.
 
         The wall time of every pass through here is recorded per model
         kind (telemetry/compile_log.py): dt-ladder re-jits and restores
@@ -382,15 +385,15 @@ class CampaignModelBase:
             (final, _, done), _ = jax.lax.scan(body, init, None, length=n)
             return final, done
 
-        # donate the state: XLA aliases the input coefficient buffers to the
-        # scan carry's outputs, so a chunked dispatch updates the state in
-        # place instead of holding a second resident copy in HBM.  Callers
-        # must hand in buffers they no longer need — update_n dispatches a
-        # fresh copy first, keeping references retained to ``self.state``
-        # across the call valid (no use-after-donate on the public API).
-        step_n_jit = jax.jit(step_n, static_argnames=("n",), donate_argnums=(1,))
-        # retained for aot_compile: .lower(...).compile() against these jit
-        # objects builds static-n executables ahead of traffic; a recompile
+        # the chunk donates nothing: XLA writes the scan's result to fresh
+        # buffers, so update_n hands it ``self.state`` as it is and a
+        # reference retained to that state stays valid across the call,
+        # without a copy on the host's side of the launch.  The price is a
+        # second state resident while a bucket runs (a few MB at the sizes
+        # served).
+        step_n_jit = jax.jit(step_n, static_argnames=("n",))
+        # retained for aot_compile: .lower(...).compile() against this jit
+        # object builds static-n executables ahead of traffic; a recompile
         # pass invalidates any prebuilt executables (the consts changed)
         self._step_n_jit = step_n_jit
 
@@ -457,9 +460,7 @@ class CampaignModelBase:
             (st, ss, tk, _, done), _ = jax.lax.scan(body, init, None, length=n)
             return st, ss, tk, done
 
-        stats_jit = jax.jit(
-            step_n_stats, static_argnames=("n",), donate_argnums=(2, 3, 4)
-        )
+        stats_jit = jax.jit(step_n_stats, static_argnames=("n",))
         self._step_n_stats = lambda s, ss, tk, n: stats_jit(
             self._step_consts, self._stats_consts, s, ss, tk, n=n
         )
@@ -581,7 +582,7 @@ class CampaignModelBase:
             final, _ = jax.lax.scan(body, carry, None, length=n)
             return final
 
-        sent_jit = jax.jit(step_n_sent, static_argnames=("n",), donate_argnums=(2,))
+        sent_jit = jax.jit(step_n_sent, static_argnames=("n",))
         self._step_n_sent = lambda c, n: sent_jit(
             self._sent_consts, self._stats_consts, c, n=n
         )
@@ -596,9 +597,12 @@ class CampaignModelBase:
     def update_n(self, n: int):
         """Advance n steps on the device via scanned power-of-two chunks
         (utils/jit.run_scanned).  Dispatches stay asynchronous and donate
-        their input state buffers; on divergence the in-scan early exit
-        freezes the state, ``exit()`` reports it at the next chunk boundary,
-        and ``self.time`` deliberately counts the scheduled steps.
+        nothing: the chunk takes the caller-visible state as it is and
+        writes its result to fresh buffers, so a retained reference to the
+        state stays readable and nothing is copied for it.  On divergence
+        the in-scan early exit freezes the state, ``exit()`` reports it at
+        the next chunk boundary, and ``self.time`` deliberately counts the
+        scheduled steps.
 
         With stability sentinels armed (:meth:`set_stability`) the chunk
         additionally returns a
@@ -608,31 +612,20 @@ class CampaignModelBase:
         still finite, the chunk is rolled back in memory and ``exit()``
         latches True until a governor acknowledges
         (:meth:`clear_pre_divergence`)."""
-        from ..utils.jit import run_scanned
-
         if self._step_n_sent is not None:
             return self._update_n_sentinel(n)
         with DispatchSpans("model", _LAYER, steps=int(n)) as seams, self._scope():
-            # the chunked dispatch donates its input buffers; hand it a copy
-            # so a state reference the caller retained stays readable, while
-            # every inter-bucket hand-off inside the chain is donated
             if self._step_n_stats is not None:
-                carry = seams.copy((self.state, self.stats_state, self._stats_tick))
-                st, ss, tick = run_scanned(
-                    seams.launcher(
-                        lambda c, k: self._step_n_stats(c[0], c[1], c[2], k)[:3]
-                    ),
-                    carry,
-                    n,
+                with seams.handover():
+                    carry = (self.state, self.stats_state, self._stats_tick)
+                self.state, self.stats_state, self._stats_tick = seams.run(
+                    lambda c, k: self._step_n_stats(*c, k)[:3], carry, n
                 )
-                self.state, self.stats_state, self._stats_tick = st, ss, tick
             else:
-                self.state = run_scanned(
-                    seams.launcher(
-                        lambda s, k: self._step_n(s, k)[0], aot=self._aot_step_n
-                    ),
-                    seams.copy(self.state),
-                    n,
+                with seams.handover():
+                    carry = self.state
+                self.state = seams.run(
+                    lambda s, k: self._step_n(s, k)[0], carry, n, aot=self._aot_step_n
                 )
         self.time += n * self.dt
         return None
@@ -656,7 +649,6 @@ class CampaignModelBase:
 
         from ..utils.governor import ChunkStatus
         from ..utils.io_pipeline import PendingChunkStatus
-        from ..utils.jit import run_scanned
 
         if self._step_n_sent is None:
             raise RuntimeError(
@@ -670,24 +662,18 @@ class CampaignModelBase:
             # the running sums + tick ride the sentinel carry (and the
             # rollback snapshot below — a tripped chunk's samples are
             # discarded with its steps)
-            copied = seams.copy(
-                (self.state, self.stats_state, self._stats_tick)
-                if stats_on
-                else (self.state,)
-            )
-            carry = (
-                copied[0],
-                jnp.asarray(True),
-                jnp.asarray(True),
-                jnp.asarray(0, jnp.int32),
-                jnp.asarray(0.0, rdt),  # cfl max
-                jnp.asarray(0.0, rdt),  # ke growth max
-                jnp.asarray(0.0, rdt),  # |div| max
-                jnp.asarray(0.0, rdt),  # previous-step ke
-            ) + copied[1:]
-            carry = run_scanned(
-                seams.launcher(lambda c, k: self._step_n_sent(c, k)), carry, n
-            )
+            with seams.handover(fresh=7):
+                carry = (
+                    self.state,
+                    jnp.asarray(True),
+                    jnp.asarray(True),
+                    jnp.asarray(0, jnp.int32),
+                    jnp.asarray(0.0, rdt),  # cfl max
+                    jnp.asarray(0.0, rdt),  # ke growth max
+                    jnp.asarray(0.0, rdt),  # |div| max
+                    jnp.asarray(0.0, rdt),  # previous-step ke
+                ) + ((self.stats_state, self._stats_tick) if stats_on else ())
+            carry = seams.run(self._step_n_sent, carry, n)
         st, fin, cok, done, cflm, gm, dvm, ke = carry[:8]
         snapshot = (self.state, self.time, self.stats_state, self._stats_tick)
         self.state = st  # provisional: resolve() confirms or restores
@@ -701,8 +687,8 @@ class CampaignModelBase:
             fin_b, cok_b = bool(fin_h), bool(cok_h)
             pre_div = fin_b and not cok_b
             if pre_div:
-                # in-memory rollback: the dispatch stepped a donated COPY,
-                # so the snapshot still holds the chunk-start state — put it
+                # in-memory rollback: the chunk donated nothing, so the
+                # snapshot still holds the chunk-start state — put it
                 # back and latch exit() until a governor acts
                 (self.state, self.time, self.stats_state, self._stats_tick) = (
                     snapshot
@@ -933,13 +919,10 @@ class CampaignModelBase:
         """Shadow re-execution audit kernel: re-step ``n`` steps from the
         retained :meth:`integrity_snapshot` through the PLAIN chunked path
         and digest the result.  The snapshot is not consumed (the chunk
-        donates a copy).  The plain chunk is bit-identical to the live
-        sentinel/stats chunks by the pure-consumer contract, and XLA
+        donates nothing).  The plain chunk is bit-identical to the
+        live sentinel/stats chunks by the pure-consumer contract, and XLA
         executables are deterministic — a digest differing from the live
         chunk's means corrupted state."""
-        import jax
-        import jax.numpy as jnp
-
         from ..utils.jit import run_scanned
 
         if not self.integrity_armed:
@@ -948,8 +931,7 @@ class CampaignModelBase:
                 "(set_integrity)"
             )
         with self._scope():
-            st = jax.tree.map(jnp.copy, snap["state"])
-            st = run_scanned(lambda s, k: self._step_n(s, k)[0], st, n)
+            st = run_scanned(lambda s, k: self._step_n(s, k)[0], snap["state"], n)
             return self._digest_future(self._dig_fn(st))
 
     def integrity_snapshot(self) -> dict:

@@ -15,11 +15,12 @@ points:
   ``dt*nu`` into their factorizations), so a parameter *scan* maps to one
   ensemble per parameter value with K seed-decorrelated members inside
   (``examples/navier_rbc_ensemble.py``).
-* **buffer donation** — the chunked step donates states + mask + counters
-  (``donate_argnums``): XLA aliases the input coefficient buffers to the
-  outputs, so the resident HBM footprint is ONE stacked state, not a double
-  buffer per dispatch.  :meth:`update_n` dispatches a fresh copy first so
-  references retained to ``.state`` / ``.mask`` stay valid.
+* **no donation, no copy** — the chunked step donates nothing: it takes
+  the caller-visible states + mask + counters as they are and XLA writes its
+  result to fresh buffers, so references retained to ``.state`` / ``.mask``
+  stay valid across :meth:`update_n` with no copy made for them.  The
+  footprint while a bucket runs is two stacked states (a few MB at the sizes
+  served), and a third between the buckets of a non-power-of-two ``n``.
 * **per-member fault isolation** — the single-run in-chunk NaN early-exit
   (a scalar is-finite carry flag, models/navier.py) generalizes to a
   per-member finite **mask**: a diverging member freezes at its last finite
@@ -379,12 +380,9 @@ class NavierEnsemble(Integrate):
             (st, mk, dn), _ = jax.lax.scan(body, (states, mask, done), None, length=n)
             return st, mk, dn
 
-        # donation: states + mask + counters alias input->output buffers, so
-        # the resident footprint is one stacked state (see module docstring);
-        # the consts (operator matrices) are shared and NEVER donated
-        ens_jit = jax.jit(
-            ens_step_n, static_argnames=("n",), donate_argnums=(1, 2, 3)
-        )
+        # nothing is donated (see module docstring): the chunk takes the
+        # caller-visible carry as it is and writes fresh buffers
+        ens_jit = jax.jit(ens_step_n, static_argnames=("n",))
         # retained for aot_compile: the warm pool lowers+compiles the
         # batched chunk for the scheduler's static dispatch sizes ahead of
         # traffic; dispatch prefers a prebuilt executable when one exists
@@ -472,11 +470,7 @@ class NavierEnsemble(Integrate):
             )
             return st, ss, tk, mk, dn
 
-        stats_jit = jax.jit(
-            ens_step_n_stats,
-            static_argnames=("n",),
-            donate_argnums=(2, 3, 4, 5, 6),
-        )
+        stats_jit = jax.jit(ens_step_n_stats, static_argnames=("n",))
         self._step_n_stats = lambda st, ss, tk, mk, dn, n: stats_jit(
             model._step_consts, model._stats_consts, st, ss, tk, mk, dn, n=n
         )
@@ -571,9 +565,7 @@ class NavierEnsemble(Integrate):
             final, _ = jax.lax.scan(body, carry, None, length=n)
             return final
 
-        sent_jit = jax.jit(
-            ens_step_n_sent, static_argnames=("n",), donate_argnums=(2,)
-        )
+        sent_jit = jax.jit(ens_step_n_sent, static_argnames=("n",))
         self._step_n_sent = lambda c, n: sent_jit(
             model._sent_consts, model._stats_consts, c, n=n
         )
@@ -633,11 +625,11 @@ class NavierEnsemble(Integrate):
     def update_n(self, n: int):
         """Advance every alive member n steps in scanned power-of-two chunks.
 
-        The chunked dispatch donates its carry, so it must never receive the
-        user-visible buffers — one copy of (state, mask, counters) per call
-        keeps retained references valid while every inter-bucket hand-off
-        inside the chain is donated.  ``self.time`` counts scheduled steps;
-        ``self.steps_done`` records how far each member actually advanced.
+        The chunked dispatch donates nothing: it takes the user-visible
+        (state, mask, counters) as they are and writes fresh buffers, so
+        retained references stay valid with no copy made for them.
+        ``self.time`` counts scheduled steps; ``self.steps_done`` records how
+        far each member actually advanced.
 
         With stability sentinels armed (template model's ``set_stability``)
         the chunk returns a :class:`~rustpde_mpi_tpu.utils.governor.ChunkStatus`
@@ -646,48 +638,31 @@ class NavierEnsemble(Integrate):
         rolls the whole chunk back in memory (members share the baked dt, so
         the dt response is batch-wide) and latches ``exit()`` until a
         governor acknowledges."""
-        from ..utils.jit import run_scanned
-
         if self._step_n_sent is not None:
             return self._update_n_sentinel(n)
         with self._seams(n) as seams, self.model._scope():
             if self._step_n_stats is not None:
-                carry = seams.copy(
-                    (
+                with seams.handover():
+                    carry = (
                         self.state,
                         self.stats_state,
                         self._stats_tick,
                         self.mask,
                         self.steps_done,
                     )
-                )
-                carry = run_scanned(
-                    seams.launcher(
-                        lambda c, k: self._step_n_stats(
-                            c[0], c[1], c[2], c[3], c[4], k
-                        )
-                    ),
-                    carry,
-                    n,
-                )
                 (
                     self.state,
                     self.stats_state,
                     self._stats_tick,
                     self.mask,
                     self.steps_done,
-                ) = carry
+                ) = seams.run(lambda c, k: self._step_n_stats(*c, k), carry, n)
             else:
-                carry = seams.copy((self.state, self.mask, self.steps_done))
-                carry = run_scanned(
-                    seams.launcher(
-                        lambda c, k: self._step_n(c[0], c[1], c[2], k),
-                        aot=self._aot_step_n,
-                    ),
-                    carry,
-                    n,
+                with seams.handover():
+                    carry = (self.state, self.mask, self.steps_done)
+                self.state, self.mask, self.steps_done = seams.run(
+                    lambda c, k: self._step_n(*c, k), carry, n, aot=self._aot_step_n
                 )
-                self.state, self.mask, self.steps_done = carry
         self.time += n * self.dt
         self._obs_cache = None
         return None
@@ -714,7 +689,6 @@ class NavierEnsemble(Integrate):
         from .. import config
         from ..utils.governor import ChunkStatus
         from ..utils.io_pipeline import PendingChunkStatus
-        from ..utils.jit import run_scanned
 
         if self._step_n_sent is None:
             raise RuntimeError(
@@ -726,25 +700,18 @@ class NavierEnsemble(Integrate):
         stats_on = self.model._stats_cc is not None
         done_before = self.steps_done  # fetched with the sentinel scalars
         with self._seams(n) as seams, self.model._scope():
-            # distinct buffers per slot: the dispatch donates the whole
-            # carry, and donation rejects the same buffer appearing twice
-            copied = seams.copy(
-                (self.state, self.mask, self.steps_done)
-                + ((self.stats_state, self._stats_tick) if stats_on else ())
-            )
-            carry = (
-                copied[0],
-                copied[1],
-                jnp.ones((self.k,), bool),
-                copied[2],
-                jnp.zeros((self.k,), rdt),  # per-member cfl max
-                jnp.zeros((self.k,), rdt),  # per-member ke growth max
-                jnp.zeros((self.k,), rdt),  # per-member |div| max
-                jnp.zeros((self.k,), rdt),  # per-member previous-step ke
-            ) + copied[3:]
-            carry = run_scanned(
-                seams.launcher(lambda c, k: self._step_n_sent(c, k)), carry, n
-            )
+            with seams.handover(fresh=5):
+                carry = (
+                    self.state,
+                    self.mask,
+                    jnp.ones((self.k,), bool),
+                    self.steps_done,
+                    jnp.zeros((self.k,), rdt),  # per-member cfl max
+                    jnp.zeros((self.k,), rdt),  # per-member ke growth max
+                    jnp.zeros((self.k,), rdt),  # per-member |div| max
+                    jnp.zeros((self.k,), rdt),  # per-member previous-step ke
+                ) + ((self.stats_state, self._stats_tick) if stats_on else ())
+            carry = seams.run(self._step_n_sent, carry, n)
         st, fin, cok, dn, cflm, gm, dvm, kep = carry[:8]
         snapshot = (
             self.state,
@@ -960,7 +927,8 @@ class NavierEnsemble(Integrate):
         PLAIN batched chunk — threading the snapshot's alive mask and step
         counters, so per-member freeze decisions replay exactly — and
         digest the resulting member states.  Bit-equal to the live chunk's
-        digests by XLA determinism, unless the state was corrupted."""
+        digests by XLA determinism, unless the state was corrupted.  The
+        snapshot is not consumed (the chunk donates nothing)."""
         from ..utils.jit import run_scanned
 
         if not self.integrity_armed:
@@ -969,11 +937,10 @@ class NavierEnsemble(Integrate):
                 "(set_integrity)"
             )
         with self.model._scope():
-            carry = jax.tree.map(
-                jnp.copy, (snap["state"], snap["mask"], snap["steps_done"])
-            )
             carry = run_scanned(
-                lambda c, k: self._step_n(c[0], c[1], c[2], k), carry, n
+                lambda c, k: self._step_n(*c, k),
+                (snap["state"], snap["mask"], snap["steps_done"]),
+                n,
             )
             return self._digest_future(self._dig_fn(carry[0]))
 

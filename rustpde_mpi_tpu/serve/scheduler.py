@@ -12,7 +12,7 @@ each request resolves.  The batching is LLM-style CONTINUOUS batching:
 * requests bucket by :attr:`SimRequest.compat_key` (the operator constants
   one compiled vmapped step can serve: grid, Ra/Pr, dt, geometry, BC),
 * a campaign opens one K-slot ensemble per bucket; each chunk advances
-  every running slot together as ONE donated vmapped dispatch,
+  every running slot together as ONE vmapped dispatch,
 * the chunk length is ``min(remaining steps of any running slot,
   chunk_steps)``, so completions land exactly on chunk boundaries,
 * a finished, diverged or idle slot is REFILLED from the queue at the

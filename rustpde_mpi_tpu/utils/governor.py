@@ -14,7 +14,7 @@ the solver factorizations:
   the step already materializes.  A step whose CFL exceeds ``max_cfl``
   early-exits the scan with a typed ``pre_divergence`` status *before* NaNs
   propagate, and the chunk is recovered by a cheap **in-memory rollback**
-  (the chunk-start snapshot the donation-safe dispatch already retains)
+  (the chunk-start snapshot: the state the dispatch took and did not donate)
   instead of the checkpoint-restore path,
 * **a geometric dt ladder** (:class:`DtLadder`): the controller only ever
   selects dt values ``dt_anchor * ratio**rung``, so the dt-baked solver

@@ -12,9 +12,9 @@ the ``jax.named_scope`` of the step stage it was traced under
 (``Navier2D._make_step``).  A fusion is counted under the stage of the
 instruction XLA took its metadata from.  Printed: ms per step by stage and
 solve, the share under no stage, and the share of device time whose
-instruction the chunk's text does not hold (the carry's copies, the
-observables).  Also: the program's ``rustpde:`` spans the trace's host plane
-holds, and how long after a launch span opens the device starts on its chunk.
+instruction the chunk's text does not hold (the observables).  Also: the
+program's ``rustpde:`` spans the trace's host plane holds, and how long after
+a launch span opens the device starts on its chunk.
 
 ``--size N`` shrinks the grid for a rehearsal on the CPU, where the table is
 empty (the CPU backend writes no device plane) and only control flow is shown.
@@ -156,7 +156,7 @@ def host_spans(path: str) -> dict:
     """The program's ``rustpde:`` spans on the trace's host plane as
     ``{name: [(start_ns, end_ns)]}``, and for each ``.launch`` span the time
     from its start to the start of the chunk program nearest to it on the
-    device (the ``XLA Modules`` row; the carry's copies are other modules).
+    device (the ``XLA Modules`` row; the observables are another module).
     Read with ``ProfileData`` itself: the reducer's ``load`` keeps only
     ``bench:`` spans and Python frames once the Python tracer is on."""
     from jax.profiler import ProfileData

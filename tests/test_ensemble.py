@@ -3,8 +3,8 @@
 The three contract points of the batched execution engine: a K-member
 vmapped step is bit-for-tolerance equivalent to K sequential solo runs (one
 physics code path), a diverging member freezes without corrupting the batch
-(per-member fault isolation), and buffer donation never invalidates a
-reference the user retained through the public API.
+(per-member fault isolation), and no dispatch donates or overwrites a
+buffer the user retained through the public API.
 """
 
 import jax
@@ -13,6 +13,8 @@ import numpy as np
 import pytest
 
 from rustpde_mpi_tpu import Navier2D, NavierEnsemble
+from rustpde_mpi_tpu.config import StabilityConfig, StatsConfig
+from rustpde_mpi_tpu.utils.jit import scan_buckets
 from rustpde_mpi_tpu.utils.profiling import benchmark_steps
 
 
@@ -93,22 +95,98 @@ def test_all_members_dead_triggers_exit():
     assert (np.asarray(ens.steps_done) == 0).all()
 
 
-def test_donation_preserves_retained_references():
-    # single-run path: the donated dispatch must never touch the buffers a
-    # caller retained through the public API
-    model = _model()
+def _sim(kind, branch="plain"):
+    """A model or a two-member ensemble with the ``branch`` of ``update_n``
+    armed: plain, the stats carry, or the sentinel carry."""
+    model = _model(dt=0.01)
     model.init_random(0.1, seed=0)
-    s0 = model.state
-    model.update_n(4)
-    assert np.isfinite(np.asarray(s0.temp)).all()  # no use-after-donate
-    assert model.state is not s0
-    # ensemble path: state, mask and counters are all donated
-    ens = NavierEnsemble.from_seeds(_model(), seeds=range(2))
-    e0, m0, d0 = ens.state, ens.mask, ens.steps_done
-    ens.update_n(4)
-    assert np.isfinite(np.asarray(e0.temp)).all()
-    assert np.asarray(m0).all() and (np.asarray(d0) == 0).all()
-    assert np.isfinite(np.asarray(ens.state.temp)).all()
+    if branch == "stats":
+        model.set_stats(StatsConfig(stride=2))
+    if branch == "sentinel":
+        model.set_stability(StabilityConfig())
+    if kind == "model":
+        return model
+    return NavierEnsemble.from_seeds(model, seeds=range(2))
+
+
+def _visible(sim):
+    """Every device buffer of the carry that the public API hands out."""
+    held = {"state": sim.state, "stats": sim.stats_state}
+    if isinstance(sim, NavierEnsemble):
+        held.update(mask=sim.mask, steps_done=sim.steps_done)
+    return held
+
+
+def _values(held):
+    """Host values of ``held``, read from device-side copies: a host read of
+    a CPU buffer pins it, and a pinned buffer cannot be donated, which would
+    make the test pass whatever the program did."""
+    return jax.device_get(jax.tree.map(jnp.copy, held))
+
+
+def _assert_untouched(held, values):
+    for leaf, value in zip(jax.tree.leaves(held), jax.tree.leaves(values)):
+        assert not leaf.is_deleted()  # no use-after-donate
+        np.testing.assert_array_equal(np.asarray(leaf), value)
+
+
+@pytest.mark.parametrize("n", [8, 14])  # one bucket, and three (8 + 4 + 2)
+@pytest.mark.parametrize("branch", ["plain", "stats", "sentinel"])
+@pytest.mark.parametrize("kind", ["model", "ensemble"])
+def test_donation_preserves_retained_references(kind, branch, n):
+    """No chunk donates a buffer the caller retained through the public
+    API, although nothing is copied for it any more (the name is from when
+    the chunk donated a copy)."""
+    assert len(scan_buckets(n)) == (1 if n == 8 else 3)
+    sim = _sim(kind, branch)
+    held = _visible(sim)
+    values = _values(held)
+    sim.update_n(n)
+    _assert_untouched(held, values)
+    assert sim.state is not held["state"]
+    assert np.isfinite(np.asarray(sim.state.temp)).all()
+    if kind == "ensemble":
+        assert (np.asarray(sim.steps_done) == n).all()
+    if branch != "sentinel":
+        return
+    # a CFL trip rolls the chunk back: what comes back is the very carry the
+    # caller saw before the call, which no bucket may have written to
+    if kind == "model":
+        sim.state = sim.state._replace(
+            velx=sim.state.velx * 200.0, vely=sim.state.vely * 200.0
+        )
+    else:
+        bad = jax.tree.map(lambda x: x * 300.0, sim.member_state(1))
+        sim.set_member(1, bad._replace(temp=sim.member_state(1).temp))
+    held = _visible(sim)
+    values = _values(held)
+    assert sim.update_n(n).pre_divergence
+    assert all(a is b for a, b in zip(jax.tree.leaves(_visible(sim)), jax.tree.leaves(held)))
+    _assert_untouched(held, values)
+
+
+@pytest.mark.parametrize("kind", ["model", "ensemble"])
+def test_chunk_takes_the_visible_carry_and_splits_bit_identically(kind):
+    """The chunk program run straight on the caller-visible carry: 4 steps
+    in one bucket and 2 + 2 in two end in the same bits, leave the carry
+    whole, and agree with four single ``update()`` steps to the suite's
+    tolerance."""
+    sim = _sim(kind)
+    carry = sim.state if kind == "model" else (sim.state, sim.mask, sim.steps_done)
+
+    def chunk(c, k):
+        return sim._step_n(c, k)[0] if kind == "model" else sim._step_n(*c, k)
+
+    whole = chunk(carry, 4)
+    split = chunk(chunk(carry, 2), 2)
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(carry))
+    for a, b in zip(jax.tree.leaves(split), jax.tree.leaves(whole)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for _ in range(4):
+        sim.update()
+    for a, b in zip(jax.tree.leaves(whole if kind == "model" else whole[0]),
+                    jax.tree.leaves(sim.state)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-9, atol=1e-12)
 
 
 def test_ensemble_snapshot_roundtrip(tmp_path):

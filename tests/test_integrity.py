@@ -87,6 +87,29 @@ def test_ensemble_member_digests_localize_the_flip():
     assert changed == [1]
 
 
+@pytest.mark.parametrize("n", [8, 14])  # one bucket, and three (8 + 4 + 2)
+@pytest.mark.parametrize("kind", ["model", "ensemble"])
+def test_shadow_digest_replays_the_live_chunk_and_keeps_its_snapshot(kind, n):
+    """The audit re-steps the snapshot through the live chunk's own
+    programs: same digest, and the snapshot, which nothing copies for the
+    audit, stays whole."""
+    import jax
+
+    from rustpde_mpi_tpu import NavierEnsemble
+
+    sim = _armed17()
+    if kind == "ensemble":
+        sim = NavierEnsemble.from_seeds(build_rbc17(), seeds=range(2))
+        sim.set_integrity(IntegrityConfig())
+    snap = sim.integrity_snapshot()
+    shadow = np.asarray(sim.shadow_digest_async(snap, n).result())
+    sim.update_n(n)
+    assert np.array_equal(shadow, _digest(sim))
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(snap["state"]))
+    again = np.asarray(sim.shadow_digest_async(snap, n).result())
+    assert np.array_equal(shadow, again)  # the snapshot was not consumed
+
+
 # -- runner: detection, rollback, bit-equality --------------------------------
 
 

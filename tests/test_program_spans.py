@@ -22,6 +22,7 @@ import pytest
 
 import jax
 
+from benchmark.meter import CompileMeter
 from rustpde_mpi_tpu import Navier2D, NavierEnsemble
 from rustpde_mpi_tpu.telemetry import FlightRecorder, compile_log
 from rustpde_mpi_tpu.telemetry import tracing as ttracing
@@ -164,17 +165,18 @@ def test_sibling_threads_do_not_adopt_each_others_parents(ring):
 @pytest.mark.parametrize("n", [8, 11])
 @pytest.mark.parametrize("kind", ["model", "ensemble"])
 def test_launches_counts_leaves_copied_and_buckets(ring, kind, n):
+    """Nothing is copied at the seam any more: ``launches`` is the buckets
+    (the name is from when it also counted the leaves copied)."""
     if kind == "model":
         sim = _model()
-        leaves = len(jax.tree.leaves(sim.state))
     else:
         sim = NavierEnsemble.from_seeds(_model(), seeds=[1, 2, 3], amp=0.1)
-        leaves = len(jax.tree.leaves((sim.state, sim.mask, sim.steps_done)))
     sim.update_n(n)
     (_, _, _, _, args), = ttracing.spans(f"{kind}.update_n")
     assert args["steps"] == n
-    assert args["launches"] == leaves + len(scan_buckets(n))
-    assert ttracing.spans(f"{kind}.carry_copy")[0][4]["leaves"] == leaves
+    assert args["launches"] == len(scan_buckets(n))
+    (copy,) = ttracing.spans(f"{kind}.carry_copy")  # the seam stays, empty
+    assert copy[4]["leaves"] == 0 and copy[4]["fresh"] == 0
     launched = ttracing.spans(f"{kind}.launch")
     assert [s[4]["steps"] for s in launched] == scan_buckets(n)
     assert not any(s[4]["aot"] for s in launched)
@@ -182,11 +184,51 @@ def test_launches_counts_leaves_copied_and_buckets(ring, kind, n):
         assert args["members"] == 3 and args["layer"] == "ensemble"
 
 
+@pytest.mark.parametrize("kind, fresh", [("model", 7), ("ensemble", 5)])
+def test_sentinel_seam_names_the_arrays_it_builds(ring, kind, fresh):
+    """The sentinel branch still builds its initial flags and maxima eagerly
+    inside ``carry_copy``: the span says how many, ``launches`` leaves them
+    out."""
+    from rustpde_mpi_tpu.config import StabilityConfig
+
+    model = _model()
+    model.set_stability(StabilityConfig())
+    sim = model if kind == "model" else NavierEnsemble.from_seeds(model, seeds=[1, 2], amp=0.1)
+    sim.update_n(11)
+    (copy,) = ttracing.spans(f"{kind}.carry_copy")
+    assert copy[4]["leaves"] == 0 and copy[4]["fresh"] == fresh
+    assert ttracing.spans(f"{kind}.update_n")[0][4]["launches"] == len(scan_buckets(11))
+
+
 def test_launch_span_says_when_a_prebuilt_executable_served_it(ring):
     m = _model()
     assert m.aot_compile(8) == 1
     m.update_n(8)
     assert [s[4]["aot"] for s in ttracing.spans("model.launch")] == [True]
+
+
+@pytest.mark.parametrize("chunk_steps", [8, 14])
+@pytest.mark.parametrize("kind", ["model", "ensemble"])
+def test_aot_compile_serves_every_bucket_of_the_schedule(ring, kind, chunk_steps):
+    """``aot_compile`` builds an executable for every bucket of the
+    schedule, so a dispatch of that length enters jit nowhere."""
+    if kind == "model":
+        sim = _model()
+    else:
+        sim = NavierEnsemble.from_seeds(_model(), seeds=[1, 2], amp=0.1)
+    buckets = scan_buckets(chunk_steps)
+    assert sim.aot_compile(chunk_steps) == len(buckets)
+    assert set(sim._aot_step_n) == set(buckets)
+    assert sim.aot_compile(chunk_steps) == 0  # all there already
+    kept = sim.state
+    meter = CompileMeter()  # counts every backend compile, cache loads included
+    sim.update_n(chunk_steps)
+    jax.block_until_ready(sim.state)
+    assert meter.compiles == 0
+    assert sim.aot_reuse_count == len(buckets)
+    launched = ttracing.spans(f"{kind}.launch")
+    assert [s[4]["aot"] for s in launched] == [True] * len(buckets)
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(kept))
 
 
 # -- (d) the scopes alter no device code ----------------------------------------
