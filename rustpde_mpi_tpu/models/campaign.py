@@ -293,6 +293,32 @@ class CampaignModelBase:
 
         return device_put(arr, SPEC)
 
+    def _exchanges_per_step(self) -> tuple:
+        """``(exchanges, bytes one device sends)`` of one step's hand-placed
+        pencil transposes; a model with manual regions says (``Navier2D``)."""
+        return 0, 0
+
+    def _mesh_span_args(self) -> dict:
+        """What a meshed model's ``model.update_n`` span says of its
+        decomposition: ``devices`` of the mesh, ``transposes`` and
+        ``exchange_bytes`` per step (:meth:`_exchanges_per_step`), and
+        ``replicated_leaves``, the state leaves that sit whole on every
+        device at dispatch.  Nothing without a mesh."""
+        mesh = getattr(self, "mesh", None)
+        if mesh is None:
+            return {}
+        import jax
+
+        transposes, sent = self._exchanges_per_step()
+        return {
+            "devices": int(mesh.size),
+            "transposes": transposes,
+            "exchange_bytes": sent,
+            "replicated_leaves": sum(
+                leaf.sharding.is_fully_replicated for leaf in jax.tree.leaves(self.state)
+            ),
+        }
+
     # -- compiled entry points ------------------------------------------------
 
     def _compile_entry_points(self) -> None:
@@ -614,7 +640,9 @@ class CampaignModelBase:
         (:meth:`clear_pre_divergence`)."""
         if self._step_n_sent is not None:
             return self._update_n_sentinel(n)
-        with DispatchSpans("model", _LAYER, steps=int(n)) as seams, self._scope():
+        with DispatchSpans(
+            "model", _LAYER, steps=int(n), **self._mesh_span_args()
+        ) as seams, self._scope():
             if self._step_n_stats is not None:
                 with seams.handover():
                     carry = (self.state, self.stats_state, self._stats_tick)
@@ -658,7 +686,9 @@ class CampaignModelBase:
         self._pre_div_latch = False
         rdt = config.real_dtype()
         stats_on = self._stats_cc is not None
-        with DispatchSpans("model", _LAYER, steps=int(n)) as seams, self._scope():
+        with DispatchSpans(
+            "model", _LAYER, steps=int(n), **self._mesh_span_args()
+        ) as seams, self._scope():
             # the running sums + tick ride the sentinel carry (and the
             # rollback snapshot below — a tripped chunk's samples are
             # discarded with its steps)

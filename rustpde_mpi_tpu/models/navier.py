@@ -353,6 +353,30 @@ class Navier2D(CampaignModelBase, Integrate):
             fc.check_compiles()  # typed refusal at build on a TPU
         return specs
 
+    def _exchanges_per_step(self) -> tuple:
+        """``(exchanges, bytes one device sends)`` of one step's hand-placed
+        pencil transposes, reckoned from the manual regions' block shapes
+        (parallel/decomp.Sharded*): three convection chains (four with the
+        passive scalar), the two convection-velocity syntheses and the
+        pressure-Poisson solve.  ``(0, 0)`` where no region is manual: on a
+        GSPMD-partitioned layout the compiler places the collectives and
+        nothing here can count them."""
+        if getattr(self, "_manual_poisson", None) is None:
+            return 0, 0
+        from ..parallel.decomp import sent_bytes
+
+        conv_u = self._conv_impl[id(self.velx_space)].exchanges
+        conv_t = self._conv_impl[id(self.temp_space)].exchanges
+        synth = self._manual_synth[id(self.velx_space)].exchanges
+        blocks = (
+            2 * conv_u
+            + (2 if self._scalar_active() else 1) * conv_t
+            + 2 * synth
+            + self._manual_poisson.exchanges
+        )
+        itemsize = np.dtype(config.real_dtype()).itemsize
+        return len(blocks), sent_bytes(blocks, self.mesh.size, itemsize)
+
     def _build_step_kernels(self):
         """Fused implicit-half stage kernels the step routes through
         (None: the dense solver chain).  Single-device only — meshed

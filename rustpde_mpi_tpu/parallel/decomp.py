@@ -219,24 +219,42 @@ def make_transpose_local(
     if method not in ("alltoall", "ring"):
         raise ValueError(f"unknown transpose method {method!r}")
     if method == "alltoall":
-        return (
-            Decomp2d.transpose_x_to_y_local if x_to_y else Decomp2d.transpose_y_to_x_local
-        )
-    if not _pallas_ring_available():
-        return functools.partial(_ring_transpose_ppermute, nprocs=nprocs, x_to_y=x_to_y)
-    fn = functools.partial(_ring_transpose_pallas, nprocs=nprocs, x_to_y=x_to_y)
-    if mesh is not None:
-        from ..ops.pallas_common import require_native_compile
+        fn = Decomp2d.transpose_x_to_y_local if x_to_y else Decomp2d.transpose_y_to_x_local
+    elif not _pallas_ring_available():
+        fn = functools.partial(_ring_transpose_ppermute, nprocs=nprocs, x_to_y=x_to_y)
+    else:
+        fn = functools.partial(_ring_transpose_pallas, nprocs=nprocs, x_to_y=x_to_y)
+        if mesh is not None:
+            from ..ops.pallas_common import require_native_compile
 
-        src, dst = (SPEC, PHYS) if x_to_y else (PHYS, SPEC)
-        require_native_compile(
-            "RUSTPDE_TRANSPOSE=ring (RUSTPDE_RING_IMPL=pallas; ppermute runs "
-            "the same ring through XLA's collective-permute)",
-            "ring_transpose",
-            _smap(fn, mesh, PartitionSpec(*src), PartitionSpec(*dst)),
-            jax.ShapeDtypeStruct((8 * nprocs, 128 * nprocs), jnp.float32),
-        )
-    return fn
+            src, dst = (SPEC, PHYS) if x_to_y else (PHYS, SPEC)
+            require_native_compile(
+                "RUSTPDE_TRANSPOSE=ring (RUSTPDE_RING_IMPL=pallas; ppermute runs "
+                "the same ring through XLA's collective-permute)",
+                "ring_transpose",
+                _smap(fn, mesh, PartitionSpec(*src), PartitionSpec(*dst)),
+                jax.ShapeDtypeStruct((8 * nprocs, 128 * nprocs), jnp.float32),
+            )
+    return _scoped(fn, x_to_y)
+
+
+def _scoped(exchange, x_to_y: bool):
+    """``exchange`` under the trace name of its direction (instruction
+    metadata only): every body :func:`make_transpose_local` hands out."""
+    name = "transpose_x_to_y" if x_to_y else "transpose_y_to_x"
+
+    def named(block):
+        with jax.named_scope(name):
+            return exchange(block)
+
+    return named
+
+
+def sent_bytes(blocks, nprocs: int, itemsize: int) -> int:
+    """Bytes ONE device sends for the equal-tile exchanges of the local
+    ``blocks`` (rows, cols): of each block a device keeps its own tile and
+    sends the other ``nprocs - 1``."""
+    return sum(r * c // nprocs * (nprocs - 1) for r, c in blocks) * itemsize
 
 
 def _ring_transpose_ppermute(block, *, nprocs: int, x_to_y: bool):
@@ -415,6 +433,10 @@ class ShardedConv:
 
         x2y = make_transpose_local(P, x_to_y=True, mesh=mesh)
         y2x = make_transpose_local(P, x_to_y=False, mesh=mesh)
+        # local blocks one apply exchanges (t1 and t0 out, fy back)
+        self.exchanges = (
+            (self.nxp, self.myp // P), (self.nxp, self.myp // P), (self.nxp // P, self.myfp),
+        )
 
         def region(gx1m, gx0m, gy0tm, gy1tm, fxm_, fytm, vb, uxb, uyb, bdxb, bdyb):
             # spectral x-pencil: x-axis locally full — synthesis(-of-d/dx)
@@ -446,13 +468,14 @@ class ShardedConv:
         pads = ((0, 0), (0, self.myp - self.my))
         z = jnp.zeros_like(ux) if bc_dx is None else bc_dx
         z2 = jnp.zeros_like(uy) if bc_dy is None else bc_dy
-        out = self._region(
-            self._gx1, self._gx0, self._gy0t, self._gy1t, self._fx, self._fyt,
-            jnp.pad(vhat, pads),
-            jnp.pad(ux, padp), jnp.pad(uy, padp),
-            jnp.pad(z, padp), jnp.pad(z2, padp),
-        )
-        return out[:, : self.myf]
+        with jax.named_scope("sharded_conv"):
+            out = self._region(
+                self._gx1, self._gx0, self._gy0t, self._gy1t, self._fx, self._fyt,
+                jnp.pad(vhat, pads),
+                jnp.pad(ux, padp), jnp.pad(uy, padp),
+                jnp.pad(z, padp), jnp.pad(z2, padp),
+            )
+            return out[:, : self.myf]
 
 
 class ShardedSynthesis:
@@ -482,6 +505,7 @@ class ShardedSynthesis:
             self._gx0 = jnp.asarray(pad(gx0, self.nxp, self.mx), dtype=rdt)
             self._gy0t = jnp.asarray(pad(gy0.T, self.myp, self.ny), dtype=rdt)
         x2y = make_transpose_local(P, x_to_y=True, mesh=mesh)
+        self.exchanges = ((self.nxp, self.myp // P),)
 
         def region(gx0m, gy0tm, vb):
             return x2y(gx0m @ vb) @ gy0tm
@@ -495,11 +519,12 @@ class ShardedSynthesis:
         )
 
     def apply(self, vhat):
-        out = self._region(
-            self._gx0, self._gy0t,
-            jnp.pad(vhat, ((0, 0), (0, self.myp - self.my))),
-        )
-        return out[: self.nx, :]
+        with jax.named_scope("sharded_synthesis"):
+            out = self._region(
+                self._gx0, self._gy0t,
+                jnp.pad(vhat, ((0, 0), (0, self.myp - self.my))),
+            )
+            return out[: self.nx, :]
 
 
 class ShardedPoisson:
@@ -548,6 +573,7 @@ class ShardedPoisson:
         y2x = make_transpose_local(P, x_to_y=False, mesh=mesh)
         my_in, myop = self.my_in, self.myop
         fwd1, bwd1 = self._fwd1, self._bwd1
+        self.exchanges = ((self.mxp, self.myip // P), (self.mxp // P, self.myop))
 
         def region(denom_blk, rhs_blk):
             t = x2y(rhs_blk)[:, :my_in]
@@ -567,11 +593,12 @@ class ShardedPoisson:
         )
 
     def solve(self, rhs):
-        out = self._region(
-            self._denom,
-            jnp.pad(rhs, ((0, self.mxp - self.mx), (0, self.myip - rhs.shape[1]))),
-        )
-        return out[: self.mx, : self.my_out]
+        with jax.named_scope("sharded_poisson"):
+            out = self._region(
+                self._denom,
+                jnp.pad(rhs, ((0, self.mxp - self.mx), (0, self.myip - rhs.shape[1]))),
+            )
+            return out[: self.mx, : self.my_out]
 
 
 # ---------------------------------------------------------------------------

@@ -2,6 +2,7 @@
 
     python scripts/stage_times.py --workload rbc513_f32.solo [--dispatches 2]
     python scripts/stage_times.py --workload swarm129_f32.batch --dispatches 6
+    python scripts/stage_times.py --workload periodic1024_f32.mesh4   (4 chips)
 
 Builds the cell's own model (``BENCHMARK.json`` and ``benchmark/`` say what a
 cell is), warms one dispatch, traces a few under ``utils/profiling.trace`` and
@@ -35,6 +36,10 @@ sys.path.insert(0, ROOT)
 STAGES = ("buoyancy", "synthesis", "sentinels", "momentum_x", "momentum_y", "divergence",
           "poisson", "projection", "pressure", "temperature", "scalar", "solid")
 INNER = ("convection", "helmholtz", "fastdiag", "tensor_solve")
+#: the mesh's own scopes (parallel/decomp.py): the manual regions and, inside
+#: them, each hand-placed exchange by direction
+REGIONS = ("sharded_conv", "sharded_synthesis", "sharded_poisson")
+EXCHANGES = ("transpose_x_to_y", "transpose_y_to_x")
 
 
 def stage_of(op_name: str) -> str | None:
@@ -44,7 +49,9 @@ def stage_of(op_name: str) -> str | None:
     if stage is None:
         return None
     inner = next((p for p in parts if p in INNER), None)
-    return f"{stage}/{inner}" if inner else stage
+    region = next((p for p in parts if p in REGIONS), None)
+    exchange = next((p for p in parts if p in EXCHANGES), None)
+    return "/".join(p for p in (stage, inner, region, exchange) if p)
 
 
 def scopes_of(text: str, short) -> dict:
@@ -98,7 +105,15 @@ def main(argv=None) -> int:
     g, ph = cfg["grid"], cfg["physics"]
     nx, ny = (args.size, args.size) if args.size else (g["nx"], g["ny"])
     n, k = int(traffic["steps_per_interval"]), int(traffic.get("members", 0))
-    model = Navier2D.new_confined(nx, ny, ph["ra"], ph["pr"], ph["dt"], ph["aspect"], ph["bc"])
+    if "new_periodic" in cfg["entry"]:
+        from rustpde_mpi_tpu.parallel.mesh import make_mesh
+
+        chips = int(traffic.get("mesh", 0))
+        model = Navier2D.new_periodic(
+            nx, ny, ph["ra"], ph["pr"], ph["dt"], ph["aspect"], ph["bc"],
+            mesh=make_mesh(jax.devices()[:chips]) if chips else None)
+    else:
+        model = Navier2D.new_confined(nx, ny, ph["ra"], ph["pr"], ph["dt"], ph["aspect"], ph["bc"])
     if k:
         sim = NavierEnsemble.from_seeds(model, seeds=list(range(k)), amp=traffic["amp"])
         lowered = sim._step_n_jit.lower(model._step_consts, sim.state, sim.mask, sim.steps_done, n=n)
@@ -106,7 +121,8 @@ def main(argv=None) -> int:
     else:
         sim = model
         model.init_random(0.1, seed=0)
-        lowered = model._step_n_jit.lower(model._step_consts, model.state, n=n)
+        with model._scope():
+            lowered = model._step_n_jit.lower(model._step_consts, model.state, n=n)
         read = model.get_observables
     scopes = scopes_of(compiled_text(lowered), reducer.short)
     sim.update_n(n), read()
@@ -136,6 +152,14 @@ def main(argv=None) -> int:
         print(f"  under a named stage {100 * named / total:.2f} %; in the chunk's text but under no "
               f"stage {100 * table.get('(no stage)', 0.0) / total:.2f} %; not in the chunk's text "
               f"{100 * unknown / total:.2f} %")
+    kinds: dict = {}
+    for name, seconds in red["ops"].items():
+        kind = re.match(r"(all-to-all|all-gather|collective-permute|all-reduce|reduce-scatter)", name)
+        if kind:
+            kinds[kind.group(1)] = kinds.get(kind.group(1), 0.0) + seconds
+    for kind, seconds in sorted(kinds.items(), key=lambda kv: -kv[1]):
+        print(f"  collective {kind:20s} {1e3 * seconds / steps:9.5f} ms/step  "
+              f"{100 * seconds / red['busy_s']:6.2f} % of busy (mean over the device planes)")
     host = host_spans(path)
     for name, found in sorted(host["spans"].items()):
         print(f"  host plane: {name} x {len(found)}, mean {1e-6 * sum(e - s for s, e in found) / len(found):.4f} ms")
@@ -147,7 +171,7 @@ def main(argv=None) -> int:
         with open(args.out, "w", encoding="utf-8") as fh:
             json.dump({"workload": args.workload, "device": dev.device_kind, "steps": steps,
                        "busy_s": red["busy_s"], "window_s": red["window_s"], "ops_s": total,
-                       "stages_s": table, "not_in_text_s": unknown,
+                       "stages_s": table, "collectives_s": kinds, "not_in_text_s": unknown,
                        "launch_to_device_us": host["launch_to_device_us"]}, fh, indent=1)
     return 0
 

@@ -62,6 +62,22 @@ def stepped_rbc17():
     return model
 
 
+@pytest.fixture
+def no_compile_cache():
+    """The persistent compile cache keys a program without its metadata, so
+    a hit hands back whatever names the first compilation had: compile
+    afresh where the names are what is read."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: heavyweight end-to-end test (skipped unless RUSTPDE_SLOW=1 or -m slow)"

@@ -1,0 +1,278 @@
+"""What the ``periodic1024_f32`` cells rest on, at sizes a CPU can hold:
+
+* the plain reference with a Fourier axis (``benchmark/reference_periodic.py``)
+  against the program's own float64 periodic path, and its three-pass control
+  against itself;
+* the program pencil-decomposed over a virtual CPU mesh against that same
+  reference, on the normal path (GSPMD places the collectives) and on the
+  manual one (``RUSTPDE_SEP=1``: the ``shard_map`` regions of
+  parallel/decomp.py with hand-placed all-to-alls);
+* what a meshed model's ``model.update_n`` span counts, against a hand count;
+* the scopes of parallel/decomp.py in the chunk's compiled text, and that they
+  change nothing else of it;
+* ``benchmark/work_periodic.py``'s count against a hand count, and the new
+  per-layer readers.
+"""
+
+import contextlib
+import importlib
+import re
+
+import numpy as np
+import pytest
+
+import jax
+
+from benchmark import check, work, work_periodic
+from benchmark.ic_periodic import smooth_periodic_fields
+from benchmark.reference import random_fields
+from benchmark.reference_periodic import Reference
+from rustpde_mpi_tpu import Navier2D, config
+from rustpde_mpi_tpu.parallel.decomp import Decomp2d
+from rustpde_mpi_tpu.parallel.mesh import make_mesh
+from rustpde_mpi_tpu.telemetry import FlightRecorder
+from rustpde_mpi_tpu.telemetry import tracing as ttracing
+
+PHYSICS = (1e6, 1.0, 2e-3, 1.0)  # ra, pr, dt, aspect
+FIELDS = ("temp", "velx", "vely", "pres")
+needs_x64 = pytest.mark.skipif(not config.X64, reason="the reference is pinned in float64")
+
+
+def gaps(model, ref, out) -> dict:
+    got = {k: model.get_field(k) for k in FIELDS}
+    want = {k: ref.backward(k, out[i]) for i, k in enumerate(FIELDS)}
+    return {k: float(np.linalg.norm(got[k] - want[k]) / np.linalg.norm(want[k])) for k in FIELDS}
+
+
+def reference_after(nx, ny, fields, steps):
+    ref = Reference(nx, ny, *PHYSICS, dtype=np.float64)
+    return ref, ref.run(ref.initial_state(fields), steps)
+
+
+# -- the reference ---------------------------------------------------------------
+
+
+@needs_x64
+@pytest.mark.parametrize("grid", [(16, 17), (32, 33)])
+def test_reference_is_pinned_to_the_programs_f64_periodic_path(grid):
+    """The program's CPU path in float64 (FFT along x, complex spectra, banded
+    solves) and the reference (dense products, its own operators) agree to
+    rounding after 10 steps from white noise."""
+    nx, ny = grid
+    model = Navier2D.new_periodic(nx, ny, *PHYSICS, "rbc")
+    model.init_random(0.1, seed=5)
+    model.update_n(10)
+    ref, out = reference_after(nx, ny, random_fields((nx, ny), 0.1, 5), 10)
+    assert max(gaps(model, ref, out).values()) < 1e-9
+
+
+@needs_x64
+def test_three_pass_control_reads_twenty_times_worse_than_float32():
+    """Against the float64 trajectory after 128 steps at 32 x 33 the float32
+    reference reads 2e-6..1e-5 and its ``bf16_3x`` control 2e-4..6e-4: the
+    nearest precision below is told apart by a factor of 20 at least."""
+    nx, ny = 32, 33
+    ic = smooth_periodic_fields(nx, ny, 2**31 + 3)
+    truth = check.reference_fields(Reference(nx, ny, *PHYSICS, dtype=np.float64), ic, 128)
+    ref = Reference(nx, ny, *PHYSICS)
+    sound = check.field_gaps(check.reference_fields(ref, ic, 128), truth)
+    control = check.field_gaps(check.reference_fields(ref, ic, 128, "bf16_3x"), truth)
+    for k in check.FIELDS:
+        assert sound[k] < 1e-4 and control[k] > 20.0 * sound[k], (k, sound, control)
+
+
+def test_initial_condition_is_periodic_solenoidal_and_no_slip():
+    nx, ny = 32, 33
+    ic = smooth_periodic_fields(nx, ny, 7, aspect=1.5)
+    ref = Reference(nx, ny, 1e6, 1.0, 2e-3, 1.5, dtype=np.float64)
+    _, velx, vely, _, _ = ref.initial_state(ic)
+    h = ref._host
+    div = 1j * h["k1"][:, None] * (velx @ h["ortho_T"]) + vely @ h["div_yT"]
+    assert np.abs(div).max() < 1e-12
+    for k in check.FIELDS:
+        assert np.abs(ic[k][:, [0, -1]]).max() < 1e-15, k  # the plates
+        assert np.abs(ref.backward(k, ref.initial_state(ic)[ref_index(k)]) - ic[k]).max() < 1e-12
+    assert smooth_periodic_fields(nx, ny, 7)["temp"].tolist() != \
+        smooth_periodic_fields(nx, ny, 8)["temp"].tolist()
+
+
+def ref_index(name: str) -> int:
+    return ("temp", "velx", "vely", "pres", "pseu").index(name)
+
+
+# -- the program on a mesh, against the same reference ---------------------------
+
+
+def meshed(monkeypatch, devices: int, path: str, nx=32, ny=33):
+    """The periodic model on ``devices`` virtual CPU devices in the layout a
+    TPU runs (split Re/Im spectra); ``manual`` forces the mixed-sep layout,
+    whose mesh path is the shard_map regions of parallel/decomp.py."""
+    monkeypatch.setenv("RUSTPDE_FORCE_TPU_PATH", "1")
+    if path == "manual":
+        monkeypatch.setenv("RUSTPDE_SEP", "1")
+    mesh = make_mesh(jax.devices()[:devices]) if devices else None
+    model = Navier2D.new_periodic(nx, ny, *PHYSICS, "rbc", mesh=mesh)
+    assert model.temp_space.bases[0].kind.is_split
+    assert (model._manual_poisson is not None) == (path == "manual" and bool(devices))
+    return model
+
+
+@needs_x64
+@pytest.mark.parametrize("path", ["normal", "manual"])
+@pytest.mark.parametrize("devices", [2, 4])
+def test_meshed_program_follows_the_reference(monkeypatch, devices, path):
+    nx, ny = 32, 33
+    model = meshed(monkeypatch, devices, path)
+    ic = smooth_periodic_fields(nx, ny, 2**31 + 11)
+    for name, values in ic.items():
+        model.set_field(name, values)
+    model.update_n(10)
+    ref, out = reference_after(nx, ny, ic, 10)
+    assert max(gaps(model, ref, out).values()) < 1e-9
+
+
+# -- the span's counters -----------------------------------------------------------
+
+
+@pytest.fixture
+def ring(monkeypatch):
+    rec = FlightRecorder(capacity=64)
+    monkeypatch.setattr(ttracing, "RECORDER", rec)
+    monkeypatch.setattr(ttracing, "_ENABLED", True)
+    return rec
+
+
+def last_update_n(model) -> dict:
+    model.update_n(2)
+    return ttracing.spans("model.update_n")[-1][-1]
+
+
+def test_span_counts_the_manual_exchanges(monkeypatch, ring):
+    """32 x 33 on 4 devices, mixed-sep layout.  Padded extents: nx 32, split
+    modes 34 -> 36, composite y 31 -> 32, ortho y 33 -> 36.  Local blocks a
+    step exchanges: a convection chain (32, 8) twice out and (8, 36) back,
+    three chains; a synthesis (32, 8), two; the Poisson solve (36, 9) out and
+    (9, 32) back: 13 exchanges.  A device keeps a quarter of each block and
+    sends three: 3 * (3 * (64 + 64 + 72)) + 2 * (3 * 64) + 3 * (81 + 72)
+    = 2643 numbers of 4 or 8 bytes."""
+    args = last_update_n(meshed(monkeypatch, 4, "manual"))
+    itemsize = 8 if config.X64 else 4
+    assert args["devices"] == 4
+    assert args["transposes"] == 13
+    assert args["exchange_bytes"] == 2643 * itemsize
+    # neither 31, 33 composite nor 33 ortho columns divide by 4: every leaf whole
+    assert args["replicated_leaves"] == 5
+
+
+def test_span_counts_no_exchange_where_the_compiler_places_them(monkeypatch, ring):
+    args = last_update_n(meshed(monkeypatch, 4, "normal"))
+    assert (args["devices"], args["transposes"], args["exchange_bytes"]) == (4, 0, 0)
+    assert args["replicated_leaves"] == 5
+    # 32 composite columns divide by 2 and 4; pres keeps its 34 ortho columns whole
+    model = meshed(monkeypatch, 2, "normal", ny=34)
+    assert last_update_n(model)["replicated_leaves"] == 0
+    model = meshed(monkeypatch, 4, "normal", ny=34)
+    assert last_update_n(model)["replicated_leaves"] == 1
+
+
+def test_span_of_an_unmeshed_model_says_nothing_of_a_mesh(monkeypatch, ring):
+    args = last_update_n(meshed(monkeypatch, 0, "normal"))
+    assert not {"devices", "transposes", "exchange_bytes", "replicated_leaves"} & set(args)
+
+
+# -- the scopes --------------------------------------------------------------------
+
+
+@contextlib.contextmanager
+def _no_scope(name):
+    yield
+
+
+def _strip(text: str) -> str:
+    blocks = [b for b in text.split("\n\n") if b.split("\n", 1)[0] not in
+              ("FileNames", "FunctionNames", "FileLocations", "StackFrames")]
+    return re.sub(r",? ?metadata=\{[^}]*\}", "", "\n\n".join(blocks))
+
+
+def test_mesh_scopes_are_in_the_chunks_text_and_change_nothing_else(monkeypatch, no_compile_cache):
+    def chunk_text(named: bool) -> str:
+        with monkeypatch.context() as mp:
+            if not named:
+                mp.setattr(jax, "named_scope", _no_scope)
+            m = meshed(mp, 2, "manual", nx=16, ny=17)
+            with m._scope():
+                return m._step_n_jit.lower(m._step_consts, m.state, n=4).compile().as_text()
+
+    named, bare = chunk_text(True), chunk_text(False)
+    assert _strip(named) == _strip(bare)
+    scopes = set(re.findall(r'op_name="([^"]*)"', named))
+    for want in ("/synthesis/sharded_synthesis/", "/momentum_x/convection/sharded_conv/",
+                 "/temperature/convection/sharded_conv/", "/poisson/sharded_poisson/",
+                 "transpose_x_to_y", "transpose_y_to_x"):
+        assert any(want in s for s in scopes), want
+    # every hand-placed exchange says its direction (the compiler adds its own elsewhere)
+    exchanges = [ln for ln in named.splitlines() if " all-to-all(" in ln and "/sharded_" in ln]
+    assert exchanges and all("/transpose_" in ln for ln in exchanges)
+    assert not any("transpose_" in s for s in re.findall(r'op_name="([^"]*)"', bare))
+
+
+@pytest.mark.parametrize("method", ["alltoall", "ring"])
+def test_global_view_transposes_carry_their_direction(no_compile_cache, method):
+    decomp = Decomp2d((9, 7), make_mesh(jax.devices()[:2]))
+    x = np.arange(63.0).reshape(9, 7)
+    for name in ("transpose_x_to_y", "transpose_y_to_x"):
+        fn = jax.jit(lambda a, _name=name: getattr(decomp, _name)(a, method=method))
+        np.testing.assert_array_equal(np.asarray(fn(x)), x)
+        assert f"/{name}/" in fn.lower(x).compile().as_text()
+
+
+# -- the yardstick's new pieces ------------------------------------------------------
+
+
+def test_work_count_against_a_hand_count():
+    """8 x 9: m = 5 complex modes.  16 Chebyshev products of 2 * 5 * 81 flops
+    and 11 Fourier products of 2 * 5 * 8 * 9."""
+    w = work_periodic.step_work(8, 9)
+    assert w["products"] == 27
+    assert w["flops"] == 16 * 810 + 11 * 720 == 20880
+    # five m x ny complex spectra in and out; 7 half y-operators, 2 half x-operators
+    assert w["bytes"] == 4 * (2 * 5 * 90 + 7 * 40.5 + 2 * 40.0)
+    full = work_periodic.step_work(1024, 1025)
+    assert full["flops"] == pytest.approx(2.909e10, rel=1e-3)
+    assert work.roofline(full, "TPU v5 lite", 1e-3)["bound"] == "compute"
+
+
+def _read(name, trace, run):
+    return importlib.import_module(f"benchmark.layer_metrics.{name}").read(trace, run)
+
+
+def test_roofline_reader_counts_the_mixs_chips():
+    run = {"traced_steps": 100, "cfg": {"grid": {"nx": 1024, "ny": 1025}},
+           "traffic": {}, "device": {"kind": "TPU v5 lite"}}
+    least = work_periodic.step_work(1024, 1025)["flops"] / 197e12
+    one = _read("periodic_step_roofline", {"busy_s": 0.1}, run)
+    assert one == pytest.approx(100.0 * least / 1e-3)
+    four = _read("periodic_step_roofline", {"busy_s": 0.1}, {**run, "traffic": {"mesh": 4}})
+    assert four == pytest.approx(one / 4.0)  # four chips could take a quarter of the time
+    assert _read("periodic_step_roofline", {"busy_s": 0.1}, {**run, "traced_steps": 0}) is None
+
+
+def test_collective_share_reader():
+    ops = {"all-gather.3 f32[1026,1025]": 0.02, "all-to-all.1 f32[8,36]": 0.01,
+           "collective-permute-start.2 f32[4]": 0.005, "all-reduce.9 f32[]": 0.005,
+           "fusion.7 f32[1026,1023]": 0.16}
+    assert _read("collective_share", {"ops": ops, "busy_s": 0.2}, {}) == pytest.approx(20.0)
+    # one device: no collective in the trace reads nothing, never 0
+    assert _read("collective_share", {"ops": {"fusion.7 f32[8]": 0.2}, "busy_s": 0.2}, {}) is None
+
+
+def test_replicated_leaves_reader(ring):
+    run = {"traced_dispatches": 2}
+    assert _read("replicated_leaves", {}, run) is None
+    for leaves in (0, 5, 5):
+        ring.add_complete("model.update_n", ring.now_us(), 700.0,
+                          {"id": 1, "parent": None, "replicated_leaves": leaves})
+    assert _read("replicated_leaves", {}, run) == 5.0
+    # the span of an unmeshed model (or of an older commit) has no such count
+    ring.add_complete("model.update_n", ring.now_us(), 700.0, {"id": 1, "parent": None})
+    assert _read("replicated_leaves", {}, run) is None

@@ -234,21 +234,6 @@ def test_aot_compile_serves_every_bucket_of_the_schedule(ring, kind, chunk_steps
 # -- (d) the scopes alter no device code ----------------------------------------
 
 
-@pytest.fixture
-def no_compile_cache():
-    """The persistent compile cache keys a program without its metadata, so
-    a hit hands back whatever names the first compilation had: compile
-    afresh where the names are what is read."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    before = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", before)
-    compilation_cache.reset_cache()
-
-
 @contextlib.contextmanager
 def _no_scope(name):
     yield
@@ -359,4 +344,4 @@ def test_benchmark_selfcheck_passes(capsys):
     from benchmark import selfcheck
 
     assert selfcheck.main([]) == 0
-    assert "8 readers agree" in capsys.readouterr().out
+    assert "11 readers agree" in capsys.readouterr().out
