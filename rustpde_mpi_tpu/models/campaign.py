@@ -293,6 +293,19 @@ class CampaignModelBase:
 
         return device_put(arr, SPEC)
 
+    def _hoist(self, fn, *example):
+        """``hoist_constants`` under this model's mesh, with the constants
+        committed to that mesh here, once, whole on every device
+        (``parallel.mesh.replicate``): every compiled entry point takes its
+        constants through this seam, so a dispatch places none.  Without a
+        mesh they are the arrays ``hoist_constants`` returned."""
+        from ..parallel.mesh import replicate
+        from ..utils.jit import hoist_constants
+
+        with self._scope():
+            converted, consts = hoist_constants(fn, *example)
+            return converted, replicate(consts)
+
     def _exchanges_per_step(self) -> tuple:
         """``(exchanges, bytes one device sends)`` of one step's hand-placed
         pencil transposes; a model with manual regions says (``Navier2D``)."""
@@ -303,11 +316,17 @@ class CampaignModelBase:
         decomposition: ``devices`` of the mesh, ``transposes`` and
         ``exchange_bytes`` per step (:meth:`_exchanges_per_step`), and
         ``replicated_leaves``, the state leaves that sit whole on every
-        device at dispatch.  Nothing without a mesh."""
+        device at dispatch, and ``unplaced_args``, the leaves of the chunk's
+        constants (counted where they are hoisted) and of the state that are
+        not laid out over the mesh's devices, each of which the dispatch
+        would place anew: 0 unless a path hands the chunk a single-device
+        array.  Nothing without a mesh."""
         mesh = getattr(self, "mesh", None)
         if mesh is None:
             return {}
         import jax
+
+        from ..parallel.mesh import unplaced
 
         transposes, sent = self._exchanges_per_step()
         return {
@@ -317,6 +336,7 @@ class CampaignModelBase:
             "replicated_leaves": sum(
                 leaf.sharding.is_fully_replicated for leaf in jax.tree.leaves(self.state)
             ),
+            "unplaced_args": self._unplaced_consts + unplaced(self.state, mesh),
         }
 
     # -- compiled entry points ------------------------------------------------
@@ -333,11 +353,18 @@ class CampaignModelBase:
         ROADMAP item needs that attribution separated from build time."""
         import time as _time
 
+        from ..parallel.mesh import unplaced
         from ..telemetry import compile_log
 
         t0 = _time.perf_counter()
         try:
             self._compile_entry_points_impl()
+            # the scanned chunks' constants, counted once per pass for the
+            # span's ``unplaced_args`` (:meth:`_mesh_span_args`)
+            self._unplaced_consts = unplaced(
+                (self._step_consts, self._stats_consts, self._sent_consts),
+                getattr(self, "mesh", None),
+            )
         finally:
             compile_log.observe_entry_compile(
                 str(getattr(self, "MODEL_KIND", type(self).__name__)),
@@ -347,8 +374,6 @@ class CampaignModelBase:
     def _compile_entry_points_impl(self) -> None:
         import jax
         import jax.numpy as jnp
-
-        from ..utils.jit import hoist_constants
 
         example = self._state_example()
         self.recompile_count += 1
@@ -366,9 +391,8 @@ class CampaignModelBase:
         self._dig_cc = None
         self._dig_consts = None
         self._dig_fn = None
-        with self._scope():
-            step_cc, step_consts = hoist_constants(self._make_step(), example)
-            obs_cc, obs_consts = hoist_constants(self._make_observables(), example)
+        step_cc, step_consts = self._hoist(self._make_step(), example)
+        obs_cc, obs_consts = self._hoist(self._make_observables(), example)
         self._step_consts = step_consts
         self._obs_consts = obs_consts
         # retained for the ensemble engine (models/ensemble.py): the SAME
@@ -451,13 +475,10 @@ class CampaignModelBase:
         import jax
         import jax.numpy as jnp
 
-        from ..utils.jit import hoist_constants
-
         eng = self._stats_engine
         sx = eng.state_example()
-        with self._scope():
-            stats_cc, stats_consts = hoist_constants(eng.accum_fn(), sx, example)
-            health_cc, health_consts = hoist_constants(eng.health_fn(), sx)
+        stats_cc, stats_consts = self._hoist(eng.accum_fn(), sx, example)
+        health_cc, health_consts = self._hoist(eng.health_fn(), sx)
         self._stats_cc = stats_cc
         self._stats_consts = stats_consts
         self._stats_health_cc = health_cc
@@ -550,12 +571,9 @@ class CampaignModelBase:
         import jax
         import jax.numpy as jnp
 
-        from ..utils.jit import hoist_constants
-
-        with self._scope():
-            sent_cc, sent_consts = hoist_constants(
-                self._make_step(with_sentinels=True), example
-            )
+        sent_cc, sent_consts = self._hoist(
+            self._make_step(with_sentinels=True), example
+        )
         self._sent_cc = sent_cc
         self._sent_consts = sent_consts
         ceiling = float(self._stability.max_cfl)
@@ -888,10 +906,8 @@ class CampaignModelBase:
         import jax
 
         from ..integrity import digest_tree
-        from ..utils.jit import hoist_constants
 
-        with self._scope():
-            dig_cc, dig_consts = hoist_constants(digest_tree, example)
+        dig_cc, dig_consts = self._hoist(digest_tree, example)
         self._dig_cc = dig_cc
         self._dig_consts = dig_consts
         dig_jit = jax.jit(dig_cc)
@@ -1055,6 +1071,7 @@ class CampaignModelBase:
         "_dig_cc",
         "_dig_consts",
         "_dig_fn",
+        "_unplaced_consts",
     )
 
     def _dt_artifacts(self) -> dict:

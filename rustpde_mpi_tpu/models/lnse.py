@@ -498,13 +498,10 @@ class Navier2DLnse(CampaignModelBase, Integrate):
         super()._compile_entry_points_impl()
         if self.NONLINEAR:
             return
-        from ..utils.jit import hoist_constants
-
         nav = self.navier
         example = self._state_example()
         adj = self._make_adjoint_step()
-        with nav._scope():
-            adj_cc, adj_consts = hoist_constants(lambda s: adj(s), example)
+        adj_cc, adj_consts = nav._hoist(lambda s: adj(s), example)
         self._adj_consts = adj_consts
 
         def adj_n(consts, state, n: int):
@@ -798,8 +795,6 @@ class Navier2DNonLin(Navier2DLnse):
         example = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), NavierState(*nav.state)
         )
-        from ..utils.jit import hoist_constants
-
         step = self._make_step()
         sp_u, sp_v, sp_t = nav.velx_space, nav.vely_space, nav.temp_space
 
@@ -814,17 +809,15 @@ class Navier2DNonLin(Navier2DLnse):
             )
             return new, hist
 
-        with nav._scope():
-            fwd_cc, fwd_consts = hoist_constants(fwd_with_history, example)
+        fwd_cc, fwd_consts = nav._hoist(fwd_with_history, example)
         adj = self._make_adjoint_step()
         sds = jax.ShapeDtypeStruct(
             nav.field_space.shape_spectral, nav.field_space.spectral_dtype()
         )
         hist_sds = (sds, sds, sds)
-        with nav._scope():
-            adj_cc, adj_consts = hoist_constants(
-                lambda s, h: adj(s, history=h), example, hist_sds
-            )
+        adj_cc, adj_consts = nav._hoist(
+            lambda s, h: adj(s, history=h), example, hist_sds
+        )
         self._fwd_consts = fwd_consts
         self._nl_adj_consts = adj_consts
 

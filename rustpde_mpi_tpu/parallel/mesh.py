@@ -95,11 +95,16 @@ def sharding(spec: tuple, ndim: int | None = None) -> NamedSharding | None:
 # (the pencils are cut, padded, INSIDE the step — measured on 4 devices at
 # 1025^2: f32[257,1025] dots, all-gathers at the flips, replicated result).
 # Committing the replicated layout from construction is therefore the steady
-# state made explicit: nothing sits uncommitted on device 0, and no
-# executable has to repartition a leftover compiler-chosen partial sharding
+# state made explicit: no leaf of the STATE sits uncommitted on device 0, and
+# no executable has to repartition a leftover compiler-chosen partial sharding
 # (the "[SPMD] Involuntary full rematerialization" a 17^2 `pres` on 8 devices
-# used to trigger on every dispatch).  Distributing such a state for real
-# needs padded storage (1023 -> 1024 columns); ROADMAP Queue 1 item 5.
+# used to trigger on every dispatch).  The same holds of the hoisted operator
+# CONSTANTS a meshed model's programs take as arguments only since they go
+# through ``replicate`` where they are hoisted (CampaignModelBase._hoist):
+# until then ``hoist_constants`` left them uncommitted on device 0 and every
+# dispatch laid them out over the mesh anew (49 ms a dispatch at 1024 x 1025
+# on four chips).  Distributing such a state, or the operators, for real needs
+# padded storage (1023 -> 1024 columns); ROADMAP Queue 2 A2.
 
 
 def constrain(x, spec: tuple):
@@ -165,4 +170,39 @@ def device_put(x, spec: tuple):
     )
     return jax.device_put(
         arr, NamedSharding(mesh, PartitionSpec(*([None] * arr.ndim)))
+    )
+
+
+def _on(leaf, devices: set) -> bool:
+    placed = getattr(leaf, "sharding", None)
+    return placed is not None and placed.device_set == devices
+
+
+def unplaced(tree, mesh: Mesh | None) -> int:
+    """How many leaves of ``tree`` are not laid out over exactly ``mesh``'s
+    devices: each costs a dispatch over the mesh a placement of its own,
+    every time.  0 without a mesh."""
+    if mesh is None:
+        return 0
+    devices = set(mesh.devices.flat)
+    return sum(not _on(leaf, devices) for leaf in jax.tree.leaves(tree))
+
+
+def replicate(tree):
+    """Commit every leaf of ``tree`` whole to every device of the active mesh
+    (``PartitionSpec()``), once.  A mesh program is compiled for the layout
+    its committed arguments have, so every dispatch finds such a leaf where
+    the executable wants it and moves nothing; an uncommitted one (whatever
+    ``jnp.asarray`` made, on device 0) is laid out over the mesh anew by
+    every dispatch that takes it.  A leaf already laid out over the mesh's
+    devices, whatever its spec, is kept.  Without an active mesh the argument
+    comes back as it is (the same objects), as from ``constrain`` and
+    ``device_put``."""
+    mesh = active_mesh()
+    if mesh is None:
+        return tree
+    devices = set(mesh.devices.flat)
+    whole = NamedSharding(mesh, PartitionSpec())
+    return jax.tree.map(
+        lambda leaf: leaf if _on(leaf, devices) else jax.device_put(leaf, whole), tree
     )

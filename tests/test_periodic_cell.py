@@ -177,7 +177,115 @@ def test_span_counts_no_exchange_where_the_compiler_places_them(monkeypatch, rin
 
 def test_span_of_an_unmeshed_model_says_nothing_of_a_mesh(monkeypatch, ring):
     args = last_update_n(meshed(monkeypatch, 0, "normal"))
-    assert not {"devices", "transposes", "exchange_bytes", "replicated_leaves"} & set(args)
+    assert not {"devices", "transposes", "exchange_bytes", "replicated_leaves",
+                "unplaced_args"} & set(args)
+
+
+# -- the hoisted constants, committed to the mesh where they are hoisted -----------
+
+CONSTS = ("_step_consts", "_obs_consts", "_stats_consts", "_stats_health_consts",
+          "_sent_consts", "_dig_consts")
+
+
+def off_the_mesh(model, names=CONSTS) -> dict:
+    """Per list of hoisted constants, the leaves that are not committed to
+    exactly the model's devices (a list that is not armed is None and counts
+    nothing: the test arms them all)."""
+    devices = set(model.mesh.devices.flat)
+    return {
+        name: sum(not (leaf.committed and leaf.sharding.device_set == devices)
+                  for leaf in jax.tree.leaves(getattr(model, name)))
+        for name in names
+    }
+
+
+def seeded(model):
+    for name, values in smooth_periodic_fields(32, 33, 2**31 + 11).items():
+        model.set_field(name, values)
+    return model
+
+
+def unplaced_args() -> int:
+    return ttracing.spans("model.update_n")[-1][-1]["unplaced_args"]
+
+
+def two_launches(model) -> list:
+    model.update_n(4)
+    model.update_n(4)
+    return [np.asarray(leaf) for leaf in jax.tree.leaves(model.state)]
+
+
+@pytest.mark.parametrize("path", ["normal", "manual"])
+@pytest.mark.parametrize("devices", [2, 4])
+def test_hoisted_constants_are_committed_to_the_mesh_once(monkeypatch, ring, devices, path):
+    """Every constant a meshed model's programs take sits whole on each of the
+    mesh's devices from the build on, and again after a ``set_dt`` rebuild:
+    a dispatch moves nothing (the transfer guard holds both launches, the
+    second of which finds no operand of the first left over), and the span
+    counts 0 ``unplaced_args``.  With ``replicate`` patched out (the parent's
+    behaviour) the programs take the same values from device 0: the state
+    comes out bit-identical on the normal path, the span counts the leaves
+    each dispatch places anew, and the guard refuses the launch."""
+    from rustpde_mpi_tpu.config import IntegrityConfig, StabilityConfig, StatsConfig
+    from rustpde_mpi_tpu.parallel import mesh as pmesh
+
+    model = seeded(meshed(monkeypatch, devices, path))
+    assert off_the_mesh(model, CONSTS[:2]) == {"_step_consts": 0, "_obs_consts": 0}
+    with jax.transfer_guard_device_to_device("disallow"):
+        placed = two_launches(model)
+        model.get_observables()
+    assert unplaced_args() == 0
+
+    model.set_integrity(IntegrityConfig())
+    model.set_stats(StatsConfig(stride=2))
+    model.set_stability(StabilityConfig())
+    assert off_the_mesh(model) == dict.fromkeys(CONSTS, 0)
+    before = model._step_consts
+    model.set_dt(model.dt / 2)
+    assert model._step_consts is not before
+    assert off_the_mesh(model) == dict.fromkeys(CONSTS, 0)
+    # the sentinel chunk with the stats riding its carry: its seven fresh flags
+    # and maxima are made eagerly per dispatch (the carry_copy span's ``fresh``)
+    # and are no constants, so the guard holds the digest's launch alone
+    model.update_n(2)
+    with jax.transfer_guard_device_to_device("disallow"):
+        model.state_digest_async().result()
+        model.stats_health_async().result()
+    assert unplaced_args() == 0
+    model.set_dt(model.dt * 2)  # a cached rung brings its own count back
+    assert model._step_consts is before and model._unplaced_consts == 0
+
+    monkeypatch.setattr(pmesh, "replicate", lambda tree: tree)
+    bare = seeded(meshed(monkeypatch, devices, path))
+    unplaced = off_the_mesh(bare, CONSTS[:2])
+    assert unplaced["_step_consts"] > 0 and unplaced["_obs_consts"] > 0
+    # left free on the manual path, the compiler cuts some of the unplaced
+    # operators along "p" itself and sums in another order: the same values to
+    # the rounding of the state's largest entries
+    slack = 0.0 if path == "normal" else 1e3 * np.finfo(placed[0].dtype).eps * max(
+        np.abs(leaf).max() for leaf in placed)
+    for got, want in zip(two_launches(bare), placed):
+        np.testing.assert_allclose(got, want, rtol=0, atol=slack)
+    assert unplaced_args() == unplaced["_step_consts"]
+    with pytest.raises(Exception, match="Disallowed device-to-device transfer"):
+        with jax.transfer_guard_device_to_device("disallow"):
+            bare.update_n(4)
+
+
+def test_unmeshed_constants_are_the_arrays_the_hoist_returned(monkeypatch):
+    from rustpde_mpi_tpu.utils import jit as ujit
+
+    returned, hoist = [], ujit.hoist_constants
+
+    def recording(fn, *example):
+        out = hoist(fn, *example)
+        returned.append(out[1])
+        return out
+
+    monkeypatch.setattr(ujit, "hoist_constants", recording)
+    model = meshed(monkeypatch, 0, "normal")
+    assert model._step_consts is returned[0] and model._obs_consts is returned[1]
+    assert model._unplaced_consts == 0
 
 
 # -- the scopes --------------------------------------------------------------------
