@@ -50,7 +50,7 @@ def run_proxy(args) -> int:
         fleet=fleet,
     )
     proxy.start()
-    # the bench driver parses this line for the ephemeral port
+    # a driver parses this line for the ephemeral port
     print(json.dumps({"proxy": proxy.proxy_id, "address": list(proxy.address)}),
           flush=True)
     stop = threading.Event()

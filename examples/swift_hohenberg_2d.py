@@ -2,8 +2,8 @@
 
 TPU rebuild of the reference's user-level "bring your own PDE" demo
 (/root/reference/examples/swift_hohenberg_2d.rs: 512^2, length=20, r=0.35,
-dt=0.02, integrate to t=1000 saving every 10).  BASELINE.json config #5 runs
-this at 2048^2 (use --nx 2048).  The IMEX step is diagonal in Fourier space;
+dt=0.02, integrate to t=1000 saving every 10; --nx 2048 runs it at
+2048^2).  The IMEX step is diagonal in Fourier space;
 on the TPU chip the transforms run as real MXU matmuls over the split Re/Im
 representation.
 """

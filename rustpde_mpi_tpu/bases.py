@@ -71,7 +71,7 @@ def _fast_deriv_enabled(n: int, sep: bool = False) -> bool:
     """Chebyshev derivatives via the parity-cumsum recurrence
     (ops/transforms.cheb_derivative) instead of dense triangular GEMMs.
     ``RUSTPDE_FAST_DERIV``: "auto" (default), "1" (always), "0" (never).
-    Auto is measured on the v5e (scripts/profile_step.py + /tmp A/B runs,
+    Auto is measured on the v5e (building blocks timed in isolation,
     round 3): f32 cumsum 0.22 vs GEMM 0.46 ms at 2049 but 0.11 vs 0.07 at
     1025 (dispatch/bandwidth bound), and in *emulated f64* the cumsum's scan
     ops are 2-5x slower than the MXU GEMM at every tested size — so the

@@ -27,9 +27,9 @@ from dataclasses import dataclass, field
 # tests/test_lint.py diffs all three against a grep of the source tree —
 # so a new knob cannot ship unregistered or undocumented, and a typo'd
 # read dies loudly instead of silently returning the default forever.
-# Driver-side code (bench.py, scripts/, tests/, examples/) may keep raw
+# Driver-side code (scripts/, tests/, examples/) may keep raw
 # ``os.environ`` reads, but its knob NAMES must still be registered
-# (scope "bench"/"test"); tools/lint rule RPD006 enforces the read-path
+# (scope "test"); tools/lint rule RPD006 enforces the read-path
 # rule inside the package (utils/faults.py stays raw by design: it must
 # not import this jax-loading module from inside the two-phase commit
 # window).
@@ -39,7 +39,7 @@ from dataclasses import dataclass, field
 class EnvKnob:
     """One registered environment knob: ``default`` is documentation of the
     effective default (None = unset means off/auto), ``scope`` names the
-    consuming layer (``lib`` | ``bench`` | ``test``)."""
+    consuming layer (``lib`` | ``test``)."""
 
     name: str
     default: str | None
@@ -149,9 +149,6 @@ register_knob("RUSTPDE_REQTRACE_EVENTS", "16384",
               "request-trace per-process event capacity per campaign")
 register_knob("RUSTPDE_PROFILE_MAX_S", "60",
               "cap on one POST /profile (or perf_degraded auto) capture")
-register_knob("RUSTPDE_TREND_BAND", "0.3",
-              "bench_trend noise band: regression when below (1-band)*best",
-              "bench")
 # resilience / watchdogs / fault injection
 register_knob("RUSTPDE_DISPATCH_TIMEOUT_S", None, "device-dispatch hang watchdog")
 register_knob("RUSTPDE_SYNC_TIMEOUT_S", "0",
@@ -202,30 +199,9 @@ register_knob("RUSTPDE_SANITIZE_INJECT", None,
 register_knob("RUSTPDE_COMPILE_CACHE", "1",
               "0 = do NOT arm the persistent JAX compilation cache in "
               "long-lived entry points (serve/replica/resilient sessions)")
-# bench drivers (bench.py — raw reads allowed, names registered)
-register_knob("RUSTPDE_BENCH_CONFIGS", None, "comma list of bench configs", "bench")
-register_knob("RUSTPDE_BENCH_STEPS", None, "bench step-count override", "bench")
-register_knob("RUSTPDE_BENCH_BUDGET_S", None, "bench wall budget", "bench")
-register_knob("RUSTPDE_BENCH_STARVE_LIMIT", "3",
-              "consecutive budget-starved skips before a config FAILS", "bench")
-register_knob("RUSTPDE_BENCH_ALLOW_CPU", None,
-              "1 = let a bench cell that finds no TPU run on the CPU "
-              "(function only: its rows are not device measurements)", "bench")
-register_knob("RUSTPDE_BENCH_SHARDED_N", "130",
-              "shardedio129 grid size override", "bench")
-register_knob("RUSTPDE_SERVE_BENCH_REQUESTS", None,
-              "serve129 soak request count", "bench")
-register_knob("RUSTPDE_SERVE_MP_REQUESTS", "4",
-              "serve129 2-proc leg request count", "bench")
-register_knob("RUSTPDE_FLEET_BENCH_REQUESTS", "10",
-              "serve129 fleet leg request count (proxy + 2 replicas)", "bench")
-register_knob("RUSTPDE_AUTOSCALE_BENCH_REQUESTS", "6",
-              "autoscale129 chaos leg request count (autoscaled fleet under "
-              "Poisson preemptions)", "bench")
-register_knob("RUSTPDE_GANG_BENCH_REQUESTS", "2",
-              "serve_submesh129 gang-sharded request count (the co-resident "
-              "vmapped count rides along, min 2)", "bench")
 # test harness (tests/ — raw reads allowed, names registered)
+register_knob("RUSTPDE_BENCH_SHARDED_N", "130",
+              "mp_worker bench_sharded grid size override", "test")
 register_knob("RUSTPDE_SLOW", None, "1 = run the slow test tier", "test")
 register_knob("RUSTPDE_TEST_BUDGET_S", "45", "per-test wall budget (fast tier)", "test")
 register_knob("RUSTPDE_TEST_TRACEBACK_S", None,
@@ -442,7 +418,7 @@ class StatsConfig:
 
     * ``stride`` — steps between samples (None: ``RUSTPDE_STATS_STRIDE``,
       default 16).  The sample cost is a handful of extra syntheses, so the
-      amortized overhead scales as ~1/stride (the bench gate holds it ≤5%),
+      amortized overhead scales as ~1/stride (not measured on the chip),
     * ``tail_warn`` — spectral-tail energy fraction (top third of the
       ortho spectrum, per field/axis) above which the runner journals a
       typed ``resolution_warning`` (None: ``RUSTPDE_STATS_TAIL_WARN``),
@@ -451,7 +427,7 @@ class StatsConfig:
       journals a typed ``budget_drift`` (None:
       ``RUSTPDE_STATS_BUDGET_WARN``).
 
-    The hard contract (CI- and bench-gated like the sentinel/telemetry
+    The hard contract (CI-asserted like the sentinel/telemetry
     layers): the accumulators READ the state and never feed back — the
     state trajectory is bit-identical stats-on vs stats-off."""
 
@@ -480,9 +456,9 @@ class IntegrityConfig:
       this no longer count toward the threshold (transient upsets decay,
       sticky-bad silicon accumulates).
 
-    The hard contract (bench-gated like the stats engine): the digest READS
+    The hard contract (CI-asserted like the stats engine): the digest READS
     the state and never feeds back — the trajectory is bit-identical
-    integrity-on vs integrity-off, overhead ≤2%."""
+    integrity-on vs integrity-off."""
 
     cadence: int | None = None
     strikes: int = 2
@@ -752,7 +728,7 @@ class CanonicalConfig:
     same physical end time), the grid/Ra/Pr/BC physics of the key, seeds,
     priority, or deadlines.  Every snap is journaled
     (``request_canonicalized``) and the result is guaranteed within
-    ``rtol`` of the un-canonicalized run (tests/bench gate it).
+    ``rtol`` of the un-canonicalized run (tests/test_coldstart.py).
 
     * ``dt_anchor`` / ``ladder_ratio`` — the service-wide rung grid
       (``dt = anchor * ratio**rung``); anchor defaults to the request

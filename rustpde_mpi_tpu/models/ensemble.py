@@ -31,8 +31,7 @@ points:
   is dead.  Graceful degradation, reported per member.
 * **batched observables / IO** — the fused ``(Nu, Nuvol, Re, |div|)``
   diagnostics vmap to shape ``(K,)``; snapshots write per-member groups
-  (utils/checkpoint.write_ensemble_snapshot); ``benchmark_steps`` reports
-  aggregate member-steps/s and ensemble MFU.
+  (utils/checkpoint.write_ensemble_snapshot).
 
 Composes with the pencil-sharding mesh: the member axis is a leading batch
 dim, which the transform layer replicates across shards (bases.Space2), so
@@ -160,7 +159,7 @@ class NavierEnsemble(Integrate):
 
     @property
     def ensemble_size(self) -> int:
-        """Member count (read by utils/profiling.benchmark_steps)."""
+        """Member count."""
         return self.k
 
     @property
@@ -582,12 +581,6 @@ class NavierEnsemble(Integrate):
         model = self.model
         dig_jit = jax.jit(jax.vmap(model._dig_cc, in_axes=(None, 0)))
         self._dig_fn = lambda st: dig_jit(model._dig_consts, st)
-
-    def _make_step(self):
-        """vmapped single-member step — profiling.step_flops introspects this
-        (the batched dot_generals in its jaxpr carry the K factor, so the
-        reported ensemble MFU is per dispatch, all members included)."""
-        return jax.vmap(self.model._make_step())
 
     def aot_compile(self, chunk_steps: int) -> int:
         """AOT-build the batched-chunk executables a ``chunk_steps``-sized

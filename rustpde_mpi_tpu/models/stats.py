@@ -9,7 +9,7 @@ is the production replacement: a :class:`StatsState` pytree of running sums
 carried *through the scanned step chunk* alongside the model state —
 
 * updated ON DEVICE at a configured ``stride`` (a handful of extra
-  syntheses per sample, ~1/stride amortized overhead, bench-gated ≤5%),
+  syntheses per sample, ~1/stride amortized overhead),
 * vmapped per ensemble member and pencil-sharded under a mesh (the
   accumulation is a pure function of one member state, so the batch axis
   and GSPMD propagation come for free),
@@ -25,7 +25,7 @@ What is accumulated (per member):
 * the legacy-parity set — running spectral-space sums of T (ortho, no BC
   lift), ux, uy, and the pointwise Nusselt field (with lift, dealiased) —
   the engine matches the eager legacy accumulator to fp tolerance
-  (PARITY.json ``"stats"``), and :func:`export_stats` writes the reference
+  (tests/test_stats.py), and :func:`export_stats` writes the reference
   ``statistics.h5`` layout plus engine extras,
 * x-averaged profiles: mean T, second moments of T/ux/uy (RMS profiles),
   convective flux ``uy*T``,

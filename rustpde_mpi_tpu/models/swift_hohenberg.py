@@ -206,8 +206,8 @@ class SwiftHohenberg1D(_SwiftHohenbergBase):
 
 class SwiftHohenberg2D(_SwiftHohenbergBase):
     """2-D Swift–Hohenberg on a doubly-periodic square of side
-    ``2*pi*length`` (/root/reference/examples/swift_hohenberg_2d.rs;
-    BASELINE.json config #5 at 2048^2)."""
+    ``2*pi*length`` (the upstream example
+    /root/reference/examples/swift_hohenberg_2d.rs)."""
 
     def __init__(self, nx: int, ny: int, r: float, dt: float, length: float):
         super().__init__(r, dt)
@@ -261,8 +261,8 @@ class SwiftHohenberg2D(_SwiftHohenbergBase):
         return step
 
     def pattern_energy(self) -> float:
-        """Domain-averaged theta^2 — the pattern-amplitude trace BASELINE
-        config #5 records."""
+        """Domain-averaged theta^2 — the pattern-amplitude trace of the
+        upstream example (examples/swift_hohenberg_2d.rs)."""
         v = self.theta_physical()
         return float(np.mean(v**2))
 

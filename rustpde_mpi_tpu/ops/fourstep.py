@@ -47,7 +47,7 @@ from .. import config
 # process-level (jax_enable_x64 at import) and cannot toggle mid-process.
 _MODE = config.env_get("RUSTPDE_FOURSTEP", "auto")
 # Per-kind auto thresholds on the DFT length, measured on the v5e in f32
-# (scripts/bench_transforms.py + scripts/profile_step.py): below these the
+# (scripts/bench_transforms.py): below these the
 # folded dense GEMM wins (it is one well-shaped MXU op; the factored path's
 # smaller-K stages + twiddle/mirror passes only pay off once the dense
 # O(n^2) bill is large enough).  Measured ratios dense/fourstep: r2c 0.44x

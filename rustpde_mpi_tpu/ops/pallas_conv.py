@@ -1,9 +1,10 @@
 """Pallas TPU kernel: the fused convection-transform chain.
 
-The convection family is 54-55% of step dot-flops on the confined
-flagships (71.5% at periodic1024; scripts/flops_breakdown.py), dispatched
-as ~22 separate XLA ops per step with full HBM round-trips between the
-derivative syntheses, the pointwise product, and the dealiased forward.
+The convection family is a third of the confined 513^2 step's device time
+and over half of the periodic 1024 x 1025 step's (PERF.md section 5),
+dispatched as ~22 separate XLA ops per step with full HBM round-trips
+between the derivative syntheses, the pointwise product, and the dealiased
+forward.
 This kernel fuses the whole chain
 
     dvdx = synthesis-of-d/dx(vhat)        (one GEMM per axis)
@@ -29,10 +30,10 @@ layout).  Interpreter mode runs the same kernel on CPU
 build raises ``PallasCompileRefused`` (ops/pallas_common.py).
 
 Selection stays measurement-driven like ``solver.default_method``:
-``RUSTPDE_CONV_KERNEL=dense|pallas`` (default dense until the on-chip A/B
-lands — ``bench.py pallasconv`` records ms/step, MFU and bit-tolerance
-deltas).  VMEM: the whole-width operands (``fyt``, the output block, the
-y-synthesis columns) are resident across grid steps and the kernel asks
+``RUSTPDE_CONV_KERNEL=dense|pallas`` (default dense until an on-chip A/B
+against the dense chain lands: ROADMAP Queue 1).  VMEM: the whole-width
+operands (``fyt``, the output block, the y-synthesis columns) are resident
+across grid steps and the kernel asks
 Mosaic for that much (``pallas_common.compiler_params``); a grid whose
 residency exceeds the core's VMEM is refused at build (output-column
 tiling would lift that).
@@ -238,22 +239,6 @@ class FusedConv:
                 "RUSTPDE_CONV_KERNEL=pallas", self.kernel_name, self.apply, *args
             )
 
-    # -- flop accounting (profiling.step_flops satellite) ---------------------
-
-    @property
-    def flops(self) -> float:
-        """Analytic MXU flops of ONE kernel invocation, at the UNPADDED
-        chain shapes (the useful model flops, directly comparable to the
-        dense path's jaxpr dot count) — registered with
-        utils/profiling.register_pallas_flops so the jaxpr walk (which sees
-        ``pallas_call`` as one opaque eqn) stays honest on this path.  Tile
-        padding shows up as *lower* MFU, which is the right signal for the
-        kernel-vs-dense A/B."""
-        stage1 = 2.0 * self.nx * self.mx * self.my * 2  # a1, a0
-        stage1 += 2.0 * self.nx * self.my * self.ny * 2  # y syntheses
-        epi = 2.0 * self.nx * self.ny * self.ky + 2.0 * self.kx * self.nx * self.ky
-        return stage1 + epi
-
     # -- the fused chain ------------------------------------------------------
 
     def _pallas_call(self, with_bc: bool, batch: bool = False):
@@ -344,7 +329,7 @@ class FusedConv:
 
     def reference(self, ux, uy, vhat, bc_dx=None, bc_dy=None, fast=True):
         """The unfused dense chain (exactly models/navier.py's ``conv``):
-        the A/B denominator of the parity tests and the pallasconv bench."""
+        the A/B denominator of the parity tests."""
         sp, fs = self.space_in, self.field_space
         dvdx = sp.backward_gradient(vhat, (1, 0), self.scale, fast=fast)
         dvdy = sp.backward_gradient(vhat, (0, 1), self.scale, fast=fast)
@@ -369,10 +354,7 @@ def hybrid_cast():
 def build_model_convs(model, interpret: bool | None = None) -> dict:
     """``{id(space): FusedConv}`` for a Navier-family model's convection
     spaces (velx/vely share one space object; temp has its own), keyed so
-    the step's ``conv(ux, uy, space, vhat)`` can route by identity.
-    Registers each kernel's analytic flops with utils/profiling."""
-    from ..utils import profiling
-
+    the step's ``conv(ux, uy, space, vhat)`` can route by identity."""
     cast = hybrid_cast()
     specs: dict[int, FusedConv] = {}
     for space in (model.velx_space, model.temp_space):
@@ -381,7 +363,6 @@ def build_model_convs(model, interpret: bool | None = None) -> dict:
         fc = FusedConv(space, model.field_space, model.scale, cast=cast,
                        interpret=interpret)
         specs[id(space)] = fc
-        profiling.register_pallas_flops(fc.kernel_name, fc.flops)
     return specs
 
 

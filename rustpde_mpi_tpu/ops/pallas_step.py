@@ -28,25 +28,24 @@ folding internals — so one generalized kernel covers all eight step stages:
   per-eigenvalue scaling -> modal backward GEMM) with the singular-mode pin
   folded as an output mask.  The same discrete system as solver.TensorSolver
   / the ``pallas_banded`` recurrence (tests/test_golden.py); the fast-diag
-  scaling form is the MXU-native choice, and ``bench.py bandedsolve``
-  records the recurrence-vs-GEMM crossover per PR.
+  scaling form is the MXU-native choice
+  (``pallas_banded.bench_banded_paths`` times the recurrence against it).
 * ``projx``/``projy`` — the pressure-gradient velocity correction
   (projection x gradient cross-space GEMMs), subtracted outside the kernel.
 
 Layouts: confined sep Chebyshev, split-sep periodic, and complex periodic
 (complex arrays convert to stacked ``[Re; Im]`` planes at the kernel
 boundary, exactly the ``FusedConv`` convention).  Interpreter mode runs the
-same kernels on CPU (tests/test_pallas_step.py + the PARITY.json
-``pallas_step`` probe); on a TPU they compile natively or the model build
+same kernels on CPU (tests/test_pallas_step.py); on a TPU they compile
+natively or the model build
 raises ``PallasCompileRefused`` (ops/pallas_common.py) — never interpreted
 there, never silently dense.  vmap/ensemble batching rides the standard
 ``pallas_call`` batching rule.
 
 Selection mirrors ``RUSTPDE_CONV_KERNEL``: ``RUSTPDE_STEP_KERNEL=dense|
-pallas`` (default ``dense`` until the on-chip A/B — ``bench.py pallasconv``
-grows a ``stepkernel`` leg recording ms/step, MFU, HBM-traffic estimate and
-parity deltas).  VMEM: each stage holds its whole-width right-side operand
-``R_t^T`` and the output block resident across grid steps and asks Mosaic
+pallas`` (default ``dense`` until an on-chip A/B against the dense chain
+lands: ROADMAP Queue 1).  VMEM: each stage holds its whole-width right-side
+operand ``R_t^T`` and the output block resident across grid steps and asks Mosaic
 for that much (``pallas_common.compiler_params``); a grid whose residency
 exceeds the core's VMEM is refused at build (output-column tiling would
 lift that).
@@ -201,7 +200,7 @@ class FusedStage:
     False on a TPU.
     ``reference()`` is the same chain unfused (plain XLA dots over the same
     padded constants) — the kernel-plumbing A/B; the model-level dense A/B
-    lives in tests/test_pallas_step.py and the bench stepkernel leg."""
+    lives in tests/test_pallas_step.py."""
 
     def __init__(self, name, terms, complex_out, const=None, modal=None,
                  mask=None, cast=None, interpret: bool | None = None,
@@ -316,24 +315,7 @@ class FusedStage:
             "RUSTPDE_STEP_KERNEL=pallas", self.kernel_name, self.apply, *example
         )
 
-    # -- flop / traffic accounting (profiling satellites) ---------------------
-
-    @property
-    def flops(self) -> float:
-        """Analytic MXU flops of ONE kernel invocation at the UNPADDED
-        shapes (useful model flops, comparable to the dense path's jaxpr dot
-        count) — registered with utils/profiling.register_pallas_flops.
-        Tile padding shows up as *lower* MFU, the honest A/B signal."""
-        f = 0.0
-        for k0, k1 in zip(self._k0, self._k1):
-            if self.has_l:
-                f += 2.0 * self.r0 * k0 * k1  # stage-1  L_t @ x_t
-            f += 2.0 * self.r0 * k1 * self.q1  # epilogue (.) @ R_t^T
-        if self._b1t is not None:
-            f += 2.0 * self.r0 * self.q1 * self.p1
-        if self._b0 is not None:
-            f += 2.0 * self.p0 * self.r0 * self.p1
-        return f
+    # -- traffic accounting ---------------------------------------------------
 
     @property
     def hbm_bytes(self) -> float:
@@ -552,11 +534,9 @@ def build_model_step(model, interpret: bool | None = None) -> dict:
     stage tag: ``velx``/``vely`` (inputs: state field, pres, [temp,] conv
     output[, cross-velocity when Coriolis is active]), ``temp``/``scal``
     (state field, conv output), ``div`` (velx_n, vely_n), ``poisson``
-    (div), ``projx``/``projy`` (pseu_n).  Registers each kernel's analytic
-    flops with utils/profiling.  Raises on layouts the fused step does not
-    cover (an active mesh routes around this builder)."""
+    (div), ``projx``/``projy`` (pseu_n).  Raises on layouts the fused step
+    does not cover (an active mesh routes around this builder)."""
     from .. import solver as slv
-    from ..utils import profiling
 
     sp_u, sp_t = model.velx_space, model.temp_space
     sp_p, sp_q, sp_f = model.pres_space, model.pseu_space, model.field_space
@@ -704,16 +684,14 @@ def build_model_step(model, interpret: bool | None = None) -> dict:
         f"projy_{nx}x{ny}",
         [term(p0u @ st0q, (p1u @ g1q1) / scale[1], sp_q, sep)], cplx, **kw)
 
-    for st in stages.values():
-        profiling.register_pallas_flops(st.kernel_name, st.flops)
     return stages
 
 
 def step_traffic_estimate(model) -> dict:
     """Analytic HBM bytes/step of the implicit (solve) half: the unfused
     dense chain vs the fused stage kernels — the quantity the megakernel
-    exists to shrink (recorded by the bench ``stepkernel`` leg: dense/fused
-    0.83 at 129^2, 1.41 at 257^2, 1.67 at 513^2, f64).  Uses the model's live fused stages when present,
+    exists to shrink (dense/fused 0.83 at 129^2, 1.41 at 257^2, 1.67 at
+    513^2, f64).  Uses the model's live fused stages when present,
     else builds a throwaway set."""
     stages = getattr(model, "_step_impl", None)
     if stages is None:

@@ -190,8 +190,8 @@ class Decomp2d:
 
 def transpose_method() -> str:
     """The RUSTPDE_TRANSPOSE knob (default ``alltoall``) — selection stays
-    measurement-driven like solver.default_method; ``bench.py pallasconv``
-    records the A/B when a chip is attached."""
+    measurement-driven like solver.default_method; no cell has timed the
+    ring form yet (ROADMAP Queue 1)."""
     return env_get("RUSTPDE_TRANSPOSE", "alltoall")
 
 

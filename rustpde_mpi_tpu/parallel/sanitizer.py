@@ -29,7 +29,7 @@ immediate, located diagnosis within one cadence.
 Overhead contract: ``RUSTPDE_SANITIZE`` unset/0 costs one module-bool
 branch per collective and records nothing — runs are bit-identical (the
 sanitizer is host-side only and never touches traced programs; armed runs
-are bit-identical too, gated in ``bench.py governor129``).  Armed, each
+are bit-identical too: tests/test_sanitizer.py).  Armed, each
 record is a frame walk + sha256 update — microseconds against the
 milliseconds any real collective costs.
 
@@ -140,8 +140,8 @@ def enabled() -> bool:
 
 
 def set_enabled(flag: bool) -> None:
-    """Arm/disarm in-process (``RUSTPDE_SANITIZE`` env default; the bench
-    overhead leg and tests toggle this)."""
+    """Arm/disarm in-process (``RUSTPDE_SANITIZE`` env default; tests toggle
+    this)."""
     _STATE.enabled = bool(flag)
 
 

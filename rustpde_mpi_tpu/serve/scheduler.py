@@ -110,11 +110,6 @@ from .fleet.gang import GangMemberLost
 from .queue import DurableQueue
 from .request import AdmissionError, RequestFailed, SimRequest
 
-_MFU_HELP = (
-    "model-flops utilization per compat bucket, against the bf16 peak of the "
-    "attached device_kind"
-)
-
 
 class _ServedEnsemble(NavierEnsemble):
     """Ensemble whose checkpoints are self-describing for the scheduler:
@@ -285,13 +280,9 @@ class SimServer:
         self._prev_handlers: dict = {}
         self._http = None
         # live serve telemetry (telemetry/metrics.py): slot occupancy of the
-        # ACTIVE campaign, the member-rate mark for the steps/s + MFU gauges,
-        # and the per-member step flops of the campaign model (trace-only
-        # jaxpr count, computed once per campaign build)
+        # ACTIVE campaign and the member-rate mark for the steps/s gauge
         self._slots_state: tuple[int, int] = (0, int(self.cfg.slots))
         self._rate_mark: tuple[float, int] = (time.monotonic(), 0)
-        self._flops_member: float | None = None
-        self._peak_flops: float | None = None
         # compile/device attribution bookkeeping (telemetry/compile_log):
         # the active bucket's label, the campaign-open stamp the
         # time-to-first-chunk histogram measures from, and its one-shot flag
@@ -1535,20 +1526,6 @@ class SimServer:
                     "recompile": False,
                 }
             )
-        # per-member step flops for the live MFU gauge: the trace-only jaxpr
-        # dot count (no extra compile; the entry points were just built).
-        # The gauge divides by the published bf16 peak of the attached
-        # device_kind; a chip without a table entry leaves it UNSET.
-        from ..utils import profiling
-
-        try:
-            self._peak_flops = profiling.device_peak().bf16_flops
-        except profiling.UnknownDevicePeak:
-            self._peak_flops = None
-        try:
-            self._flops_member = profiling.step_flops(model, method="jaxpr")
-        except Exception:
-            self._flops_member = None
         rcfg = self.cfg.resilience
         runner = ResilientRunner.from_config(
             ens,
@@ -1726,16 +1703,10 @@ class SimServer:
             self._slots_state = (0, int(self.cfg.slots))
             # host-local teardown only on this path (no collectives on a
             # possibly-exceptional exit): unbind the active trace ids and
-            # zero the fleet + this bucket's MFU gauges between campaigns
-            # (a labeled gauge left at its last in-flight value would read
-            # as phantom utilization on every later scrape)
+            # zero the fleet gauges between campaigns (a gauge left at its
+            # last in-flight value would read as phantom utilization on
+            # every later scrape)
             _rt.clear_active()
-            if self._peak_flops:
-                _tm.gauge(
-                    "serve_mfu",
-                    _MFU_HELP,
-                    bucket=self._bucket_tag,
-                ).set(0.0)
             _tm.gauge(
                 "serve_fleet_utilization",
                 "running-slot fraction of the fleet (0 between campaigns)",
@@ -1878,20 +1849,13 @@ class SimServer:
 
     def _close_gang(self) -> None:
         """Host-local gang teardown on every campaign exit path: unbind
-        the fault scope, zero the per-gang gauges, release the lease
-        group (LeaseLost = a survivor already broke us: fine, its
-        cleanup is authoritative)."""
+        the fault scope, release the lease group (LeaseLost = a survivor
+        already broke us: fine, its cleanup is authoritative)."""
         if self._gang_active is None:
             return
-        info, self._gang_active = self._gang_active, None
+        self._gang_active = None
         if self._fault is not None:
             self._fault.bind_gang(None, None)
-        if self._peak_flops:
-            _tm.gauge(
-                "serve_gang_mfu",
-                "model-flops utilization per gang sub-mesh",
-                gang=str(info["gang"]),
-            ).set(0.0)
         if self._fleet is None:
             return
         from .fleet.lease import LeaseLost
@@ -2497,10 +2461,9 @@ class SimServer:
         """Refresh the live queue/throughput gauges at one chunk boundary —
         host-side bookkeeping the scheduler already holds (slot occupancy
         is kept by :meth:`_refresh_slot_state` at claim/release time, so
-        the gauge and ``slot_info()`` can never disagree).  MFU is labeled
-        PER BUCKET (``profiling.step_flops`` of this campaign's model ×
-        measured member rate), and the per-device memory watermarks refresh
-        here too (None-safe: CPU backends report nothing)."""
+        the gauge and ``slot_info()`` can never disagree).  The per-device
+        memory watermarks refresh here too (None-safe: CPU backends report
+        nothing)."""
         _tm.gauge("serve_queue_depth", "requests waiting in queued/").set(
             self.queue.counts()["queued"]
         )
@@ -2512,21 +2475,6 @@ class SimServer:
                 "serve_member_steps_per_sec",
                 "aggregate member-steps/s across running slots",
             ).set(rate)
-            if self._flops_member and self._peak_flops:
-                mfu = self._flops_member * rate / self._peak_flops
-                _tm.gauge(
-                    "serve_mfu",
-                    _MFU_HELP,
-                    bucket=self._bucket_tag,
-                ).set(mfu)
-                if self._gang_active is not None:
-                    # the per-gang view of the same quantity: one labeled
-                    # series per carved sub-mesh, zeroed at campaign close
-                    _tm.gauge(
-                        "serve_gang_mfu",
-                        "model-flops utilization per gang sub-mesh",
-                        gang=str(self._gang_active["gang"]),
-                    ).set(mfu)
         self._rate_mark = (now, self._member_steps)
         _cl.update_device_memory_gauges()
 

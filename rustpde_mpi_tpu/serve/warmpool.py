@@ -131,7 +131,7 @@ class WarmPool:
     bucket-open.  ``take`` transfers OWNERSHIP — a taken entry is gone (the
     campaign mutates the ensemble in place), so a second campaign for the
     same key is a miss by design.  Hit/miss/eviction accounting rides
-    telemetry/compile_log so tests and the bench read one source of truth;
+    telemetry/compile_log so tests and operators read one source of truth;
     ``journal`` (when given) gets the durable copies."""
 
     def __init__(
@@ -281,7 +281,7 @@ class WarmPool:
         return ent["model"], ent["ens"]
 
     def counts(self) -> dict:
-        """Accounting snapshot (tests + the bench payload)."""
+        """Accounting snapshot (read by tests)."""
         with self._lock:
             pooled = len(self._pool)
         return {

@@ -15,10 +15,9 @@ governor, io pipeline, serve scheduler — see README "Telemetry"):
   bounded flight recorder, auto-dumped as Perfetto ``traceEvents`` JSON on
   DispatchHang / DivergenceError / SIGTERM drain / unclean exit.
 
-Hard contract (CI + bench gated): telemetry records host-side values the
-run already computed — it never touches traced programs, instrumented runs
-are bit-identical to ``RUSTPDE_TELEMETRY=0`` runs, and the combined
-metrics+tracing overhead stays within the ``governor129`` 2% wall gate.
+Hard contract (CI-asserted): telemetry records host-side values the
+run already computed — it never touches traced programs, and instrumented
+runs are bit-identical to ``RUSTPDE_TELEMETRY=0`` runs.
 """
 
 from .exporters import (  # noqa: F401
@@ -64,7 +63,7 @@ from .reqtrace import set_enabled as set_reqtrace_enabled  # noqa: F401
 
 def set_enabled(flag: bool) -> None:
     """Master switch: metrics AND tracing AND request tracing together
-    (the bench gate's OFF leg; ``RUSTPDE_TELEMETRY=0`` / ``RUSTPDE_TRACE=0``
+    (the OFF leg of the bit-identity tests; ``RUSTPDE_TELEMETRY=0`` / ``RUSTPDE_TRACE=0``
     / ``RUSTPDE_REQTRACE=0`` set the per-layer defaults at import)."""
     set_metrics_enabled(flag)
     set_tracing_enabled(flag)

@@ -98,7 +98,7 @@ def observe_build(key, wall_s: float, kind: str = "", phase: str = "build") -> d
 
 
 def build_counts() -> dict:
-    """Per-key in-process build counts (tests + the bench payload)."""
+    """Per-key in-process build counts (read by tests)."""
     with _lock:
         return dict(_builds)
 
@@ -115,7 +115,7 @@ def last_build_wall(key) -> float:
 def observe_warm_pool(event: str, key=None, k: int | None = None, **extra) -> dict:
     """Warm-pool accounting (serve/warmpool.py): ``event`` is one of
     ``hit`` / ``miss`` / ``evict`` / ``aot``; returns the journal-ready
-    payload.  Counters ride the shared metrics registry so the bench and
+    payload.  Counters ride the shared metrics registry so the journal and
     the hit-rate gates read one source of truth."""
     with _lock:
         _warm_pool[event] = _warm_pool.get(event, 0) + 1
@@ -141,7 +141,7 @@ def observe_warm_pool(event: str, key=None, k: int | None = None, **extra) -> di
 
 
 def warm_pool_counts() -> dict:
-    """Warm-pool event counts (tests + the bench payload), a copy."""
+    """Warm-pool event counts (read by tests), a copy."""
     with _lock:
         return dict(_warm_pool)
 

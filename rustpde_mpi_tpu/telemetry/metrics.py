@@ -3,14 +3,13 @@ histograms behind a thread-safe registry.
 
 The reference stack's only runtime visibility was printf-style interval
 dumps (rustpde-mpi's per-interval info lines); this repo grew the same gap
-at scale — the runner journals, the bench JSON and the serve ``/stats``
-endpoint are all *post-hoc*.  This module is the live half: every layer
+at scale — the runner journals and the serve ``/stats``
+endpoint are *post-hoc*.  This module is the live half: every layer
 (runner, governor, io pipeline, serve scheduler) records into ONE default
 registry, and the exporters (telemetry/exporters.py: Prometheus ``/metrics``
 text + cadenced ``metrics.jsonl``) read it without touching the writers.
 
-Design constraints, carried as CI gates (tests/test_telemetry.py and the
-``governor129`` bench leg):
+Design constraints, carried as CI gates (tests/test_telemetry.py):
 
 * **never touch traced programs** — metrics record host-side scalars the
   run already fetched (chunk statuses, journal fields, queue counts);
@@ -20,8 +19,8 @@ Design constraints, carried as CI gates (tests/test_telemetry.py and the
   O(buckets) counters at any time while memory stays bounded regardless of
   observation count,
 * **cheap when off** — :func:`set_enabled` (or ``RUSTPDE_TELEMETRY=0``)
-  routes every handle lookup to a shared no-op metric; the overhead budget
-  (metrics+tracing ON vs OFF within 2% wall) is bench-gated,
+  routes every handle lookup to a shared no-op metric (a span costs
+  3.1-3.4 us on the chip's host with the recorder on: PERF.md section 6),
 * **multihost** — each host owns a local registry;
   :func:`gather_global_snapshot` exchanges JSON-encoded snapshots over the
   existing ``multihost.allgather_host`` and merges them (counters and
@@ -50,8 +49,8 @@ def enabled() -> bool:
 
 
 def set_enabled(flag: bool) -> None:
-    """Turn metric recording on/off globally (the bench overhead gate's
-    OFF leg and a kill switch for pathological environments).  Off routes
+    """Turn metric recording on/off globally (the OFF leg of the
+    bit-identity tests and a kill switch for pathological environments).  Off routes
     every registry lookup to one shared no-op metric — existing handles
     held by callers keep working, they just came from an earlier lookup."""
     global _ENABLED

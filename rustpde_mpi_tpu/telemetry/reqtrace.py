@@ -57,8 +57,8 @@ def enabled() -> bool:
 
 
 def set_enabled(flag: bool) -> None:
-    """Toggle request-trace recording (the bench overhead gate's OFF leg
-    rides ``telemetry.set_enabled``, which calls this too)."""
+    """Toggle request-trace recording (``telemetry.set_enabled`` calls
+    this too)."""
     global _ENABLED
     _ENABLED = bool(flag)
 

@@ -50,8 +50,8 @@ module adds the production-harness layer on top of the ``integrate`` driver:
 * **deterministic fault injection** — ``RUSTPDE_FAULT=nan@<step>`` /
   ``spike@<step>`` / ``kill@<step>`` / ``slow@<step>`` (or the ``fault=``
   argument) exercises every recovery path — including every governor path,
-  via the finite velocity-spike incipient blow-up — in tests and
-  ``bench.py`` without waiting for real failures.
+  via the finite velocity-spike incipient blow-up — in tests without
+  waiting for real failures.
 
 This checkpoint/resume/watchdog shape is exactly the preemption-safe
 training-loop pattern (ROADMAP north star): swap "spectral coefficients" for

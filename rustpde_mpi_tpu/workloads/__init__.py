@@ -11,7 +11,7 @@ contract (models/campaign.py).
 * ``modifiers`` — the scenario axis: config-carried step modifiers
   (rotating frame, passive scalar) and the vmapped solid-mask geometry
   sweep,
-* ``parity`` — per-model solo-vs-ensemble drift probe (PARITY.json).
+* ``parity`` — per-model solo-vs-ensemble drift probe.
 """
 
 from .eigenmodes import (  # noqa: F401

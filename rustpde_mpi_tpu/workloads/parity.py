@@ -2,11 +2,10 @@
 
 One tiny campaign per registered model kind: K=2 members stepped as a
 vmapped ensemble vs the same trajectories stepped solo, with the maximum
-relative state-leaf deviation recorded per kind.  ``scripts/record_tests.py``
-runs this and lands the numbers in PARITY.json (`"workloads"` key) so a
-vmap/scan/refactor regression in ANY model's batched path shows up as a
-per-PR delta next to the existing Nu-parity numbers — not months later in
-a campaign.
+relative state-leaf deviation reported per kind.  tests/test_workloads.py
+holds every kind under 1e-9, so a vmap/scan/refactor regression in ANY
+model's batched path fails the PR that made it — not months later in a
+campaign.
 """
 
 from __future__ import annotations

@@ -1,20 +1,17 @@
 """A/B the f64 hybrid (RUSTPDE_F64_HYBRID=1: f32 convection transforms
 feeding f64 solves — SURVEY S7, VERDICT r4 next #3b) against pure f64.
 
-Two legs, each isolated in subprocesses (the sep-operator cache is built
-from the env once per process):
+The parity leg, each side isolated in a subprocess (the sep-operator cache
+is built from the env once per process; ``--cpu`` runs it on the CPU): the
+PARITY.json flagship trajectory (129^2 Ra=1e7, 500 steps) run on the forced
+TPU path with and without the hybrid; reports the per-sample relative Nu
+drift hybrid-vs-pure.  The f32 budget for this statistic is ~3e-5
+(PARITY.json max_drift); the hybrid must not exceed that scale, since its
+only degradation is f32 convection roundoff.  What the hybrid is worth in
+steps per second is a benchmark cell's to say (PERF.md section 7,
+``rbc513_f64.solo``; ROADMAP Queue 1 item 6).
 
-* ``--parity`` (CPU-safe): the PARITY.json flagship trajectory (129^2
-  Ra=1e7, 500 steps) run on the forced TPU path with and without the
-  hybrid; reports the per-sample relative Nu drift hybrid-vs-pure.  The
-  f32 budget for this statistic is ~3e-5 (PARITY.json max_drift); the
-  hybrid must not exceed that scale, since its only degradation is f32
-  convection roundoff.
-* ``--perf`` (TPU): slope-timed step rates of the two f64 flagships
-  (1025^2, 2049^2) with hybrid off/on, via bench.bench_navier in X64
-  subprocesses.  Does NOT touch BENCH_FULL.json.
-
-Writes F64_HYBRID_AB.json at the repo root (legs merge across runs).
+Writes F64_HYBRID_AB.json at the repo root.
 """
 
 import argparse
@@ -85,60 +82,22 @@ def run_parity(cpu: bool) -> dict:
     }
 
 
-def run_perf() -> dict:
-    res: dict = {}
-    for name, call in (
-        ("rbc1025_f64", "bench.bench_navier(1025,1025,1e9,1e-4,16)"),
-        ("rbc2049_f64", "bench.bench_navier(2049,2049,1e9,5e-5,4)"),
-    ):
-        res[name] = {}
-        for hybrid in ("0", "1"):
-            code = f"import bench, json; print(json.dumps({call}))"
-            out = _child(
-                code, {"RUSTPDE_X64": "1", "RUSTPDE_F64_HYBRID": hybrid}
-            )
-            r = json.loads(out.strip().splitlines()[-1])
-            res[name]["hybrid" if hybrid == "1" else "pure"] = {
-                k: r[k]
-                for k in ("steps_per_sec", "ms_per_step", "nu", "finite")
-                if k in r
-            }
-            print(f"# {name} hybrid={hybrid}: {r['steps_per_sec']:.1f} steps/s")
-        a = res[name]["pure"]["steps_per_sec"]
-        b = res[name]["hybrid"]["steps_per_sec"]
-        res[name]["speedup"] = b / a
-    return res
-
-
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--parity", action="store_true")
-    ap.add_argument("--perf", action="store_true")
-    ap.add_argument("--cpu", action="store_true", help="parity leg on CPU")
+    ap.add_argument("--cpu", action="store_true", help="run on the CPU")
     args = ap.parse_args()
-    if not (args.parity or args.perf):
-        args.parity = args.perf = True
 
     path = os.path.join(REPO, "F64_HYBRID_AB.json")
-    try:
-        with open(path) as f:
-            record = json.load(f)
-    except (OSError, ValueError):
-        record = {}
-    if args.parity:
-        record["parity"] = run_parity(args.cpu)
-        print(
-            f"parity: max Nu drift hybrid-vs-pure = "
-            f"{record['parity']['max_nu_drift']:.3e} "
-            f"(budget 3e-5, passed={record['parity']['passed']})"
-        )
-    if args.perf:
-        record["perf"] = run_perf()
+    record = {"parity": run_parity(args.cpu)}
+    print(
+        f"parity: max Nu drift hybrid-vs-pure = "
+        f"{record['parity']['max_nu_drift']:.3e} "
+        f"(budget 3e-5, passed={record['parity']['passed']})"
+    )
     with open(path, "w") as f:
         json.dump(record, f, indent=1)
     print(f"wrote {path}")
-    ok = record.get("parity", {}).get("passed", True)
-    return 0 if ok else 1
+    return 0 if record["parity"]["passed"] else 1
 
 
 if __name__ == "__main__":

@@ -6,14 +6,14 @@ Reruns the 4096-step Ra=1e9 f32 comparison that justified defaulting
 ``RUSTPDE_SYNTH_PRECISION=high``: two identical 1025^2 trajectories from the
 same deterministic IC, one with the fast synthesis variants, one forced to
 "highest", and writes their Re/Nu/Nuvol/|div| statistics to
-``FAST_SYNTH_VALIDATION.json`` at the repo root, next to BENCH_FULL.json.
+``FAST_SYNTH_VALIDATION.json`` at the repo root.
 
 Each variant runs in its own subprocess: the synthesis-precision env is read
 at operator-build time and Base instances are interned process-wide
 (bases._BASE_CACHE), so toggling the env inside one process would alias the
 ("bwd","fast") device matrices between variants.
 
-The short-horizon shadow gate (bench.py) bounds per-step numerics; this
+tests/test_tpu_path.py bounds the per-step numerics of f32 against f64; this
 script bounds the *statistics* over a long chaotic stretch — pointwise fields
 decorrelate (positive Lyapunov), so the gates compare windowed means:
 mean Re and mean Nu over the second half must agree to the thresholds below,
@@ -127,8 +127,8 @@ def main() -> int:
             and fa["finite"]
         ),
     }
-    # repo root, next to BENCH_FULL.json (data/ is gitignored and this
-    # artifact is the committed evidence for the default-precision choice)
+    # repo root: data/ is gitignored and this artifact is the committed
+    # evidence for the default-precision choice
     out_path = os.path.join(_REPO, "FAST_SYNTH_VALIDATION.json")
     with open(out_path, "w") as f:
         json.dump(result, f, indent=1)

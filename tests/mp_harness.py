@@ -1,11 +1,9 @@
 """Shared 2-process CPU cluster spawner for tests/mp_worker.py.
 
 One copy of the spawn recipe (port allocation, CPU/virtual-device env,
-worker argv order, sequential communicate) used by BOTH
-tests/test_multiprocess.py and bench.py's ``shardedio129`` config, so the
-bench harness can never drift from the tested one.  Deliberately imports
-no jax: the parent (possibly TPU-bound bench process) must not have its
-platform touched.
+worker argv order, sequential communicate) for every test that needs a
+real 2-controller cluster.  Deliberately imports no jax: the parent must
+not have its platform touched.
 """
 
 from __future__ import annotations

@@ -15,9 +15,10 @@ One process of an N-process ``jax.distributed`` run on CPU devices.  Modes
   parent test can kill one host between shard fsync and manifest commit
   and prove recovery.  Rank 0 dumps the final global state (allgathered)
   so the parent can assert elastic restore is bit-equal.
-* ``bench_sharded`` — times sharded-vs-gathered checkpoint writes for
-  ``bench.py shardedio129`` (repetitions, bytes/host, and the final-state
-  dump for the parent's cross-topology restore gate).
+* ``bench_sharded`` — writes the same state sharded (two-phase) and
+  gathered, with repetitions, bytes/host and the final-state dump for a
+  parent's cross-topology restore check.  No test drives it since its
+  caller went in PR 28 (ROADMAP Queue 3).
 * ``serve_campaign`` — runs a :class:`~rustpde_mpi_tpu.serve.SimServer`
   across the 2-process mesh (root-coordinated scheduling: root owns the
   queue/journal, every slot decision is broadcast).  Root enqueues

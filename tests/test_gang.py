@@ -7,8 +7,7 @@ scoping, and the CI guard that the default (``submesh=None``) service
 emits not one gang journal row and keeps today's bare bucket keys.
 
 The 2-process gang campaign itself (formation, SIGKILL containment,
-loss-free reclaim) runs in tests/test_multiprocess.py's slow tier and
-the ``serve_submesh129`` bench leg.
+loss-free reclaim) runs in tests/test_multiprocess.py's slow tier.
 """
 
 import os

@@ -200,7 +200,6 @@ def test_governed_stable_run_bit_identical(tmp_path):
     assert s1["health"] is None  # ungoverned runs carry no telemetry
 
 
-@pytest.mark.slow
 def test_spike_caught_pre_divergence_in_memory(tmp_path):
     """The acceptance demo: a deterministic velocity spike.  Governed, the
     CFL sentinel early-exits the chunk BEFORE NaNs, the rollback happens in

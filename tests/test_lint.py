@@ -719,8 +719,53 @@ def test_removed_knobs_stay_gone():
         for tail in (
             "COMPILE_CACHE" + "_DIR", "BENCH" + "_CHILD", "BENCH" + "_SLACK_S",
             "BENCH_PROBE" + "_TIMEOUT_S",
+            # PR 28: the second benchmark's knobs went with it
+            "BENCH" + "_CONFIGS", "BENCH" + "_STEPS", "BENCH" + "_BUDGET_S",
+            "BENCH" + "_STARVE_LIMIT", "BENCH" + "_ALLOW_CPU", "TREND" + "_BAND",
+            "SERVE" + "_BENCH_REQUESTS", "SERVE" + "_MP_REQUESTS",
+            "FLEET" + "_BENCH_REQUESTS", "AUTOSCALE" + "_BENCH_REQUESTS",
+            "GANG" + "_BENCH_REQUESTS",
         )
     }
     assert not gone & set(config.env_knobs())
     assert not gone & _grep_knob_names()
     assert not gone & _readme_knob_names()
+
+
+# -- one benchmark, one yardstick, one stage timer, one record (PR 28) ---------
+#
+# The second of each went: the 25-cell driver script beside benchmark/, its
+# trend gate and record files, the flop count that followed the implementation
+# with the utilisation gauges priced by it, and the slope timers.  Code,
+# tests, README and the verify notes may not name them again; the records that
+# tell the history (and the issue) may.  (Assembled from pieces so that this
+# file passes its own check.)
+
+_SECOND_OF_EACH = re.compile(
+    "|".join(
+        (
+            r"\bbench" + r"\.py", "bench" + "_trend", "TREND" + r"\.json",
+            "BASELINE" + r"\.json", "BENCH" + "_FULL", "record" + "_tests",
+            "profile" + "_step", "flops" + "_breakdown", "step" + "_flops",
+            "mfu" + "_estimate", "benchmark" + "_steps", "serve" + "_mfu",
+            "serve_gang" + "_mfu", "register_pallas" + "_flops",
+        )
+    )
+)
+_HISTORY_FILES = _DRIVER_FILES | {
+    "CHANGES.md", "SURVEY.md", "PERF.md", "ROADMAP.md",
+}
+
+
+def test_the_second_benchmark_and_yardstick_stay_gone():
+    hits = [
+        f"{rel}:{lineno}: {line.strip()[:100]}"
+        for rel, lines in _tracked_text_files()
+        if rel not in _HISTORY_FILES
+        for lineno, line in enumerate(lines, 1)
+        if _SECOND_OF_EACH.search(line)
+    ]
+    assert not hits, "\n".join(hits)
+    for rel in ("bench" + ".py", "TREND" + ".json", "BASELINE" + ".json",
+                "TESTS" + ".json", "scripts/profile" + "_step.py"):
+        assert not os.path.exists(os.path.join(REPO, rel)), rel

@@ -292,25 +292,7 @@ def test_default_dense_builds_no_kernels(monkeypatch):
     assert m._step_impl is None
 
 
-# -- profiling / traffic accounting -------------------------------------------
-
-
-def test_step_flops_registered(monkeypatch):
-    """Every fused stage registers analytic unpadded flops under its
-    shape-keyed kernel name, and the jaxpr pricing of the fused step stays
-    comparable to the dense chain (MFU gauges survive the knob flip)."""
-    from rustpde_mpi_tpu.utils import profiling
-
-    dense = _build_navier(False)
-    f_dense = profiling.step_flops(dense, method="jaxpr")
-    monkeypatch.setenv("RUSTPDE_STEP_KERNEL", "pallas")
-    pal = _build_navier(False)
-    f_pal = profiling.step_flops(pal, method="jaxpr")
-    for stage in pal._step_impl.values():
-        assert profiling.PALLAS_FLOPS[stage.kernel_name] == stage.flops
-        assert stage.flops > 0
-    assert f_pal > 0.5 * f_dense
-    assert f_pal < 4.0 * f_dense
+# -- traffic accounting --------------------------------------------------------
 
 
 def test_step_traffic_estimate(monkeypatch):

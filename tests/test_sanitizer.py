@@ -76,12 +76,47 @@ def test_single_process_verify_is_noop(armed):
 
 def test_values_unchanged_when_armed(armed):
     # host-side only: the sanitizer must never alter what the collectives
-    # return (bit-identity of full runs is gated in bench.py governor129)
+    # return (bit-identity of a full run: the test below)
     assert int(multihost.broadcast(np.int32(41))) == 41
     assert multihost.root_decides(True) is True
     assert multihost.root_decides(False) is False
     out = multihost.allgather_host(np.float64(2.5))
     assert out.shape == (1,) and float(out[0]) == 2.5
+
+
+def test_armed_run_bit_identical_to_sanitizer_off(tmp_path, monkeypatch):
+    """The sanitizer is host-side only: a governed runner stepped through
+    its boundaries with the sanitizer armed ends in the same bits as the
+    same run with it off, and the armed run did record its handshakes (or
+    the comparison would be vacuous)."""
+    from model_builders import build_rbc17
+    from rustpde_mpi_tpu import ResilientRunner
+    from rustpde_mpi_tpu.config import StabilityConfig
+
+    states, records = {}, {}
+    monkeypatch.delenv("RUSTPDE_SANITIZE_INJECT", raising=False)
+    try:
+        for key in ("on", "off"):
+            monkeypatch.setenv("RUSTPDE_SANITIZE", "1" if key == "on" else "0")
+            sanitizer.reset()
+            model = build_rbc17()
+            model.set_stability(StabilityConfig())
+            runner = ResilientRunner(
+                model,
+                max_time=float("inf"),
+                run_dir=str(tmp_path / key),
+                checkpoint_every_s=None,
+                max_chunk_steps=4,
+            )
+            runner.advance(16)
+            states[key] = [np.asarray(leaf) for leaf in model.state]
+            records[key] = sanitizer.stats()["records"]
+    finally:
+        monkeypatch.setenv("RUSTPDE_SANITIZE", "0")
+        sanitizer.reset()
+    assert records["on"] > 0 and records["off"] == 0
+    for a, b in zip(states["on"], states["off"]):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_np_schema():

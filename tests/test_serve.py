@@ -1180,3 +1180,46 @@ def test_compile_attribution_rides_serve_journal(tmp_path):
     # the done records carry the HA gate metric
     done = [e for e in events if e["event"] == "request_done"]
     assert all(e["first_observable_s"] > 0 for e in done)
+
+
+def test_build_runner_traces_the_step_only_for_its_entry_points(tmp_path, monkeypatch):
+    """Building a campaign traces the member step as often as compiling its
+    entry points does (the K-member ensemble's vmapped chunk) and not once
+    more: no second pass over the step for a flop count, on every bucket
+    visit of the service loop."""
+    from rustpde_mpi_tpu.serve import scheduler
+    from rustpde_mpi_tpu.workloads.registry import build_model_for_key
+
+    calls = []
+    make_step = Navier2D._make_step
+
+    def counted(self, *args, **kwargs):
+        calls.append(1)
+        return make_step(self, *args, **kwargs)
+
+    monkeypatch.setattr(Navier2D, "_make_step", counted)
+    key = SimRequest(**_REQ).compat_key
+    model = build_model_for_key(key, mesh=None)
+    scheduler._ServedEnsemble(model, [model.state] * 2)
+    entry_points = len(calls)
+    assert entry_points >= 1
+    del calls[:]
+    srv = SimServer(_cfg(tmp_path, slots=2))
+    srv._build_runner(key, k=2)
+    assert len(calls) == entry_points
+
+
+def test_served_run_reports_member_rate_and_no_utilisation_gauge(tmp_path):
+    """The rate an operator can act on is set at the chunk boundaries; no
+    series prices it against a peak (the yardstick is the benchmark's, one
+    flop counted once, and lives with it)."""
+    from rustpde_mpi_tpu.telemetry import metrics as tm
+
+    srv = SimServer(_cfg(tmp_path, slots=2))
+    for s in range(3):
+        srv.submit(dict(_REQ, seed=s))
+    assert srv.serve()["completed"] == 3
+    snap = tm.REGISTRY.snapshot()
+    rate = snap["serve_member_steps_per_sec"]["series"]
+    assert rate and rate[0]["value"] > 0
+    assert not [name for name in snap if name.endswith("_mfu")]

@@ -321,6 +321,27 @@ def test_stats_span_exact_across_dt_rung_moves():
     assert float(np.asarray(m.stats_state.samples)[0]) == 8
 
 
+def test_kinetic_energy_budget_closes_after_spin_up():
+    """The budget readout means something: past the spin-up transient the
+    kinetic-energy balance (buoyancy production - viscous dissipation -
+    dKE/dt, over the larger of the first two) closes to under 5 % (0.7 %
+    here at 33^2; 5-12 % at 17^2, which is too coarse to close it), over a
+    window that holds samples, and the
+    Nu-consistency residual, which converges only in stationarity, is
+    finite."""
+    m = Navier2D(33, 33, 1e4, 1.0, 0.01, 1.0, "rbc", periodic=False)
+    m.set_velocity(0.1, 1.0, 1.0)
+    m.set_temperature(0.1, 1.0, 1.0)
+    m.set_stats(StatsConfig(stride=_STRIDE))
+    m.update_n(32)
+    m.reset_stats()  # the window covers the flow after the transient only
+    m.update_n(64)
+    health = m.stats_summary()
+    assert health["samples"] == 64 // _STRIDE
+    assert 0.0 <= health["ke_residual"] < 0.05
+    assert np.isfinite(health["nu_residual"]) and health["nu_residual"] < 3.0
+
+
 @pytest.mark.slow
 def test_stats_resolution_elastic_restore_restarts_window(tmp_path, capsys):
     """Review regression: the gathered format restores elastically across

@@ -250,16 +250,22 @@ def test_throughput_monitor_rate_limited():
 # -- runner integration --------------------------------------------------------
 
 
-def test_instrumented_run_bit_identical_to_telemetry_off(tmp_path):
+@pytest.mark.parametrize("traced_request", [False, True])
+def test_instrumented_run_bit_identical_to_telemetry_off(tmp_path, traced_request):
     """THE hard constraint, CI-asserted: telemetry must never touch traced
     programs — the full runner path with metrics+tracing ON produces a
-    final state byte-identical to the same run with telemetry OFF."""
+    final state byte-identical to the same run with telemetry OFF; with a
+    request's trace id bound to a slot too, so that the span annotator of
+    the request-tracing layer is on the path as it is under the server."""
+    from rustpde_mpi_tpu.telemetry import reqtrace
+
     states = {}
     seams = {}
     prev = tmetrics.enabled()
     try:
         for key, on in (("on", True), ("off", False)):
             telemetry.set_enabled(on)
+            reqtrace.bind_slots({0: "trace0000"} if on and traced_request else {})
             ttracing.RECORDER.clear()
             m = _model(seed=3)
             runner = ResilientRunner(
@@ -277,7 +283,12 @@ def test_instrumented_run_bit_identical_to_telemetry_off(tmp_path):
                 for n in ("dispatch", "model.update_n", "model.carry_copy", "model.launch")
             }
     finally:
+        reqtrace.clear_active()
         telemetry.set_enabled(prev)
+    if traced_request:  # the annotator was on the path, not beside it
+        assert all(
+            s[4].get("trace_ids") == ["trace0000"] for s in seams["on"]["dispatch"]
+        )
     # the model-step seams ran on this path: recorded under the runner's
     # dispatch span when ON, nothing at all when OFF
     dispatched = {s[2] for s in seams["on"]["dispatch"]}

@@ -309,8 +309,8 @@ def test_adjoint_convergence_freezes_scan():
 
 
 def test_workloads_parity_probe():
-    """The PARITY.json recorder's numbers: per-kind solo-vs-ensemble drift
-    is at numerical noise for every registered model."""
+    """Per-kind solo-vs-ensemble drift is at numerical noise for every
+    registered model."""
     deltas = solo_ensemble_parity(steps=5)
     assert set(deltas) == {"dns", "lnse", "adjoint"}
     for kind, row in deltas.items():

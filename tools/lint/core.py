@@ -26,7 +26,6 @@ DEFAULT_SCOPE = (
     "tests",
     "examples",
     "plot",
-    "bench.py",
 )
 _EXCLUDE_PARTS = {"__pycache__", ".jax_cache", "data"}
 

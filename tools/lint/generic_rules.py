@@ -5,7 +5,7 @@ rules (F401 unused import, F841 unused local, B006 mutable default
 argument, F541 f-string without placeholders).  This container bakes no
 ruff and nothing may be pip-installed, so a built-in AST fallback
 implements the same four checks under the same ids — both engines emit
-``GEN-Fxxx``/``GEN-B006`` findings so the baseline and the LINT.json
+``GEN-Fxxx``/``GEN-B006`` findings so the baseline and the ``--json``
 rule->count payload are engine-stable.
 
 The fallback honors ``# noqa`` comments on the flagged line (the repo's
