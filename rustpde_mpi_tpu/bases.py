@@ -474,15 +474,21 @@ class Base:
             return tr.fourier_r2c_backward_fft(c, axis, self.n)
         return tr.fourier_c2c_backward_fft(c, axis, self.n)
 
+    @property
+    def is_orthogonal(self) -> bool:
+        """The base is its own orthogonal space (no composite cast):
+        ``to_ortho`` and ``from_ortho`` are the identity."""
+        return not self.kind.is_chebyshev or self.kind == BaseKind.CHEBYSHEV
+
     def to_ortho(self, vhat, axis: int, sep: bool = False):
-        if self.kind in (BaseKind.CHEBYSHEV, BaseKind.FOURIER_R2C, BaseKind.FOURIER_C2C):
+        if self.is_orthogonal:
             return vhat
         if sep:
             return self._sep_dev("stencil").apply(vhat, axis)
         return self._stencil_dev.apply(vhat, axis)
 
     def from_ortho(self, c, axis: int, sep: bool = False):
-        if self.kind in (BaseKind.CHEBYSHEV, BaseKind.FOURIER_R2C, BaseKind.FOURIER_C2C):
+        if self.is_orthogonal:
             return c
         if sep:
             return self._sep_dev("proj").apply(c, axis)
@@ -835,6 +841,12 @@ class Space2:
             bool(sep) and base_x.kind.is_chebyshev and method == "matmul",
             bool(sep) and base_y.kind.is_chebyshev and method == "matmul",
         )
+        # Under a mesh the spectral operators (gradient, to_ortho, from_ortho)
+        # state the pencil layout they run in, as the transforms always have.
+        # A model whose mesh program is not GSPMD's to place clears this at its
+        # build (models/navier.py); the transforms' flips are part of that
+        # program.
+        self.states_layout = True
 
     @property
     def base_x(self) -> Base:
@@ -983,14 +995,13 @@ class Space2:
         ``backward_ortho(gradient(...))``: each sep axis is ONE
         synthesis-of-derivative GEMM (key ("bwd_grad", order); order 0 is the
         plain fused backward), saving the separate gradient apply.  Non-sep
-        axes (e.g. the split-Fourier axis of a periodic space) fall back to
-        gradient-then-synthesis on that axis only, so mixed spaces still
-        fuse their Chebyshev axis.  ``fast=True`` selects the 3-pass
+        axes (e.g. the split-Fourier axis of a periodic space) run
+        gradient-then-synthesis on that axis, so mixed spaces still fuse
+        their Chebyshev axis, and under a mesh every y-operator runs after
+        the flip, where y is local.  ``fast=True`` selects the 3-pass
         synthesis variants (DNS convection path only — see Base._sep_dev)."""
         from .parallel.mesh import PHYS, SPEC, constrain
 
-        if not any(self.sep):
-            return self.backward_ortho(self.gradient(vhat, deriv, scale))
         ax = self._batch_ax(vhat)
         out = constrain(vhat, SPEC)
         for axis in (0, 1):
@@ -1022,26 +1033,85 @@ class Space2:
         return self.backward_gradient(vhat, (0, 0), None, fast=True)
 
     def to_ortho(self, vhat):
+        from .parallel.mesh import SPEC
+
         ax = self._batch_ax(vhat)
-        out = self.bases[0].to_ortho(vhat, ax, sep=self.sep[0])
-        return self.bases[1].to_ortho(out, ax + 1, sep=self.sep[1])
+        out = self.bases[0].to_ortho(self._pin(vhat, SPEC), ax, sep=self.sep[0])
+        return self._pin(self.bases[1].to_ortho(out, ax + 1, sep=self.sep[1]), SPEC)
+
+    # Spectral operators under a mesh: an operator that needs a whole axis
+    # runs where that axis is local, and says so itself.  Spectral arrays rest
+    # as x-pencils (SPEC: x local), so x-operators run in place; a y-operator
+    # that needs the whole y extent at once (a Chebyshev derivative, the dense
+    # composite cast of from_ortho) is taken between a pair of flips to the
+    # y-pencil layout; the banded to_ortho stencil costs two halo rows where
+    # it is and stays.  Left to propagation, GSPMD runs the parity interleave
+    # of such an operator ALONG the sharded axis: every device scatters its
+    # rows into a zero field and the fields are summed, a whole-field
+    # all-reduce per interleave.
+
+    def _pin(self, a, spec):
+        """``constrain`` for the spectral operators below, unless the model
+        that owns the space cleared ``states_layout`` at its build."""
+        from .parallel.mesh import constrain
+
+        return constrain(a, spec) if self.states_layout else a
+
+    def _where_y_is_local(self, apply, c):
+        """``apply`` (y-operators that need the whole y extent) on a spectral
+        array resting as an x-pencil: between a stated pair of flips."""
+        from .parallel.mesh import PHYS, SPEC
+
+        return self._pin(apply(self._pin(c, PHYS)), SPEC)
 
     def from_ortho(self, c):
-        ax = self._batch_ax(c)
-        out = self.bases[0].from_ortho(c, ax, sep=self.sep[0])
-        return self.bases[1].from_ortho(out, ax + 1, sep=self.sep[1])
+        from .parallel.mesh import SPEC
 
-    def gradient(self, vhat, deriv, scale=None):
+        ax = self._batch_ax(c)
+        out = c
+        if not self.bases[0].is_orthogonal:
+            out = self.bases[0].from_ortho(self._pin(out, SPEC), ax, sep=self.sep[0])
+        if self.bases[1].is_orthogonal:
+            return out
+        return self._where_y_is_local(
+            lambda a: self.bases[1].from_ortho(a, ax + 1, sep=self.sep[1]), out
+        )
+
+    def gradient(self, vhat, deriv, scale=None, into: "Space2 | None" = None):
         """d^deriv[0]/dx d^deriv[1]/dy in ortho space; divides by
-        scale^deriv like the reference (/root/reference/src/field.rs:127)."""
+        scale^deriv like the reference (/root/reference/src/field.rs:127).
+        ``into``: the space whose composite coefficients the derivative is
+        cast to, ``into.from_ortho(gradient(.))`` (the projection's velocity
+        correction), so that a y-derivative and the cast share one visit to
+        the layout in which y is local."""
+        from .parallel.mesh import SPEC
+
         ax = self._batch_ax(vhat)
-        out = self.bases[0].gradient(vhat, deriv[0], ax, sep=self.sep[0])
-        out = self.bases[1].gradient(out, deriv[1], ax + 1, sep=self.sep[1])
+        factor = 1.0
         if scale is not None:
             factor = (scale[0] ** deriv[0]) * (scale[1] ** deriv[1])
-            if factor != 1.0:
-                out = out / factor
-        return out
+
+        def along_y(a):
+            a = self.bases[1].gradient(a, deriv[1], ax + 1, sep=self.sep[1])
+            return a / factor if factor != 1.0 else a
+
+        def cast_y(a):
+            if into is None:
+                return a
+            return into.bases[1].from_ortho(a, ax + 1, sep=into.sep[1])
+
+        out = vhat
+        if deriv[0] or not self.bases[0].is_orthogonal:
+            out = self.bases[0].gradient(self._pin(out, SPEC), deriv[0], ax, sep=self.sep[0])
+        if into is not None and not into.bases[0].is_orthogonal:
+            out = into.bases[0].from_ortho(self._pin(out, SPEC), ax, sep=into.sep[0])
+        if self.bases[1].kind.is_chebyshev and deriv[1] >= 1:
+            return self._where_y_is_local(lambda a: cast_y(along_y(a)), out)
+        # the banded stencil, a diagonal or nothing: where it is
+        out = self._pin(along_y(self._pin(out, SPEC)), SPEC)
+        if into is None or into.bases[1].is_orthogonal:
+            return out
+        return self._where_y_is_local(cast_y, out)
 
     # -- representation-aware helpers ---------------------------------------
 

@@ -326,8 +326,8 @@ class Navier2DLnse(CampaignModelBase, Integrate):
                 velx_n = velx_n - gx1.apply(gx0.apply(pseu_n, pax), pax + 1) / scale[0]
                 vely_n = vely_n - gy1.apply(gy0.apply(pseu_n, pax), pax + 1) / scale[1]
             else:
-                velx_n = velx_n - sp_u.from_ortho(sp_q.gradient(pseu_n, (1, 0), scale))
-                vely_n = vely_n - sp_v.from_ortho(sp_q.gradient(pseu_n, (0, 1), scale))
+                velx_n = velx_n - sp_q.gradient(pseu_n, (1, 0), scale, into=sp_u)
+                vely_n = vely_n - sp_q.gradient(pseu_n, (0, 1), scale, into=sp_v)
             pres_n = pres - nu * div + sp_q.to_ortho(pseu_n) / dt
 
             rhs = sp_t.to_ortho(temp)
@@ -465,8 +465,8 @@ class Navier2DLnse(CampaignModelBase, Integrate):
                 velx_n = velx_n - gx1.apply(gx0.apply(pseu_n, pax), pax + 1) / scale[0]
                 vely_n = vely_n - gy1.apply(gy0.apply(pseu_n, pax), pax + 1) / scale[1]
             else:
-                velx_n = velx_n - sp_u.from_ortho(sp_q.gradient(pseu_n, (1, 0), scale))
-                vely_n = vely_n - sp_v.from_ortho(sp_q.gradient(pseu_n, (0, 1), scale))
+                velx_n = velx_n - sp_q.gradient(pseu_n, (1, 0), scale, into=sp_u)
+                vely_n = vely_n - sp_q.gradient(pseu_n, (0, 1), scale, into=sp_v)
             pres_n = pres - nu * div + sp_q.to_ortho(pseu_n) / dt
 
             rhs = sp_t.to_ortho(temp)

@@ -255,6 +255,13 @@ class Navier2D(CampaignModelBase, Integrate):
         # (manual-partitioned split-sep path, parallel/decomp.py); None
         # keeps the unfused dense chain (the measured default)
         self._conv_impl = self._build_conv_kernels()
+        # the poisoned layout's mesh program is parallel/decomp.py's regions
+        # (or the eager fallback), not GSPMD's to place: its spaces' spectral
+        # operators state no layout of their own there
+        if self._split_sep_poisoned():
+            for space in (self.velx_space, self.temp_space, self.pres_space,
+                          self.pseu_space, self.field_space):
+                space.states_layout = False
 
         # fused projection-gradient operators for the velocity correction
         # (confined only; the periodic x-axis gradient is diagonal logic):
@@ -999,7 +1006,11 @@ class Navier2D(CampaignModelBase, Integrate):
                             # bisection)
                             pseu_n = manual_poisson.solve(div)
                         else:
-                            pseu_n = sol_p.solve(pin(div))
+                            # the pressure update below reads the pinned sum
+                            # too: left free it is taken in the solve's
+                            # y-pencil layout and flipped back
+                            div = pin(div)
+                            pseu_n = sol_p.solve(div)
                     pseu_n = sp_q.pin_zero_mode(pseu_n)  # remove singularity
                 with stage("projection"):
                     if proj_grad is not None:
@@ -1008,12 +1019,8 @@ class Navier2D(CampaignModelBase, Integrate):
                         velx_n = velx_n - gx1.apply(gx0.apply(pseu_n, ax), ax + 1) / scale[0]
                         vely_n = vely_n - gy1.apply(gy0.apply(pseu_n, ax), ax + 1) / scale[1]
                     else:
-                        velx_n = velx_n - sp_u.from_ortho(
-                            sp_q.gradient(pseu_n, (1, 0), scale)
-                        )
-                        vely_n = vely_n - sp_v.from_ortho(
-                            sp_q.gradient(pseu_n, (0, 1), scale)
-                        )
+                        velx_n = velx_n - sp_q.gradient(pseu_n, (1, 0), scale, into=sp_u)
+                        vely_n = vely_n - sp_q.gradient(pseu_n, (0, 1), scale, into=sp_v)
                 with stage("pressure"):
                     pres_n = pres - nu * div + sp_q.to_ortho(pseu_n) / dt
 

@@ -339,8 +339,8 @@ class Navier2DAdjoint(CampaignModelBase, Integrate):
                 velx_n = velx_n - gx1.apply(gx0.apply(pseu_n, pax), pax + 1) / scale[0]
                 vely_n = vely_n - gy1.apply(gy0.apply(pseu_n, pax), pax + 1) / scale[1]
             else:
-                velx_n = velx_n - sp_u.from_ortho(sp_q.gradient(pseu_n, (1, 0), scale))
-                vely_n = vely_n - sp_v.from_ortho(sp_q.gradient(pseu_n, (0, 1), scale))
+                velx_n = velx_n - sp_q.gradient(pseu_n, (1, 0), scale, into=sp_u)
+                vely_n = vely_n - sp_q.gradient(pseu_n, (0, 1), scale, into=sp_v)
             # adjoint pressure update: pres_adj += pseu/dt
             # (steady_adjoint_eq.rs:226-236)
             pres_adj_n = state.pres_adj + sp_q.to_ortho(pseu_n) / dt

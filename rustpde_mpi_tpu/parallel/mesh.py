@@ -11,10 +11,12 @@ but instead of hand-written MPI all-to-alls
 (/root/reference/src/field_mpi.rs:455-477) the repartitions are expressed as
 ``jax.lax.with_sharding_constraint`` at the pencil-flip points inside
 transforms and solvers; XLA GSPMD inserts the all-to-all collectives and
-overlaps them with compute.  One code path serves serial and sharded
-execution: with no active mesh every constraint is a no-op, so the physics
-layer (models/navier.py) is written once — the reference's duplicated
-navier_stokes vs navier_stokes_mpi modules collapse into one.
+overlaps them with compute.  The rule: an operator that needs a whole axis
+runs where that axis is local, and the layout is stated at the operator, not
+left to propagation (bases.Space2, solver.py).  One code path serves serial
+and sharded execution: with no active mesh every constraint is a no-op, so
+the physics layer (models/navier.py) is written once — the reference's
+duplicated navier_stokes vs navier_stokes_mpi modules collapse into one.
 """
 
 from __future__ import annotations
@@ -114,6 +116,11 @@ def constrain(x, spec: tuple):
     to XLA.  Outside a trace (eager setup code) it becomes a resharding.
     Arrays with more dims than the spec treat the extra leading dims as
     replicated batch.
+
+    NOTE an operator that needs a whole axis (a transform, a solve, a
+    Chebyshev derivative, a composite cast) states the layout in which that
+    axis is local on its own input: left to propagation, GSPMD runs it along
+    the sharded axis and sums whole fields over the devices.
 
     NOTE in-jit constraints deliberately do NOT take the replicated pin of
     ``device_put``: inside a jit a non-divisible constraint pads, and the

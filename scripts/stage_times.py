@@ -66,6 +66,19 @@ def scopes_of(text: str, short) -> dict:
     return out
 
 
+def results_of(text: str, short) -> dict:
+    """``{"all-reduce.23 f32[1,1026]": "(f32[1,1026]{1,0}, f32[512,1026]{1,0})"}``:
+    an instruction's whole result type.  The trace names a collective by the
+    first element of a tuple result, which may be its smallest."""
+    out = {}
+    for line in text.splitlines():
+        line = re.sub(r"^\s*(ROOT )?", "", line)
+        m = re.match(r"%[^ =]+ = (\(.*?\)|\S+) [\w\-]+\(", line)
+        if m:
+            out[short(line)] = re.sub(r"\{[^}]*\}", "", m.group(1))
+    return out
+
+
 def compiled_text(lowered) -> str:
     """Compiled afresh: the persistent compile cache keys a program without
     its metadata, so a hit hands back the names of whichever build compiled
@@ -88,7 +101,8 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--dispatches", type=int, default=2)
     ap.add_argument("--size", type=int, default=None)
-    ap.add_argument("--out", default=None, help="also write the table as JSON here")
+    ap.add_argument("--out", default=None,
+                    help="also write the table as JSON here, and the chunk's compiled text beside it (.txt)")
     args = ap.parse_args(argv)
     from benchmark import reduce as reducer
     from benchmark.run import load_cell
@@ -124,7 +138,8 @@ def main(argv=None) -> int:
         with model._scope():
             lowered = model._step_n_jit.lower(model._step_consts, model.state, n=n)
         read = model.get_observables
-    scopes = scopes_of(compiled_text(lowered), reducer.short)
+    text = compiled_text(lowered)
+    scopes, results = scopes_of(text, reducer.short), results_of(text, reducer.short)
     sim.update_n(n), read()
     logdir = tempfile.mkdtemp(prefix="stage_times_")
     with profiling.trace(logdir):
@@ -153,13 +168,19 @@ def main(argv=None) -> int:
               f"stage {100 * table.get('(no stage)', 0.0) / total:.2f} %; not in the chunk's text "
               f"{100 * unknown / total:.2f} %")
     kinds: dict = {}
+    collectives = []
     for name, seconds in red["ops"].items():
         kind = re.match(r"(all-to-all|all-gather|collective-permute|all-reduce|reduce-scatter)", name)
         if kind:
             kinds[kind.group(1)] = kinds.get(kind.group(1), 0.0) + seconds
+            collectives.append({"op": name, "s": seconds, "result": results.get(name, ""),
+                                "scope": stage_of(scopes.get(name, "")) or ""})
+    collectives.sort(key=lambda c: -c["s"])
     for kind, seconds in sorted(kinds.items(), key=lambda kv: -kv[1]):
         print(f"  collective {kind:20s} {1e3 * seconds / steps:9.5f} ms/step  "
               f"{100 * seconds / red['busy_s']:6.2f} % of busy (mean over the device planes)")
+    for c in collectives[:12]:  # by its whole result type: a tuple is named by its first element
+        print(f"    {c['op']:34s} {1e6 * c['s'] / steps:8.2f} us/step  {c['scope']:28s} {c['result']}")
     host = host_spans(path)
     for name, found in sorted(host["spans"].items()):
         print(f"  host plane: {name} x {len(found)}, mean {1e-6 * sum(e - s for s, e in found) / len(found):.4f} ms")
@@ -171,8 +192,11 @@ def main(argv=None) -> int:
         with open(args.out, "w", encoding="utf-8") as fh:
             json.dump({"workload": args.workload, "device": dev.device_kind, "steps": steps,
                        "busy_s": red["busy_s"], "window_s": red["window_s"], "ops_s": total,
-                       "stages_s": table, "collectives_s": kinds, "not_in_text_s": unknown,
+                       "stages_s": table, "collectives_s": kinds, "collective_ops": collectives,
+                       "not_in_text_s": unknown,
                        "launch_to_device_us": host["launch_to_device_us"]}, fh, indent=1)
+        with open(os.path.splitext(args.out)[0] + ".txt", "w", encoding="utf-8") as fh:
+            fh.write(text)  # the names above are this text's
     return 0
 
 

@@ -111,6 +111,46 @@ def test_space2_mixed_gradient_with_scale():
     np.testing.assert_allclose(dudx, expect, atol=1e-8)
 
 
+def _space(layout: str):
+    if layout == "periodic_fft":
+        return rp.Space2(rp.fourier_r2c(16), rp.cheb_dirichlet(17), method="fft")
+    if layout == "confined_fft":
+        return rp.Space2(rp.cheb_dirichlet(17), rp.cheb_neumann(16), method="fft")
+    if layout == "pure_fft":  # the pressure's space: both casts are the identity
+        return rp.Space2(rp.chebyshev(17), rp.chebyshev(16), method="fft")
+    return rp.Space2(rp.cheb_dirichlet(17), rp.cheb_neumann(16), method="matmul", sep=False)
+
+
+@pytest.mark.parametrize("layout", ["periodic_fft", "confined_fft", "confined_matmul"])
+def test_backward_gradient_of_a_space_with_no_sep_axis(layout):
+    """No sep axis: the per-axis loop (derivative and synthesis along x, then
+    along y) gives ``backward_ortho(gradient(.))``; the operators of the two
+    axes commute."""
+    space = _space(layout)
+    assert not any(space.sep)
+    vhat = space.forward(np.random.default_rng(4).standard_normal(space.shape_physical))
+    for deriv in [(0, 0), (1, 0), (0, 1), (1, 1), (0, 2)]:
+        got = np.asarray(space.backward_gradient(vhat, deriv, (1.0, 2.0)))
+        want = np.asarray(space.backward_ortho(space.gradient(vhat, deriv, (1.0, 2.0))))
+        np.testing.assert_allclose(got, want, atol=1e-11 * max(1.0, np.abs(want).max()))
+
+
+@pytest.mark.parametrize("layout", ["periodic_fft", "confined_fft", "confined_matmul", "pure_fft"])
+def test_gradient_into_a_space_is_its_from_ortho(layout):
+    """``gradient(..., into=space)`` is ``space.from_ortho(gradient(...))``:
+    the projection's velocity correction, cast inside the derivative's own
+    visit to the layout in which y is local."""
+    src = _space(layout)
+    x_base = src.base_x if src.base_x.is_periodic else rp.cheb_dirichlet(src.base_x.n)
+    dst = rp.Space2(x_base, rp.cheb_dirichlet(src.base_y.n), method=src.method, sep=False)
+    vhat = src.forward(np.random.default_rng(5).standard_normal(src.shape_physical))
+    for deriv in [(1, 0), (0, 1)]:
+        got = np.asarray(src.gradient(vhat, deriv, (2.0, 0.5), into=dst))
+        want = np.asarray(dst.from_ortho(src.gradient(vhat, deriv, (2.0, 0.5))))
+        assert got.shape == dst.shape_spectral
+        np.testing.assert_allclose(got, want, atol=1e-12 * max(1.0, np.abs(want).max()))
+
+
 # ---------------------------------------------------------------------------
 # composite bases: boundary conditions + ortho casts
 # ---------------------------------------------------------------------------
