@@ -22,16 +22,11 @@ from rustpde_mpi_tpu import (  # noqa: E402
     MeanFields,
     Navier2D,
     Navier2DNonLin,
+    descent_iteration,
     integrate,
-    steepest_descent_energy_constrained,
+    mirrored_target,
 )
 from rustpde_mpi_tpu.models.lnse import l2_norm  # noqa: E402
-
-
-def mirror_field(velx, vely, temp):
-    """x-mirrored LSC state (the reversed circulation)
-    (navier_lnse_opt_reversals.rs:7-13)."""
-    return -velx[::-1, :], -vely.copy(), temp[::-1, :]
 
 
 def find_base_field(nx, ny, dt, ra, pr, aspect, max_time):
@@ -58,14 +53,12 @@ def main() -> int:
     base = find_base_field(nx, ny, dt, ra, pr, aspect, base_time)
     base.write("data/mean.h5")
     mean = MeanFields.read_from(nx, ny, "data/mean.h5", bc="rbc")
+    # a snapshot holds the DNS's temperature without the boundary lift the
+    # DNS keeps apart; the perturbation form's base state is the total field
+    mean.temp = mean.temp + MeanFields.new_rbc(nx, ny).temp
 
     # target: mirrored base state, expressed as a perturbation about the mean
-    mu, mv, mt = mean.physical()
-    tu, tv, tt = mirror_field(mu, mv, mt)
-    target = MeanFields(mean.space)
-    target.velx = mean.space.forward(np.asarray(tu - mu))
-    target.vely = mean.space.forward(np.asarray(tv - mv))
-    target.temp = mean.space.forward(np.asarray(tt - mt))
+    target = mirrored_target(mean)
 
     for max_time in horizons:
         for e_constraint in energies:
@@ -83,40 +76,19 @@ def main() -> int:
             model.set_field("temp", t * fac)
 
             best = np.inf
-            alpha = alpha_0
-            j_old = 0.0
+            alpha, j_old = alpha_0, None
             for it in range(max_iter):
-                # fresh pressure every iteration
-                # (navier_lnse_opt_reversals.rs:127-131)
-                import jax.numpy as jnp
-
-                model.state = model.state._replace(
-                    pres=jnp.zeros_like(model.state.pres),
-                    pseu=jnp.zeros_like(model.state.pseu),
+                # the campaign's loop body (navier_lnse_opt_reversals.rs:124-165)
+                # lives in the library: models/opt_routines.py
+                step = descent_iteration(
+                    model, max_time, beta1, beta2, target, alpha, alpha_0, j_old
                 )
-                model.reset_time()
-                u0, v0, t0 = (np.asarray(a) for a in model._phys(model.state))
-                fun_val, grads = model.grad_adjoint(
-                    max_time, None, beta1, beta2, target=target
-                )
-                # backtracking step control (navier_lnse_opt_reversals.rs:143-152)
-                if it > 0 and fun_val > j_old:
-                    alpha /= 2.0
-                    print(f"  set alpha: {alpha:4.2e}")
-                    if alpha < 1e-3:
-                        print("  alpha too small. Reset")
-                        alpha = alpha_0
-                j_old = fun_val
-                print(f"  iter {it}: J = {fun_val:.6e}  alpha = {alpha:.3f}")
-                best = min(best, fun_val)
-                gu, gv, gt = (np.asarray(g) for g in grads)
-                un, vn, tn = steepest_descent_energy_constrained(
-                    u0, v0, t0, gu, gv, gt, beta1, beta2, alpha
-                )
-                model.reset_time()
-                model.set_field("velx", un)
-                model.set_field("vely", vn)
-                model.set_field("temp", tn)
+                if step.alpha != alpha:
+                    print(f"  set alpha: {step.alpha:4.2e}")
+                alpha = step.alpha
+                j_old = step.fun_val
+                print(f"  iter {it}: J = {step.fun_val:.6e}  alpha = {alpha:.3f}")
+                best = min(best, step.fun_val)
             print(f"  best J = {best:.6e}")
     print("OK")
     return 0

@@ -30,7 +30,11 @@ from .models.ensemble import NavierEnsemble  # noqa: F401
 from .models.lnse import Navier2DLnse, Navier2DNonLin  # noqa: F401
 from .models.meanfield import MeanFields  # noqa: F401
 from .models.navier import Navier2D, NavierState  # noqa: F401
-from .models.opt_routines import steepest_descent_energy_constrained  # noqa: F401
+from .models.opt_routines import (  # noqa: F401
+    descent_iteration,
+    mirrored_target,
+    steepest_descent_energy_constrained,
+)
 from .models.statistics import Statistics  # noqa: F401
 from .models.stats import StatsEngine, StatsState, export_stats  # noqa: F401
 from .models.steady_adjoint import Navier2DAdjoint  # noqa: F401

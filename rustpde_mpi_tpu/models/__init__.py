@@ -16,7 +16,11 @@ from .navier import (  # noqa: F401
     NavierState,
     scenario_signature,
 )
-from .opt_routines import steepest_descent_energy_constrained  # noqa: F401
+from .opt_routines import (  # noqa: F401
+    descent_iteration,
+    mirrored_target,
+    steepest_descent_energy_constrained,
+)
 from .statistics import Statistics  # noqa: F401
 from .stats import (  # noqa: F401
     HEALTH_NAMES,

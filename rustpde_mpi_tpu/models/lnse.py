@@ -35,8 +35,10 @@ import numpy as np
 
 from .. import config
 from ..field import norm_l2
+from ..telemetry import tracing as _tr
 from ..utils.integrate import Integrate
-from .campaign import CampaignModelBase
+from ..utils.jit import scan_buckets
+from .campaign import _LAYER, CampaignModelBase
 from .meanfield import MeanFields
 from .navier import Navier2D, NavierState
 
@@ -96,6 +98,7 @@ class Navier2DLnse(CampaignModelBase, Integrate):
         self.scale = self.navier.scale
         self.write_intervall: float | None = None
         self.statistics = None
+        self._host_seams = None
         self._init_campaign()
         self._compile_entry_points()
         self.state = NavierState(*self.navier.state)
@@ -199,6 +202,32 @@ class Navier2DLnse(CampaignModelBase, Integrate):
 
     # -- direct (forward) step ------------------------------------------------
 
+    def _make_projection(self):
+        """``(velx, vely, pseu) -> (velx, vely)`` less the gradient of the
+        pressure increment: the projection both steps end their momentum
+        half with."""
+        nav = self.navier
+        scale = self.scale
+        sp_u, sp_v, sp_q = nav.velx_space, nav.vely_space, nav.pseu_space
+        from ..bases import fused_projection_gradient
+
+        _gx = fused_projection_gradient(sp_u, sp_q, (1, 0))
+        _gy = fused_projection_gradient(sp_v, sp_q, (0, 1))
+        proj_grad = (*_gx, *_gy) if _gx and _gy else None
+
+        def project(velx, vely, pseu):
+            if proj_grad is not None:
+                gx0, gx1, gy0, gy1 = proj_grad
+                pax = pseu.ndim - 2
+                velx = velx - gx1.apply(gx0.apply(pseu, pax), pax + 1) / scale[0]
+                vely = vely - gy1.apply(gy0.apply(pseu, pax), pax + 1) / scale[1]
+            else:
+                velx = velx - sp_q.gradient(pseu, (1, 0), scale, into=sp_u)
+                vely = vely - sp_q.gradient(pseu, (0, 1), scale, into=sp_v)
+            return velx, vely
+
+        return project
+
     def _make_step(self, with_sentinels: bool = False):
         """The linearized step; ``with_sentinels=True`` additionally returns
         ``(cfl, ke, |div|)`` — the advective CFL uses the TOTAL velocity
@@ -213,11 +242,7 @@ class Navier2DLnse(CampaignModelBase, Integrate):
         w0s, w1s = nav._w0, nav._w1
         sp_t, sp_u, sp_v = nav.temp_space, nav.velx_space, nav.vely_space
         sp_p, sp_q, sp_f = nav.pres_space, nav.pseu_space, nav.field_space
-        from ..bases import fused_projection_gradient
-
-        _gx = fused_projection_gradient(sp_u, sp_q, (1, 0))
-        _gy = fused_projection_gradient(sp_v, sp_q, (0, 1))
-        proj_grad = (*_gx, *_gy) if _gx and _gy else None
+        project = self._make_projection()
         mask = nav._dealias
         mc = self._mean_constants()
         sol_u, sol_v, sol_t, sol_p = (
@@ -236,6 +261,7 @@ class Navier2DLnse(CampaignModelBase, Integrate):
 
         # mean-balance constants of the perturbation form (nonlin_eq.rs):
         # mean-mean convection and mean diffusion enter the rhs every step
+        conv_mm_x = conv_mm_y = conv_mm_t = None
         if nonlinear:
             conv_mm_x = np.asarray(
                 conv(mc["U"] * mc["dUdx"] + mc["V"] * mc["dUdy"])
@@ -260,81 +286,82 @@ class Navier2DLnse(CampaignModelBase, Integrate):
             )
             that_mean = np.asarray(mean.temp)
 
+        # Navier2D._make_step's stage names on every device operation the
+        # step lowers to (instruction metadata only), so a device trace of a
+        # sweep reads by stage: scripts/stage_times.py
+        stage = jax.named_scope
+
         def step(state: NavierState) -> NavierState:
             temp, velx, vely, pres, pseu = state
-            that = sp_t.to_ortho(temp)
-            if nonlinear:
-                that = that + that_mean  # buoyancy incl. base state
-            ux = sp_u.backward(velx)
-            uy = sp_v.backward(vely)
+            with stage("buoyancy"):
+                that = sp_t.to_ortho(temp)
+                if nonlinear:
+                    that = that + that_mean  # buoyancy incl. base state
+            with stage("synthesis"):
+                ux = sp_u.backward(velx)
+                uy = sp_v.backward(vely)
 
             if with_sentinels:
                 # advective CFL of the TOTAL velocity (mean + perturbation)
                 # + perturbation KE, from arrays the step needs anyway
-                cfl = dt * jnp.max(
-                    jnp.abs(mc["U"] + ux) * inv_dx[:, None]
-                    + jnp.abs(mc["V"] + uy) * inv_dy[None, :]
+                with stage("sentinels"):
+                    cfl = dt * jnp.max(
+                        jnp.abs(mc["U"] + ux) * inv_dx[:, None]
+                        + jnp.abs(mc["V"] + uy) * inv_dy[None, :]
+                    )
+                    ke = 0.5 * jnp.sum(
+                        (ux**2 + uy**2) * w0s[:, None] * w1s[None, :]
+                    )
+
+            @stage("convection")  # named under each caller's stage
+            def convection(space, vhat, dMdx, dMdy, mean_mean):
+                """Linearized convection ``u.grad(M) + U.grad(f)`` of one
+                field ``f`` about its base field ``M`` (lnse_eq.rs:59-110);
+                the perturbation form adds ``u.grad(f)`` and the base
+                state's own ``U.grad(M)`` (nonlin_eq.rs:59-120)."""
+                df_dx = gphys(space, vhat, (1, 0))
+                df_dy = gphys(space, vhat, (0, 1))
+                total = ux * dMdx + uy * dMdy + mc["U"] * df_dx + mc["V"] * df_dy
+                if nonlinear:
+                    total = total + ux * df_dx + uy * df_dy
+                out = conv(total)
+                return out + mean_mean if nonlinear else out
+
+            with stage("momentum_x"):
+                rhs = sp_u.to_ortho(velx)
+                rhs = rhs - dt * sp_p.gradient(pres, (1, 0), scale)
+                rhs = rhs - dt * convection(sp_u, velx, mc["dUdx"], mc["dUdy"], conv_mm_x)
+                if nonlinear:
+                    rhs = rhs + dt * nu * lap_u_m
+                velx_n = sol_u.solve(rhs)
+
+            with stage("momentum_y"):
+                rhs = sp_v.to_ortho(vely)
+                rhs = rhs - dt * sp_p.gradient(pres, (0, 1), scale)
+                rhs = rhs + dt * that
+                rhs = rhs - dt * convection(sp_v, vely, mc["dVdx"], mc["dVdy"], conv_mm_y)
+                if nonlinear:
+                    rhs = rhs + dt * nu * lap_v_m
+                vely_n = sol_v.solve(rhs)
+
+            with stage("divergence"):
+                div = sp_u.gradient(velx_n, (1, 0), scale) + sp_v.gradient(
+                    vely_n, (0, 1), scale
                 )
-                ke = 0.5 * jnp.sum(
-                    (ux**2 + uy**2) * w0s[:, None] * w1s[None, :]
-                )
+            with stage("poisson"):
+                pseu_n = sol_p.solve(div)
+                pseu_n = sp_q.pin_zero_mode(pseu_n)
+            with stage("projection"):
+                velx_n, vely_n = project(velx_n, vely_n, pseu_n)
+            with stage("pressure"):
+                pres_n = pres - nu * div + sp_q.to_ortho(pseu_n) / dt
 
-            # linearized convection: u.grad(U) + U.grad(u) (lnse_eq.rs:59-110)
-            du_dx = gphys(sp_u, velx, (1, 0))
-            du_dy = gphys(sp_u, velx, (0, 1))
-            dv_dx = gphys(sp_v, vely, (1, 0))
-            dv_dy = gphys(sp_v, vely, (0, 1))
-            dT_dx = gphys(sp_t, temp, (1, 0))
-            dT_dy = gphys(sp_t, temp, (0, 1))
-            cx = ux * mc["dUdx"] + uy * mc["dUdy"] + mc["U"] * du_dx + mc["V"] * du_dy
-            cy = ux * mc["dVdx"] + uy * mc["dVdy"] + mc["U"] * dv_dx + mc["V"] * dv_dy
-            ct = ux * mc["dTdx"] + uy * mc["dTdy"] + mc["U"] * dT_dx + mc["V"] * dT_dy
-            if nonlinear:
-                # + u.grad(u) and + U.grad(U) (nonlin_eq.rs:59-120)
-                cx = cx + ux * du_dx + uy * du_dy
-                cy = cy + ux * dv_dx + uy * dv_dy
-                ct = ct + ux * dT_dx + uy * dT_dy
-            conv_x, conv_y, conv_t = conv(cx), conv(cy), conv(ct)
-            if nonlinear:
-                conv_x = conv_x + conv_mm_x
-                conv_y = conv_y + conv_mm_y
-                conv_t = conv_t + conv_mm_t
-
-            rhs = sp_u.to_ortho(velx)
-            rhs = rhs - dt * sp_p.gradient(pres, (1, 0), scale)
-            rhs = rhs - dt * conv_x
-            if nonlinear:
-                rhs = rhs + dt * nu * lap_u_m
-            velx_n = sol_u.solve(rhs)
-
-            rhs = sp_v.to_ortho(vely)
-            rhs = rhs - dt * sp_p.gradient(pres, (0, 1), scale)
-            rhs = rhs + dt * that
-            rhs = rhs - dt * conv_y
-            if nonlinear:
-                rhs = rhs + dt * nu * lap_v_m
-            vely_n = sol_v.solve(rhs)
-
-            div = sp_u.gradient(velx_n, (1, 0), scale) + sp_v.gradient(
-                vely_n, (0, 1), scale
-            )
-            pseu_n = sol_p.solve(div)
-            pseu_n = sp_q.pin_zero_mode(pseu_n)
-            if proj_grad is not None:
-                gx0, gx1, gy0, gy1 = proj_grad
-                pax = pseu_n.ndim - 2
-                velx_n = velx_n - gx1.apply(gx0.apply(pseu_n, pax), pax + 1) / scale[0]
-                vely_n = vely_n - gy1.apply(gy0.apply(pseu_n, pax), pax + 1) / scale[1]
-            else:
-                velx_n = velx_n - sp_q.gradient(pseu_n, (1, 0), scale, into=sp_u)
-                vely_n = vely_n - sp_q.gradient(pseu_n, (0, 1), scale, into=sp_v)
-            pres_n = pres - nu * div + sp_q.to_ortho(pseu_n) / dt
-
-            rhs = sp_t.to_ortho(temp)
-            rhs = rhs - dt * conv_t
-            if nonlinear:
-                rhs = rhs + dt * ka * lap_t_m
-            temp_n = sol_t.solve(rhs)
+            with stage("temperature"):
+                rhs = sp_t.to_ortho(temp)
+                rhs = rhs - dt * convection(sp_t, temp, mc["dTdx"], mc["dTdy"], conv_mm_t)
+                if nonlinear:
+                    rhs = rhs + dt * ka * lap_t_m
+                temp_n = sol_t.solve(rhs)
 
             state_n = NavierState(temp_n, velx_n, vely_n, pres_n, pseu_n)
             if with_sentinels:
@@ -379,11 +406,7 @@ class Navier2DLnse(CampaignModelBase, Integrate):
         nu = self.params["nu"]
         sp_t, sp_u, sp_v = nav.temp_space, nav.velx_space, nav.vely_space
         sp_p, sp_q, sp_f = nav.pres_space, nav.pseu_space, nav.field_space
-        from ..bases import fused_projection_gradient
-
-        _gx = fused_projection_gradient(sp_u, sp_q, (1, 0))
-        _gy = fused_projection_gradient(sp_v, sp_q, (0, 1))
-        proj_grad = (*_gx, *_gy) if _gx and _gy else None
+        project = self._make_projection()
         mask = nav._dealias
         mc = self._mean_constants()
         sol_u, sol_v, sol_t, sol_p = (
@@ -399,80 +422,83 @@ class Navier2DLnse(CampaignModelBase, Integrate):
                 return sp_f.forward_dealiased(total)
             return sp_f.forward(total) * mask
 
+        stage = jax.named_scope  # metadata only, as in _make_step
+
         def step(state: NavierState, history=None) -> NavierState:
             temp, velx, vely, pres, pseu = state
-            uyhat = sp_v.to_ortho(vely)  # adjoint buoyancy source (pre-update)
-            us = sp_u.backward(velx)
-            vs = sp_v.backward(vely)
-            ts = sp_t.backward(temp)
+            with stage("buoyancy"):
+                uyhat = sp_v.to_ortho(vely)  # adjoint buoyancy source (pre-update)
+            with stage("synthesis"):
+                us = sp_u.backward(velx)
+                vs = sp_v.backward(vely)
+                ts = sp_t.backward(temp)
 
             U, V = mc["U"], mc["V"]
-            dUdx, dVdx, dTdx = mc["dUdx"], mc["dVdx"], mc["dTdx"]
-            dUdy, dVdy, dTdy = mc["dUdy"], mc["dVdy"], mc["dTdy"]
-            # adjoint convection (lnse_adj_eq.rs:21-92):
-            # + U.grad(u*) - (u* dUdx + v* dVdx + T* dTdx) etc.
-            cx = (
-                U * gphys(sp_u, velx, (1, 0))
-                + V * gphys(sp_u, velx, (0, 1))
-                - us * dUdx - vs * dVdx - ts * dTdx
-            )
-            cy = (
-                U * gphys(sp_v, vely, (1, 0))
-                + V * gphys(sp_v, vely, (0, 1))
-                - us * dUdy - vs * dVdy - ts * dTdy
-            )
-            ct = U * gphys(sp_t, temp, (1, 0)) + V * gphys(sp_t, temp, (0, 1))
             if nonlinear:
-                # history contributions (nonlin_adj_eq.rs:21-125)
-                uh, vh, th = history
-                Uh = sp_f.backward_ortho(uh)
-                Vh = sp_f.backward_ortho(vh)
-                cx = cx + (
-                    Uh * gphys(sp_u, velx, (1, 0))
-                    + Vh * gphys(sp_u, velx, (0, 1))
-                    - us * sp_f.backward_ortho(sp_f.gradient(uh, (1, 0), scale))
-                    - vs * sp_f.backward_ortho(sp_f.gradient(vh, (1, 0), scale))
-                    - ts * sp_f.backward_ortho(sp_f.gradient(th, (1, 0), scale))
+                # the forward trajectory's fields and their derivatives at
+                # this step, in physical space (nonlin_adj_eq.rs:21-125)
+                with stage("history_terms"):
+                    uh, vh, th = history
+                    Uh = sp_f.backward_ortho(uh)
+                    Vh = sp_f.backward_ortho(vh)
+                    hist_dx = [gphys(sp_f, h, (1, 0)) for h in (uh, vh, th)]
+                    hist_dy = [gphys(sp_f, h, (0, 1)) for h in (uh, vh, th)]
+
+            @stage("convection")  # named under each caller's stage
+            def convection(space, vhat, mean_d, hist_d):
+                """Adjoint convection of one field (lnse_adj_eq.rs:21-92):
+                ``+ U.grad(f*) - (u* dU + v* dV + T* dT)`` along the field's
+                own direction; the perturbation form adds the same with the
+                stored trajectory in the base state's place.  ``mean_d`` and
+                ``hist_d`` are ``None`` for the temperature, which no base
+                gradient couples back."""
+                df_dx = gphys(space, vhat, (1, 0))
+                df_dy = gphys(space, vhat, (0, 1))
+                total = U * df_dx + V * df_dy
+                if mean_d is not None:
+                    total = total - us * mean_d[0] - vs * mean_d[1] - ts * mean_d[2]
+                if nonlinear:
+                    extra = Uh * df_dx + Vh * df_dy
+                    if hist_d is not None:
+                        extra = extra - us * hist_d[0] - vs * hist_d[1] - ts * hist_d[2]
+                    total = total + extra
+                return conv(total)
+
+            with stage("momentum_x"):
+                rhs = sp_u.to_ortho(velx)
+                rhs = rhs - dt * sp_p.gradient(pres, (1, 0), scale)
+                rhs = rhs + dt * convection(
+                    sp_u, velx, (mc["dUdx"], mc["dVdx"], mc["dTdx"]),
+                    hist_dx if nonlinear else None,
                 )
-                cy = cy + (
-                    Uh * gphys(sp_v, vely, (1, 0))
-                    + Vh * gphys(sp_v, vely, (0, 1))
-                    - us * sp_f.backward_ortho(sp_f.gradient(uh, (0, 1), scale))
-                    - vs * sp_f.backward_ortho(sp_f.gradient(vh, (0, 1), scale))
-                    - ts * sp_f.backward_ortho(sp_f.gradient(th, (0, 1), scale))
+                velx_n = sol_u.solve(rhs)
+
+            with stage("momentum_y"):
+                rhs = sp_v.to_ortho(vely)
+                rhs = rhs - dt * sp_p.gradient(pres, (0, 1), scale)
+                rhs = rhs + dt * convection(
+                    sp_v, vely, (mc["dUdy"], mc["dVdy"], mc["dTdy"]),
+                    hist_dy if nonlinear else None,
                 )
-                ct = ct + Uh * gphys(sp_t, temp, (1, 0)) + Vh * gphys(sp_t, temp, (0, 1))
-            conv_x, conv_y, conv_t = conv(cx), conv(cy), conv(ct)
+                vely_n = sol_v.solve(rhs)
 
-            rhs = sp_u.to_ortho(velx)
-            rhs = rhs - dt * sp_p.gradient(pres, (1, 0), scale)
-            rhs = rhs + dt * conv_x
-            velx_n = sol_u.solve(rhs)
+            with stage("divergence"):
+                div = sp_u.gradient(velx_n, (1, 0), scale) + sp_v.gradient(
+                    vely_n, (0, 1), scale
+                )
+            with stage("poisson"):
+                pseu_n = sol_p.solve(div)
+                pseu_n = sp_q.pin_zero_mode(pseu_n)
+            with stage("projection"):
+                velx_n, vely_n = project(velx_n, vely_n, pseu_n)
+            with stage("pressure"):
+                pres_n = pres - nu * div + sp_q.to_ortho(pseu_n) / dt
 
-            rhs = sp_v.to_ortho(vely)
-            rhs = rhs - dt * sp_p.gradient(pres, (0, 1), scale)
-            rhs = rhs + dt * conv_y
-            vely_n = sol_v.solve(rhs)
-
-            div = sp_u.gradient(velx_n, (1, 0), scale) + sp_v.gradient(
-                vely_n, (0, 1), scale
-            )
-            pseu_n = sol_p.solve(div)
-            pseu_n = sp_q.pin_zero_mode(pseu_n)
-            if proj_grad is not None:
-                gx0, gx1, gy0, gy1 = proj_grad
-                pax = pseu_n.ndim - 2
-                velx_n = velx_n - gx1.apply(gx0.apply(pseu_n, pax), pax + 1) / scale[0]
-                vely_n = vely_n - gy1.apply(gy0.apply(pseu_n, pax), pax + 1) / scale[1]
-            else:
-                velx_n = velx_n - sp_q.gradient(pseu_n, (1, 0), scale, into=sp_u)
-                vely_n = vely_n - sp_q.gradient(pseu_n, (0, 1), scale, into=sp_v)
-            pres_n = pres - nu * div + sp_q.to_ortho(pseu_n) / dt
-
-            rhs = sp_t.to_ortho(temp)
-            rhs = rhs + dt * conv_t
-            rhs = rhs + dt * uyhat  # adjoint buoyancy
-            temp_n = sol_t.solve(rhs)
+            with stage("temperature"):
+                rhs = sp_t.to_ortho(temp)
+                rhs = rhs + dt * convection(sp_t, temp, None, None)
+                rhs = rhs + dt * uyhat  # adjoint buoyancy
+                temp_n = sol_t.solve(rhs)
 
             return NavierState(temp_n, velx_n, vely_n, pres_n, pseu_n)
 
@@ -480,8 +506,11 @@ class Navier2DLnse(CampaignModelBase, Integrate):
 
     # -- compiled entry points -------------------------------------------------
 
-    # dt-baked artifacts (campaign rung cache) include the adjoint entries
-    _DT_ARTIFACTS = ("_adj_n", "_adj_consts") + CampaignModelBase._DT_ARTIFACTS
+    # dt-baked artifacts (campaign rung cache) include the sweeps' entries
+    _DT_ARTIFACTS = (
+        "_adj_n", "_adj_n_jit", "_adj_consts", "_fwd_n", "_fwd_n_jit", "_fwd_consts",
+        "_sweep_programs",
+    ) + CampaignModelBase._DT_ARTIFACTS
 
     def _dt_changed(self, dt: float) -> None:
         """Propagate a campaign dt change into the embedded Navier2D (whose
@@ -489,28 +518,45 @@ class Navier2DLnse(CampaignModelBase, Integrate):
         bounds the rebuild cost."""
         self.navier.set_dt(dt)
 
+    def _compile_entry_points(self) -> None:
+        """The compile seam, with the sweeps' constants counted beside the
+        chunks' for the spans' ``unplaced_args``."""
+        from ..parallel.mesh import unplaced
+
+        super()._compile_entry_points()
+        self._unplaced_consts += unplaced(
+            (self._adj_consts, self._fwd_consts), getattr(self, "mesh", None)
+        )
+
     def _compile_entry_points_impl(self) -> None:
         """The campaign entry points (hoisted ``_step_cc``/``_obs_cc``,
-        chunked scans, sentinels — CampaignModelBase) plus the lnse-specific
-        ADJOINT loop entries of the linearized model.  Overrides the IMPL
+        chunked scans, sentinels — CampaignModelBase) plus the two sweeps of
+        ``grad_adjoint``, through the same hoisting seam.  Overrides the IMPL
         hook (not the timed wrapper), so the per-kind compile attribution
-        covers the adjoint-loop hoist+jit too."""
+        covers the sweeps' hoist+jit too."""
         super()._compile_entry_points_impl()
-        if self.NONLINEAR:
-            return
-        nav = self.navier
-        example = self._state_example()
+        #: (direction, bucket) of every sweep program dispatched so far: a
+        #: bucket length seen before builds nothing
+        self._sweep_programs = set()
+        self._compile_sweep_entry_points(self._state_example())
+
+    def _compile_sweep_entry_points(self, example) -> None:
+        """``_adj_n(state, history, n)``: n adjoint steps.  The linear
+        model's forward sweep is ``update_n`` and keeps no history."""
         adj = self._make_adjoint_step()
-        adj_cc, adj_consts = nav._hoist(lambda s: adj(s), example)
-        self._adj_consts = adj_consts
+        adj_cc, self._adj_consts = self._hoist(lambda s: adj(s), example)
+        self._fwd_n, self._fwd_consts = None, None
 
         def adj_n(consts, state, n: int):
             return jax.lax.scan(
                 lambda c, _: (adj_cc(consts, c), None), state, None, length=n
             )[0]
 
-        adj_n_jit = jax.jit(adj_n, static_argnames=("n",))
-        self._adj_n = lambda s, n: adj_n_jit(self._adj_consts, s, n=n)
+        # the jit objects are retained, as ``_step_n_jit`` is: a stage table
+        # lowers them to read the scopes (scripts/stage_times.py)
+        self._fwd_n_jit = None
+        self._adj_n_jit = adj_n_jit = jax.jit(adj_n, static_argnames=("n",))
+        self._adj_n = lambda s, history, n: adj_n_jit(self._adj_consts, s, n=n)
 
     # -- Integrate protocol ----------------------------------------------------
     # update/update_n/update_n_pending, sentinels, set_dt, observable
@@ -595,13 +641,80 @@ class Navier2DLnse(CampaignModelBase, Integrate):
             nav.temp_space.backward(state.temp),
         )
 
+    def _host_seam(self, name: str):
+        """The iteration's small device programs between the sweeps, each one
+        hoisted jit (one dispatch where the eager form made dozens):
+        ``physical(state)``, ``energy(state, target, beta1, beta2)``,
+        ``terminal(state, target, beta1, beta2)`` and ``forward(velx, vely,
+        temp)``; ``target`` is the target's three ortho-space fields.  None
+        depends on dt; built at first use."""
+        if self._host_seams is None:
+            nav = self.navier
+            sp_t, sp_u, sp_v, sp_f = nav.temp_space, nav.velx_space, nav.vely_space, nav.field_space
+            rdt = config.real_dtype()
+            state = self._state_example()
+            vhat = jax.ShapeDtypeStruct(sp_f.shape_spectral, sp_f.spectral_dtype())
+            phys = jax.ShapeDtypeStruct(sp_f.shape_physical, rdt)
+            beta = jax.ShapeDtypeStruct((), rdt)
+
+            def energy(st, target, beta1, beta2):
+                u, v, t = self._phys(st)
+                tu, tv, tt = (sp_f.backward_ortho(x) for x in target)
+                u, v, t = u - tu, v - tv, t - tt
+                return l2_norm(u, u, v, v, t, t, beta1, beta2)
+
+            def terminal(st, target, beta1, beta2):
+                return st._replace(
+                    velx=(st.velx - sp_u.from_ortho(target[0])) * beta1,
+                    vely=(st.vely - sp_v.from_ortho(target[1])) * beta1,
+                    temp=(st.temp - sp_t.from_ortho(target[2])) * beta2,
+                )
+
+            def forward(velx, vely, temp):
+                return sp_u.forward(velx), sp_v.forward(vely), sp_t.forward(temp)
+
+            def hoisted(fn, *example):
+                cc, consts = self._hoist(fn, *example)
+                jitted = jax.jit(cc)
+                return lambda *args: jitted(consts, *args)
+
+            self._host_seams = {
+                "physical": hoisted(self._phys, state),
+                "energy": hoisted(energy, state, (vhat,) * 3, beta, beta),
+                "terminal": hoisted(terminal, state, (vhat,) * 3, beta, beta),
+                "forward": hoisted(forward, phys, phys, phys),
+            }
+        return self._host_seams[name]
+
+    def _target_fields(self, target: MeanFields | None):
+        """The target's ortho-space fields; zeros without a target."""
+        if target is None:
+            return (self.navier.field_space.ndarray_spectral(),) * 3
+        return target.velx, target.vely, target.temp
+
+    def physical(self):
+        """Physical values ``(velx, vely, temp)`` of the state, one dispatch."""
+        with self.navier._scope():
+            return self._host_seam("physical")(self.state)
+
+    def set_fields(self, velx, vely, temp) -> None:
+        """``set_field`` of the three prognostic fields from physical values,
+        as one dispatch (pres and pseu untouched)."""
+        rdt = config.real_dtype()
+        nav = self.navier
+        with nav._scope():
+            vhats = self._host_seam("forward")(*(jnp.asarray(a, dtype=rdt) for a in (velx, vely, temp)))
+            self.state = self.state._replace(
+                **{k: nav._place(v) for k, v in zip(("velx", "vely", "temp"), vhats)}
+            )
+        self._obs_cache = None
+
     def energy(self, beta1: float, beta2: float, target: MeanFields | None = None):
         """l2_norm of the current (optionally target-shifted) state."""
-        u, v, t = self._phys(self.state)
-        if target is not None:
-            tu, tv, tt = target.physical()
-            u, v, t = u - tu, v - tv, t - tt
-        return float(l2_norm(u, u, v, v, t, t, beta1, beta2))
+        with self.navier._scope():
+            return float(
+                self._host_seam("energy")(self.state, self._target_fields(target), beta1, beta2)
+            )
 
     def _zero_state(self) -> NavierState:
         return NavierState(
@@ -615,15 +728,38 @@ class Navier2DLnse(CampaignModelBase, Integrate):
     def _adjoint_ic(self, state, beta1, beta2, target):
         """Terminal condition of the adjoint loop: fields scaled by the norm
         weights (minus target) with pressure kept (lnse_adj_grad.rs:155-168)."""
-        nav = self.navier
-        velx, vely, temp = state.velx, state.vely, state.temp
-        if target is not None:
-            velx = velx - nav.velx_space.from_ortho(target.velx)
-            vely = vely - nav.vely_space.from_ortho(target.vely)
-            temp = temp - nav.temp_space.from_ortho(target.temp)
-        return state._replace(
-            velx=velx * beta1, vely=vely * beta1, temp=temp * beta2
+        return self._host_seam("terminal")(state, self._target_fields(target), beta1, beta2)
+
+    def _launch_sweep(self, name: str, step_k, carry, lengths):
+        """``carry = step_k(carry, k)`` for each program length of a sweep,
+        each under a ``model.launch`` span as ``update_n``'s buckets are;
+        returns the carry, the launches made and how many of the programs
+        were dispatched for the first time (built, or loaded from the
+        persistent compile cache)."""
+        built = 0
+        for k in lengths:
+            built += (name, int(k)) not in self._sweep_programs
+            self._sweep_programs.add((name, int(k)))
+            with _tr.span("model.launch", layer=_LAYER, steps=int(k), aot=False):
+                carry = step_k(carry, int(k))
+        return carry, len(lengths), built
+
+    def _forward_sweep(self, n: int):
+        """n forward steps from ``self.state``; returns ``(history, launches,
+        programs built)``.  The linear adjoint reads no trajectory, so the
+        sweep is ``update_n`` itself and the history is empty."""
+        self.update_n(n)
+        return (), len(scan_buckets(n)), 0
+
+    def _adjoint_sweep(self, n: int, history):
+        """n adjoint steps from ``self.state`` (the terminal condition);
+        returns ``(launches, programs built)``.  The linear adjoint carries
+        nothing but its state, so it runs in ``run_scanned``'s power-of-two
+        buckets, as ``update_n`` does."""
+        self.state, launches, built = self._launch_sweep(
+            "adjoint", lambda s, k: self._adj_n(s, history, k), self.state, scan_buckets(n)
         )
+        return launches, built
 
     def grad_adjoint(
         self,
@@ -635,26 +771,41 @@ class Navier2DLnse(CampaignModelBase, Integrate):
         outfile: str | None = None,
     ):
         """Hand-adjoint gradient of the final energy w.r.t. the initial
-        condition (lnse_adj_grad.rs:105-205).
+        condition (lnse_adj_grad.rs:105-205; the nonlinear variant's adjoint
+        loop consumes the recorded forward trajectory backward,
+        nonlin_adj_grad.rs:120-223).
 
         Returns ``(fun_val, (grad_u, grad_v, grad_t))`` with gradients as
         physical-space numpy arrays.  MAXIMIZE flips the sign.
+
+        Both sweeps go through the hoisting compile seam and their programs
+        are kept with the model, so a horizon seen before builds nothing.
+        Spans (model step): ``lnse.grad_adjoint`` (``steps``, ``launches``,
+        ``history_bytes`` of the stacked trajectory, ``compiles`` = sweep
+        programs dispatched for the first time) > ``lnse.forward_sweep`` (to
+        the moment J is on the host) and ``lnse.adjoint_sweep`` (terminal
+        condition to the gradient on the host) > ``model.launch``.
         """
         del save_intervall  # device loop; intermediate snapshots not written
         n = max(1, round(max_time / self.dt))
-        self.update_n(n)
-        fun_val = self.energy(beta1, beta2, target)
-
-        with self.navier._scope():
-            self.state = self._adjoint_ic(self.state, beta1, beta2, target)
-            from ..utils.jit import run_scanned
-
-            self.state = run_scanned(self._adj_n, self.state, n)
-        self.reset_time()
-
-        fac = 1.0 if MAXIMIZE else -1.0
-        u, v, t = self._phys(self.state)
-        grads = (fac * np.asarray(u), fac * np.asarray(v), fac * np.asarray(t))
+        with _tr.span("lnse.grad_adjoint", layer=_LAYER, steps=2 * n) as whole:
+            with _tr.span("lnse.forward_sweep", layer=_LAYER, steps=n):
+                history, launches, built = self._forward_sweep(n)
+                fun_val = self.energy(beta1, beta2, target)
+            with _tr.span("lnse.adjoint_sweep", layer=_LAYER, steps=n):
+                with self.navier._scope():
+                    self.state = self._adjoint_ic(self.state, beta1, beta2, target)
+                    more = self._adjoint_sweep(n, history)
+                self.reset_time()
+                fac = 1.0 if MAXIMIZE else -1.0
+                grads = tuple(fac * a for a in jax.device_get(self.physical()))
+            whole.set(
+                launches=launches + more[0],
+                compiles=built + more[1],
+                history_bytes=sum(
+                    leaf.nbytes for leaf in jax.tree.leaves(history)
+                ),
+            )
         if outfile:
             self._write_grad(outfile, grads)
         return fun_val, grads
@@ -783,18 +934,25 @@ class Navier2DLnse(CampaignModelBase, Integrate):
 class Navier2DNonLin(Navier2DLnse):
     """Full nonlinear equations as a perturbation about the base state
     (nonlin.rs:23-57); the forward loop records the trajectory history the
-    adjoint convection terms need (nonlin_adj_grad.rs:186-190)."""
+    adjoint convection terms need (nonlin_adj_grad.rs:186-190).
+
+    Each sweep is ONE program per horizon, kept in the model's jit cache: the
+    forward scan stacks the trajectory ``(n, nx, ny)`` per field and the
+    adjoint scan reads it from its end.  ``update_n``'s power-of-two buckets
+    were tried for them (one stacked chunk a bucket, never joined) and cost
+    more than they save: a sweep's step lowers in 1-2 s of host time per
+    program at first use, so the five buckets of a 2500-step horizon made a
+    model's first ``grad_adjoint`` 22 s where the compile cache was warm, and
+    the upstream's campaign visits five horizons, fewer than the dozen bucket
+    lengths they break into (PERF.md section 6, PR 30)."""
 
     NONLINEAR = True
 
-    def _compile_entry_points_impl(self) -> None:
-        # impl-hook override (see the linear model's note): the nonlinear
-        # trajectory-recording entries stay inside the timed attribution
-        super()._compile_entry_points_impl()
+    def _compile_sweep_entry_points(self, example) -> None:
+        """``_fwd_n(state, n) -> (state, history)`` and
+        ``_adj_n(state, history, n)``, ``history`` the three stacked
+        ``(n, nx, ny)`` arrays of the trajectory."""
         nav = self.navier
-        example = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), NavierState(*nav.state)
-        )
         step = self._make_step()
         sp_u, sp_v, sp_t = nav.velx_space, nav.vely_space, nav.temp_space
 
@@ -802,66 +960,50 @@ class Navier2DNonLin(Navier2DLnse):
             new = step(state)
             # ortho-space history of the *new* fields (the reference stores
             # the post-step state, nonlin_adj_grad.rs:66-76)
-            hist = (
-                sp_u.to_ortho(new.velx),
-                sp_v.to_ortho(new.vely),
-                sp_t.to_ortho(new.temp),
-            )
+            with jax.named_scope("history"):
+                hist = (
+                    sp_u.to_ortho(new.velx),
+                    sp_v.to_ortho(new.vely),
+                    sp_t.to_ortho(new.temp),
+                )
             return new, hist
 
-        fwd_cc, fwd_consts = nav._hoist(fwd_with_history, example)
+        fwd_cc, self._fwd_consts = self._hoist(fwd_with_history, example)
         adj = self._make_adjoint_step()
         sds = jax.ShapeDtypeStruct(
             nav.field_space.shape_spectral, nav.field_space.spectral_dtype()
         )
-        hist_sds = (sds, sds, sds)
-        adj_cc, adj_consts = nav._hoist(
-            lambda s, h: adj(s, history=h), example, hist_sds
+        adj_cc, self._adj_consts = self._hoist(
+            lambda s, h: adj(s, history=h), example, (sds, sds, sds)
         )
-        self._fwd_consts = fwd_consts
-        self._nl_adj_consts = adj_consts
 
-        def fwd_scan(consts, state, n: int):
+        def fwd_n(consts, state, n: int):
             return jax.lax.scan(
                 lambda c, _: fwd_cc(consts, c), state, None, length=n
             )
 
-        def adj_scan(consts, state, history):
+        def adj_n(consts, state, history):
             return jax.lax.scan(
                 lambda c, h: (adj_cc(consts, c, h), None),
                 state,
                 jax.tree.map(lambda x: x[::-1], history),
             )[0]
 
-        self._fwd_scan = jax.jit(fwd_scan, static_argnames=("n",))
-        self._adj_scan = jax.jit(adj_scan)
+        self._fwd_n_jit = fwd_n_jit = jax.jit(fwd_n, static_argnames=("n",))
+        self._adj_n_jit = adj_n_jit = jax.jit(adj_n)
+        self._fwd_n = lambda s, n: fwd_n_jit(self._fwd_consts, s, n=n)
+        self._adj_n = lambda s, history, n: adj_n_jit(self._adj_consts, s, history)
 
-    def grad_adjoint(
-        self,
-        max_time: float,
-        save_intervall: float | None = None,
-        beta1: float = 0.5,
-        beta2: float = 0.5,
-        target: MeanFields | None = None,
-        outfile: str | None = None,
-    ):
-        """Nonlinear variant: the adjoint loop consumes the recorded forward
-        trajectory backward (nonlin_adj_grad.rs:120-223)."""
-        del save_intervall
-        n = max(1, round(max_time / self.dt))
+    def _forward_sweep(self, n: int):
         with self.navier._scope():
-            self.state, history = self._fwd_scan(self._fwd_consts, self.state, n=n)
+            (self.state, history), launches, built = self._launch_sweep(
+                "forward", self._fwd_n, self.state, [n]
+            )
         self.time += n * self.dt
-        fun_val = self.energy(beta1, beta2, target)
+        return history, launches, built
 
-        with self.navier._scope():
-            self.state = self._adjoint_ic(self.state, beta1, beta2, target)
-            self.state = self._adj_scan(self._nl_adj_consts, self.state, history)
-        self.reset_time()
-
-        fac = 1.0 if MAXIMIZE else -1.0
-        u, v, t = self._phys(self.state)
-        grads = (fac * np.asarray(u), fac * np.asarray(v), fac * np.asarray(t))
-        if outfile:
-            self._write_grad(outfile, grads)
-        return fun_val, grads
+    def _adjoint_sweep(self, n: int, history):
+        self.state, launches, built = self._launch_sweep(
+            "adjoint", lambda s, k: self._adj_n(s, history, k), self.state, [n]
+        )
+        return launches, built

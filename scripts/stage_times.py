@@ -3,6 +3,7 @@
     python scripts/stage_times.py --workload rbc513_f32.solo [--dispatches 2]
     python scripts/stage_times.py --workload swarm129_f32.batch --dispatches 6
     python scripts/stage_times.py --workload periodic1024_f32.mesh4   (4 chips)
+    python scripts/stage_times.py --workload lnse_opt128_f32.loop     (two tables)
 
 Builds the cell's own model (``BENCHMARK.json`` and ``benchmark/`` say what a
 cell is), warms one dispatch, traces a few under ``utils/profiling.trace`` and
@@ -16,6 +17,12 @@ solve, the share under no stage, and the share of device time whose
 instruction the chunk's text does not hold (the observables).  Also: the
 program's ``rustpde:`` spans the trace's host plane holds, and how long after
 a launch span opens the device starts on its chunk.
+
+For a cell of ``Navier2DNonLin`` (the optimal-perturbation iteration) the
+forward sweep and the adjoint sweep are traced apart, each as one bucket of
+the largest power of two in the cell's sweep, and each gets a table of its
+own: two programs number their fusions alike, so one trace of both could not
+be told apart by name.
 
 ``--size N`` shrinks the grid for a rehearsal on the CPU, where the table is
 empty (the CPU backend writes no device plane) and only control flow is shown.
@@ -34,7 +41,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 STAGES = ("buoyancy", "synthesis", "sentinels", "momentum_x", "momentum_y", "divergence",
-          "poisson", "projection", "pressure", "temperature", "scalar", "solid")
+          "poisson", "projection", "pressure", "temperature", "scalar", "solid",
+          "history", "history_terms")
 INNER = ("convection", "helmholtz", "fastdiag", "tensor_solve")
 #: the mesh's own scopes (parallel/decomp.py): the manual regions and, inside
 #: them, each hand-placed exchange by direction
@@ -96,6 +104,94 @@ def compiled_text(lowered) -> str:
         compilation_cache.reset_cache()
 
 
+def stage_table(red: dict, scopes: dict) -> tuple:
+    """``({stage: seconds}, seconds of operations the text does not hold)`` of
+    a reduced trace, each operation looked up by name and shape in ``scopes``."""
+    table, unknown = {}, 0.0
+    for name, seconds in red["ops"].items():
+        if name not in scopes:
+            unknown += seconds
+            continue
+        stage = stage_of(scopes[name]) or "(no stage)"
+        table[stage] = table.get(stage, 0.0) + seconds
+    return table, unknown
+
+
+def print_table(table: dict, unknown: float, steps: int) -> None:
+    total = sum(table.values()) + unknown
+    for stage, seconds in sorted(table.items(), key=lambda kv: -kv[1]):
+        print(f"  {stage:28s} {1e3 * seconds / steps:9.5f} ms/step  {100 * seconds / total:6.2f} %")
+    named = sum(v for s, v in table.items() if s != "(no stage)")
+    if total:
+        print(f"  under a named stage {100 * named / total:.2f} %; in the chunk's text but under no "
+              f"stage {100 * table.get('(no stage)', 0.0) / total:.2f} %; not in the chunk's text "
+              f"{100 * unknown / total:.2f} %")
+
+
+def sweep_tables(args, cfg: dict, traffic: dict) -> int:
+    """The two tables of a ``Navier2DNonLin`` cell: its forward sweep and its
+    adjoint sweep, one bucket each, traced apart."""
+    import copy
+
+    import jax
+
+    from benchmark import reduce as reducer
+    from benchmark.drivers import descent_loop
+    from rustpde_mpi_tpu import Navier2DNonLin
+    from rustpde_mpi_tpu.utils import profiling
+
+    cfg = copy.deepcopy(cfg)
+    if args.size:
+        cfg["grid"] = {"nx": args.size, "ny": args.size}
+        cfg["optimisation"]["base_time"] = 1.0
+    g, ph = cfg["grid"], cfg["physics"]
+    n = 1 << (int(traffic["steps_per_interval"]).bit_length() - 1)
+    if args.size:
+        n = min(n, 64)
+    mean = descent_loop.mean_fields(g["nx"], g["ny"], descent_loop.base_state(cfg, traffic, 0))
+    model = Navier2DNonLin.new_confined(
+        g["nx"], g["ny"], ph["ra"], ph["pr"], ph["dt"], ph["aspect"], ph["bc"], mean=mean)
+    model.init_random(1e-3, seed=0)
+    start = model.state
+    after, chunk = model._fwd_n(start, n)  # warms the forward bucket too
+    jax.block_until_ready(model._adj_n(after, chunk, n))
+    sweeps = {
+        "forward": (model._fwd_n_jit.lower(model._fwd_consts, start, n=n),
+                    lambda: model._fwd_n(start, n)),
+        "adjoint": (model._adj_n_jit.lower(model._adj_consts, after, chunk),
+                    lambda: model._adj_n(after, chunk, n)),
+    }
+    dev = jax.devices()[0]
+    out = {"workload": args.workload, "device": dev.device_kind, "steps": args.dispatches * n}
+    if args.out:
+        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    for name, (lowered, dispatch) in sweeps.items():
+        text = compiled_text(lowered)
+        scopes = scopes_of(text, reducer.short)
+        logdir = tempfile.mkdtemp(prefix="stage_times_")
+        with profiling.trace(logdir):
+            for _ in range(args.dispatches):
+                jax.block_until_ready(dispatch())
+        path = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"), recursive=True)[0]
+        red = reducer.reduce_xplane(path)
+        table, unknown = stage_table(red, scopes)
+        print(f"stage_times: {args.workload}, {name} sweep at {g['nx']} x {g['ny']}, "
+              f"{args.dispatches} dispatches of {n} steps on {dev.device_kind}; device busy "
+              f"{red['busy_s']:.4f} s of {red['window_s']:.4f} s, sum of operations "
+              f"{sum(red['ops'].values()):.4f} s")
+        print_table(table, unknown, args.dispatches * n)
+        out[name] = {"busy_s": red["busy_s"], "window_s": red["window_s"],
+                     "ops_s": sum(red["ops"].values()), "stages_s": table,
+                     "not_in_text_s": unknown}
+        if args.out:
+            with open(f"{os.path.splitext(args.out)[0]}.{name}.txt", "w", encoding="utf-8") as fh:
+                fh.write(text)  # the names above are this text's
+    if args.out:
+        with open(args.out, "w", encoding="utf-8") as fh:
+            json.dump(out, fh, indent=1)
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -110,6 +206,8 @@ def main(argv=None) -> int:
     _, _, cfg, traffic = load_cell(args.workload)
     for key, value in cfg.get("env", {}).items():
         os.environ[key] = str(value)
+    if "Navier2DNonLin" in cfg["entry"]:
+        return sweep_tables(args, cfg, traffic)
     import jax
     import numpy as np
 
@@ -149,24 +247,12 @@ def main(argv=None) -> int:
     red = reducer.reduce_xplane(path)
     steps = args.dispatches * n
     total = sum(red["ops"].values())
-    table, unknown = {}, 0.0
-    for name, seconds in red["ops"].items():
-        if name not in scopes:
-            unknown += seconds
-            continue
-        stage = stage_of(scopes[name]) or "(no stage)"
-        table[stage] = table.get(stage, 0.0) + seconds
+    table, unknown = stage_table(red, scopes)
     dev = jax.devices()[0]
     print(f"stage_times: {args.workload} at {nx} x {ny}" + (f" x {k} members" if k else "")
           + f", {args.dispatches} dispatches of {n} steps on {dev.device_kind}; device busy "
           f"{red['busy_s']:.4f} s of {red['window_s']:.4f} s, sum of operations {total:.4f} s")
-    for stage, seconds in sorted(table.items(), key=lambda kv: -kv[1]):
-        print(f"  {stage:28s} {1e3 * seconds / steps:9.5f} ms/step  {100 * seconds / total:6.2f} %")
-    named = sum(v for s, v in table.items() if s != "(no stage)")
-    if total:
-        print(f"  under a named stage {100 * named / total:.2f} %; in the chunk's text but under no "
-              f"stage {100 * table.get('(no stage)', 0.0) / total:.2f} %; not in the chunk's text "
-              f"{100 * unknown / total:.2f} %")
+    print_table(table, unknown, steps)
     kinds: dict = {}
     collectives = []
     for name, seconds in red["ops"].items():

@@ -63,6 +63,17 @@ _CASES = {
 }
 
 
+#: lines an example has to print.  The optimal-perturbation campaign's J
+#: sequence (f64, --tiny: one iteration) since PR 30, when its loop body moved
+#: into models/opt_routines.descent_iteration (same J to the last digit:
+#: tests/test_lnse_cell.py) and its base state's temperature became the total
+#: field (before: 1.443568e-03 about a base state without its conduction
+#: profile, which is no solution of the equations it perturbs)
+_PINNED = {
+    "navier_lnse_opt_reversals.py": ["  iter 0: J = 2.259963e-03  alpha = 1.000"],
+}
+
+
 def test_every_example_has_a_case():
     present = sorted(
         f for f in os.listdir(os.path.join(_REPO, "examples")) if f.endswith(".py")
@@ -87,3 +98,5 @@ def test_example_smoke(name, tmp_path):
         timeout=600,
     )
     assert res.returncode == 0, f"{name} rc={res.returncode}\n{res.stderr[-2500:]}"
+    for line in _PINNED.get(name, ()):
+        assert line in res.stdout, f"{name}: {line!r} not in\n{res.stdout[-1500:]}"
