@@ -4,7 +4,8 @@ One script covers /root/reference/examples/{navier_mpi, navier_periodic_mpi,
 navier_periodic_hc_mpi}.rs: the same ``Navier2D`` model pencil-sharded over a
 ``jax.sharding.Mesh`` of all visible devices (physical y-pencils / spectral
 x-pencils with XLA all-to-all pencil flips — the GSPMD form of the
-reference's Decomp2d transposes).  On one real chip this degenerates to a
+reference's Decomp2d transposes; a periodic model rests the other way round,
+``Space2.rest``).  On one real chip this degenerates to a
 1-device mesh; run under a virtual CPU mesh to exercise the collectives:
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\

@@ -694,19 +694,20 @@ class SplitFourierBase(Base):
         if order % 2 == 1 and self.n % 2 == 0:
             k = k.copy()
             k[-1] = 0.0  # Nyquist of odd derivatives (see fourier.diff_diag)
-        kd = jnp.asarray(k, dtype=vhat.dtype)
         shape = [1] * vhat.ndim
+        quadrant = order % 4
+        if quadrant % 2 == 0:
+            # an even derivative keeps the halves where they are: a diagonal,
+            # which needs no slice of the axis (nor, on a mesh, the axis whole)
+            shape[axis] = 2 * mc
+            k = np.concatenate([k, k]) * (1.0 if quadrant == 0 else -1.0)
+            return vhat * jnp.asarray(k, dtype=vhat.dtype).reshape(shape)
         shape[axis] = mc
-        kd = kd.reshape(shape)
+        kd = jnp.asarray(k, dtype=vhat.dtype).reshape(shape)
         re = jax.lax.slice_in_dim(vhat, 0, mc, axis=axis)
         im = jax.lax.slice_in_dim(vhat, mc, 2 * mc, axis=axis)
-        quadrant = order % 4
-        if quadrant == 0:
-            re_n, im_n = kd * re, kd * im
-        elif quadrant == 1:
+        if quadrant == 1:
             re_n, im_n = -kd * im, kd * re
-        elif quadrant == 2:
-            re_n, im_n = -kd * re, -kd * im
         else:
             re_n, im_n = kd * im, -kd * re
         return jnp.concatenate([re_n, im_n], axis=axis)
@@ -844,8 +845,9 @@ class Space2:
         # Under a mesh the spectral operators (gradient, to_ortho, from_ortho)
         # state the pencil layout they run in, as the transforms always have.
         # A model whose mesh program is not GSPMD's to place clears this at its
-        # build (models/navier.py); the transforms' flips are part of that
-        # program.
+        # build (models/navier.py), before it builds its solvers; the
+        # transforms' flips are part of that program, and stay the x-pencil
+        # ones it was written for.
         self.states_layout = True
 
     @property
@@ -885,12 +887,57 @@ class Space2:
 
     # -- transforms ---------------------------------------------------------
     #
-    # Pencil discipline (active only under a parallel mesh): physical data is
-    # a y-pencil (axis 0 sharded), spectral an x-pencil (axis 1 sharded); each
-    # 2-D transform works on its local axis, flips pencils in between —
-    # exactly funspace's forward_inplace_mpi = [transform y][transpose y->x]
-    # [transform x] (/root/reference/src/field_mpi.rs:324-333), with the
-    # all-to-all left to XLA GSPMD.
+    # Pencil discipline (active only under a parallel mesh): each 2-D
+    # transform works on its local axis and flips pencils in between, the
+    # all-to-all left to XLA GSPMD.  Spectral arrays rest in the pencil of the
+    # axis a synthesis takes first, physical ones in that of the axis it takes
+    # last.  A confined space: x first, so spectral data is an x-pencil (axis
+    # 1 sharded), where its dense x-operators run in place, and physical data
+    # a y-pencil (axis 0 sharded) — exactly funspace's forward_inplace_mpi =
+    # [transform y][transpose y->x][transform x]
+    # (/root/reference/src/field_mpi.rs:324-333).  A Fourier x Chebyshev
+    # space: spectral data is a y-pencil, where every solve, y-derivative,
+    # stencil and cast runs in place, because every spectral x-operator
+    # between two transforms is a diagonal there but one (the odd derivative
+    # of the split layout, ``gradient``); so a synthesis takes y first and
+    # leaves physical data an x-pencil.  One flip a transform either way.
+
+    @property
+    def rest(self) -> tuple:
+        """The pencil layout spectral arrays of this space rest in under a
+        mesh: decided by what the x-base is."""
+        from .parallel.mesh import LOCAL
+
+        y_local = (
+            self.bases[0].kind.is_periodic
+            and self.bases[1].kind.is_chebyshev
+            and self.states_layout
+        )
+        return LOCAL[1 if y_local else 0]
+
+    @property
+    def synthesis_axes(self) -> tuple[int, int]:
+        """The order in which a synthesis takes the axes (an analysis takes
+        the reverse).  Where arrays are distributed, the axis that is local
+        at rest first: any other order costs a second flip.  Where nothing is
+        distributed every order is free of flips, and x goes first: a
+        synthesis shrinks x (1026 split rows to 1024 points) and widens y
+        (1023 composite modes to 1025 points), so x first keeps the free
+        extent of both products within eight MXU tiles of 128 where y first
+        takes nine (measured on one chip at 1024 x 1025: the step 4 % slower,
+        PERF.md section 6, PR 31)."""
+        from .parallel.mesh import LOCAL, active_mesh
+
+        if active_mesh() is not None and self.rest == LOCAL[1]:
+            return (1, 0)
+        return (0, 1)
+
+    @property
+    def physical(self) -> tuple:
+        """The pencil layout a synthesis leaves physical arrays in."""
+        from .parallel.mesh import LOCAL
+
+        return LOCAL[self.synthesis_axes[1]]
 
     def _axis_method(self, axis: int) -> str:
         """Per-axis transform path; under an active mesh Chebyshev axes use
@@ -918,43 +965,39 @@ class Space2:
 
     def forward(self, v):
         """Physical (..., n_x, n_y) -> spectral (..., m_x, m_y)."""
-        from .parallel.mesh import PHYS, SPEC, constrain
+        from .parallel.mesh import LOCAL, constrain
 
         ax = self._batch_ax(v)
-        out = self.bases[1].forward(
-            constrain(v, PHYS), ax + 1, self._axis_method(1), sep=self.sep[1]
-        )
-        out = self.bases[0].forward(
-            constrain(out, SPEC), ax, self._axis_method(0), sep=self.sep[0]
-        )
-        return constrain(out, SPEC)
+        out = v
+        for axis in reversed(self.synthesis_axes):
+            out = self.bases[axis].forward(
+                constrain(out, LOCAL[axis]), ax + axis, self._axis_method(axis),
+                sep=self.sep[axis],
+            )
+        return constrain(out, self.rest)
+
+    def _synthesis(self, name: str, vhat):
+        """``name`` (a base's ``backward`` or ``backward_ortho``) axis by
+        axis, each where it is local."""
+        from .parallel.mesh import LOCAL, constrain
+
+        ax = self._batch_ax(vhat)
+        out = vhat
+        for axis in self.synthesis_axes:
+            out = getattr(self.bases[axis], name)(
+                constrain(out, LOCAL[axis]), ax + axis, self._axis_method(axis),
+                sep=self.sep[axis],
+            )
+        return constrain(out, self.physical)
 
     def backward(self, vhat):
         """Spectral (..., m_x, m_y) -> physical (..., n_x, n_y)."""
-        from .parallel.mesh import PHYS, SPEC, constrain
-
-        ax = self._batch_ax(vhat)
-        out = self.bases[0].backward(
-            constrain(vhat, SPEC), ax, self._axis_method(0), sep=self.sep[0]
-        )
-        out = self.bases[1].backward(
-            constrain(out, PHYS), ax + 1, self._axis_method(1), sep=self.sep[1]
-        )
-        return constrain(out, PHYS)
+        return self._synthesis("backward", vhat)
 
     def backward_ortho(self, c):
         """Physical values from orthogonal-space coefficients (the space the
         reference's scratch ``field`` provides, /root/reference/src/navier_stokes/navier.rs:256)."""
-        from .parallel.mesh import PHYS, SPEC, constrain
-
-        ax = self._batch_ax(c)
-        out = self.bases[0].backward_ortho(
-            constrain(c, SPEC), ax, self._axis_method(0), sep=self.sep[0]
-        )
-        out = self.bases[1].backward_ortho(
-            constrain(out, PHYS), ax + 1, self._axis_method(1), sep=self.sep[1]
-        )
-        return constrain(out, PHYS)
+        return self._synthesis("backward_ortho", c)
 
     def forward_dealiased(self, v, fast: bool = False):
         """Physical -> spectral with the 2/3-rule mask applied, in one fused
@@ -964,22 +1007,19 @@ class Space2:
         vector multiply.  Callers keep a ``forward() * mask`` fallback for
         fully non-sep spaces.  ``fast=True`` selects the 3-pass variant
         gated by RUSTPDE_FWD_PRECISION (default off — see Base._sep_dev)."""
-        from .parallel.mesh import PHYS, SPEC, constrain
+        from .parallel.mesh import LOCAL, constrain
 
         if not any(self.sep):
             raise ValueError("forward_dealiased requires at least one sep axis")
         ax = self._batch_ax(v)
         key = ("fwd_cut", "fast") if fast else "fwd_cut"
-        out = constrain(v, PHYS)
-        if self.sep[1]:
-            out = self.bases[1]._sep_dev(key).apply(out, ax + 1)
-        else:
-            out = self.bases[1].forward(out, ax + 1, self._axis_method(1))
-        out = constrain(out, SPEC)
-        if self.sep[0]:
-            out = self.bases[0]._sep_dev(key).apply(out, ax)
-        else:
-            out = self.bases[0].forward(out, ax, self._axis_method(0))
+        out = v
+        for axis in reversed(self.synthesis_axes):
+            out = constrain(out, LOCAL[axis])
+            if self.sep[axis]:
+                out = self.bases[axis]._sep_dev(key).apply(out, ax + axis)
+            else:
+                out = self.bases[axis].forward(out, ax + axis, self._axis_method(axis))
         for axis in (0, 1):
             if not self.sep[axis]:
                 cut = self.bases[axis].dealias_cut()
@@ -988,7 +1028,7 @@ class Space2:
                 out = out * jnp.asarray(
                     cut.reshape(shape), dtype=config.real_dtype()
                 )
-        return constrain(out, SPEC)
+        return constrain(out, self.rest)
 
     def backward_gradient(self, vhat, deriv, scale=None, fast=False):
         """Physical values of d^deriv[0]/dx d^deriv[1]/dy — the fused
@@ -997,16 +1037,21 @@ class Space2:
         plain fused backward), saving the separate gradient apply.  Non-sep
         axes (e.g. the split-Fourier axis of a periodic space) run
         gradient-then-synthesis on that axis, so mixed spaces still fuse
-        their Chebyshev axis, and under a mesh every y-operator runs after
-        the flip, where y is local.  ``fast=True`` selects the 3-pass
+        their Chebyshev axis, and under a mesh each axis's derivative runs
+        with its synthesis, where that axis is local (the odd x-derivative of
+        a split base too).  ``fast=True`` selects the 3-pass
         synthesis variants (DNS convection path only — see Base._sep_dev)."""
-        from .parallel.mesh import PHYS, SPEC, constrain
+        from .parallel.mesh import LOCAL, constrain
 
         ax = self._batch_ax(vhat)
-        out = constrain(vhat, SPEC)
-        for axis in (0, 1):
+        out = vhat
+        for axis in self.synthesis_axes:
             b = self.bases[axis]
             a = ax + axis
+            # the pencil in which this axis is local: the resting one for the
+            # first axis, after the flip for the second, as in
+            # backward()/backward_ortho()
+            out = constrain(out, LOCAL[axis])
             if self.sep[axis]:
                 key = ("bwd_grad", deriv[axis]) if deriv[axis] else "bwd"
                 if fast:
@@ -1015,10 +1060,7 @@ class Space2:
             else:
                 out = b.gradient(out, deriv[axis], a, sep=False)
                 out = b.backward_ortho(out, a, self._axis_method(axis))
-            # pencil flip: the half-transformed intermediate moves to the
-            # physical (y-pencil) layout before the axis-1 apply, as in
-            # backward()/backward_ortho()
-            out = constrain(out, PHYS)
+        out = constrain(out, self.physical)
         if scale is not None:
             factor = (scale[0] ** deriv[0]) * (scale[1] ** deriv[1])
             if factor != 1.0:
@@ -1033,22 +1075,25 @@ class Space2:
         return self.backward_gradient(vhat, (0, 0), None, fast=True)
 
     def to_ortho(self, vhat):
-        from .parallel.mesh import SPEC
-
         ax = self._batch_ax(vhat)
-        out = self.bases[0].to_ortho(self._pin(vhat, SPEC), ax, sep=self.sep[0])
-        return self._pin(self.bases[1].to_ortho(out, ax + 1, sep=self.sep[1]), SPEC)
+        out = self.bases[0].to_ortho(self._pin(vhat, self.rest), ax, sep=self.sep[0])
+        return self._pin(self.bases[1].to_ortho(out, ax + 1, sep=self.sep[1]), self.rest)
 
     # Spectral operators under a mesh: an operator that needs a whole axis
-    # runs where that axis is local, and says so itself.  Spectral arrays rest
-    # as x-pencils (SPEC: x local), so x-operators run in place; a y-operator
-    # that needs the whole y extent at once (a Chebyshev derivative, the dense
+    # runs where that axis is local, and says so itself; arrays rest where
+    # most operators need no flip (``rest``).  On a confined space that is the
+    # x-pencil: the dense x-operators run in place, and a y-operator that
+    # needs the whole y extent at once (a Chebyshev derivative, the dense
     # composite cast of from_ortho) is taken between a pair of flips to the
     # y-pencil layout; the banded to_ortho stencil costs two halo rows where
-    # it is and stays.  Left to propagation, GSPMD runs the parity interleave
-    # of such an operator ALONG the sharded axis: every device scatters its
-    # rows into a zero field and the fields are summed, a whole-field
-    # all-reduce per interleave.
+    # it is and stays.  On a Fourier x Chebyshev space it is the y-pencil:
+    # every y-operator runs in place, the x-operators are diagonals that need
+    # no layout, and the one that does need the whole x extent (the odd
+    # derivative of the split layout, which swaps the Re and Im halves of the
+    # x extent) is the one taken between a pair of flips.  Left to
+    # propagation, GSPMD runs the parity interleave or the half swap of such
+    # an operator ALONG the sharded axis: every device scatters its rows into
+    # a zero field and the fields are summed, a whole-field all-reduce each.
 
     def _pin(self, a, spec):
         """``constrain`` for the spectral operators below, unless the model
@@ -1057,24 +1102,33 @@ class Space2:
 
         return constrain(a, spec) if self.states_layout else a
 
-    def _where_y_is_local(self, apply, c):
-        """``apply`` (y-operators that need the whole y extent) on a spectral
-        array resting as an x-pencil: between a stated pair of flips."""
-        from .parallel.mesh import PHYS, SPEC
+    def _where_local(self, axis: int, apply, c):
+        """``apply`` (operators that need the whole extent of ``axis``) on a
+        spectral array at rest: in place where the resting pencil has that
+        axis local, else between a stated pair of flips."""
+        from .parallel.mesh import LOCAL
 
-        return self._pin(apply(self._pin(c, PHYS)), SPEC)
+        return self._pin(apply(self._pin(c, LOCAL[axis])), self.rest)
+
+    def _whole(self, axis: int, order: int) -> bool:
+        """Whether the ``order``-th derivative along ``axis`` needs the whole
+        extent at once: a Chebyshev recurrence or product does, a Fourier
+        diagonal does not, the odd derivative of the split layout (Re and Im
+        halves swapped) does."""
+        kind = self.bases[axis].kind
+        if kind.is_chebyshev:
+            return order >= 1
+        return kind.is_split and order % 2 == 1
 
     def from_ortho(self, c):
-        from .parallel.mesh import SPEC
-
         ax = self._batch_ax(c)
         out = c
         if not self.bases[0].is_orthogonal:
-            out = self.bases[0].from_ortho(self._pin(out, SPEC), ax, sep=self.sep[0])
+            out = self.bases[0].from_ortho(self._pin(out, self.rest), ax, sep=self.sep[0])
         if self.bases[1].is_orthogonal:
             return out
-        return self._where_y_is_local(
-            lambda a: self.bases[1].from_ortho(a, ax + 1, sep=self.sep[1]), out
+        return self._where_local(
+            1, lambda a: self.bases[1].from_ortho(a, ax + 1, sep=self.sep[1]), out
         )
 
     def gradient(self, vhat, deriv, scale=None, into: "Space2 | None" = None):
@@ -1084,9 +1138,10 @@ class Space2:
         cast to, ``into.from_ortho(gradient(.))`` (the projection's velocity
         correction), so that a y-derivative and the cast share one visit to
         the layout in which y is local."""
-        from .parallel.mesh import SPEC
+        from .parallel.mesh import LOCAL
 
         ax = self._batch_ax(vhat)
+        rest = self.rest
         factor = 1.0
         if scale is not None:
             factor = (scale[0] ** deriv[0]) * (scale[1] ** deriv[1])
@@ -1100,18 +1155,23 @@ class Space2:
                 return a
             return into.bases[1].from_ortho(a, ax + 1, sep=into.sep[1])
 
+        def along_x(a):
+            return self.bases[0].gradient(a, deriv[0], ax, sep=self.sep[0])
+
         out = vhat
-        if deriv[0] or not self.bases[0].is_orthogonal:
-            out = self.bases[0].gradient(self._pin(out, SPEC), deriv[0], ax, sep=self.sep[0])
+        if self._whole(0, deriv[0]) and rest != LOCAL[0]:
+            out = self._where_local(0, along_x, out)
+        elif deriv[0] or not self.bases[0].is_orthogonal:
+            out = along_x(self._pin(out, rest))
         if into is not None and not into.bases[0].is_orthogonal:
-            out = into.bases[0].from_ortho(self._pin(out, SPEC), ax, sep=into.sep[0])
-        if self.bases[1].kind.is_chebyshev and deriv[1] >= 1:
-            return self._where_y_is_local(lambda a: cast_y(along_y(a)), out)
+            out = into.bases[0].from_ortho(self._pin(out, rest), ax, sep=into.sep[0])
+        if self._whole(1, deriv[1]):
+            return self._where_local(1, lambda a: cast_y(along_y(a)), out)
         # the banded stencil, a diagonal or nothing: where it is
-        out = self._pin(along_y(self._pin(out, SPEC)), SPEC)
+        out = self._pin(along_y(self._pin(out, rest)), rest)
         if into is None or into.bases[1].is_orthogonal:
             return out
-        return self._where_y_is_local(cast_y, out)
+        return self._where_local(1, cast_y, out)
 
     # -- representation-aware helpers ---------------------------------------
 

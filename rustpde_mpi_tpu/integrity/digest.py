@@ -42,6 +42,8 @@ def _leaf_digest(x):
     import jax.numpy as jnp
     from jax import lax
 
+    from ..parallel.mesh import constrain
+
     if jnp.issubdtype(x.dtype, jnp.complexfloating):
         return _leaf_digest(jnp.real(x)) * _GOLD + _leaf_digest(jnp.imag(x))
     if x.dtype == jnp.bool_:
@@ -62,6 +64,10 @@ def _leaf_digest(x):
         i = lax.broadcasted_iota(jnp.uint32, bits.shape, d)
         h = i if h is None else h * jnp.uint32(1000003) + i
     mixed = bits ^ (h * _GOLD)
+    # under a mesh: whole on every device first.  No backend sums an xor over
+    # devices ("Unsupported reduction computation"), and a leaf whose sharded
+    # extent divides the mesh is really cut (parallel/mesh.py)
+    mixed = constrain(mixed, (None,) * mixed.ndim)
     axes = tuple(range(mixed.ndim))
     s = jnp.sum(mixed, dtype=jnp.uint32)
     xo = lax.reduce(mixed, jnp.uint32(0), lax.bitwise_xor, axes)

@@ -288,10 +288,26 @@ class CampaignModelBase:
         return use_mesh(self.mesh)
 
     def _place(self, arr):
-        """Put a spectral array into x-pencil layout under the mesh."""
-        from ..parallel.mesh import SPEC, device_put
+        """Put a spectral array into the pencil layout its space rests in
+        under the mesh (``Space2.rest``; all of a model's spaces rest
+        alike)."""
+        from ..parallel.mesh import device_put
 
-        return device_put(arr, SPEC)
+        return device_put(arr, self.temp_space.rest)
+
+    def _hand_back(self, state):
+        """A chunk program's state as it leaves the program: every leaf laid
+        out as ``_place`` laid it out going in (``parallel.mesh.settle``), so
+        that one executable serves every dispatch.  The state as it is
+        without a mesh."""
+        import jax
+
+        from ..parallel.mesh import settle
+
+        if getattr(self, "mesh", None) is None:
+            return state
+        rest = self.temp_space.rest
+        return jax.tree.map(lambda leaf: settle(leaf, rest), state)
 
     def _hoist(self, fn, *example):
         """``hoist_constants`` under this model's mesh, with the constants
@@ -410,7 +426,10 @@ class CampaignModelBase:
             self._compile_eager_entry_points()
             return
 
-        step_jit = jax.jit(step_cc)
+        def step_once(consts, state):
+            return self._hand_back(step_cc(consts, state))
+
+        step_jit = jax.jit(step_once)
         self._step = lambda s: step_jit(self._step_consts, s)
 
         def step_n(consts, state, n: int):
@@ -433,7 +452,7 @@ class CampaignModelBase:
 
             init = (state, jnp.asarray(True), jnp.asarray(0, jnp.int32))
             (final, _, done), _ = jax.lax.scan(body, init, None, length=n)
-            return final, done
+            return self._hand_back(final), done
 
         # the chunk donates nothing: XLA writes the scan's result to fresh
         # buffers, so update_n hands it ``self.state`` as it is and a
@@ -505,7 +524,7 @@ class CampaignModelBase:
 
             init = (state, ss, tick, jnp.asarray(True), jnp.asarray(0, jnp.int32))
             (st, ss, tk, _, done), _ = jax.lax.scan(body, init, None, length=n)
-            return st, ss, tk, done
+            return self._hand_back(st), ss, tk, done
 
         stats_jit = jax.jit(step_n_stats, static_argnames=("n",))
         self._step_n_stats = lambda s, ss, tk, n: stats_jit(
@@ -624,7 +643,7 @@ class CampaignModelBase:
                 return carry2, None
 
             final, _ = jax.lax.scan(body, carry, None, length=n)
-            return final
+            return (self._hand_back(final[0]),) + final[1:]
 
         sent_jit = jax.jit(step_n_sent, static_argnames=("n",))
         self._step_n_sent = lambda c, n: sent_jit(
@@ -907,7 +926,10 @@ class CampaignModelBase:
 
         from ..integrity import digest_tree
 
-        dig_cc, dig_consts = self._hoist(digest_tree, example)
+        # a function of this model's own: the digest states a layout under a
+        # mesh (whole on every device), and a trace of the module's one
+        # ``digest_tree`` would be found again, mesh and all, by the next model
+        dig_cc, dig_consts = self._hoist(lambda state: digest_tree(state), example)
         self._dig_cc = dig_cc
         self._dig_consts = dig_consts
         dig_jit = jax.jit(dig_cc)

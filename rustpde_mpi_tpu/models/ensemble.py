@@ -377,7 +377,7 @@ class NavierEnsemble(Integrate):
                 return carry2, None
 
             (st, mk, dn), _ = jax.lax.scan(body, (states, mask, done), None, length=n)
-            return st, mk, dn
+            return model._hand_back(st), mk, dn
 
         # nothing is donated (see module docstring): the chunk takes the
         # caller-visible carry as it is and writes fresh buffers
@@ -467,7 +467,7 @@ class NavierEnsemble(Integrate):
             (st, ss, tk, mk, dn), _ = jax.lax.scan(
                 body, (states, ss, tick, mask, done), None, length=n
             )
-            return st, ss, tk, mk, dn
+            return model._hand_back(st), ss, tk, mk, dn
 
         stats_jit = jax.jit(ens_step_n_stats, static_argnames=("n",))
         self._step_n_stats = lambda st, ss, tk, mk, dn, n: stats_jit(
@@ -562,7 +562,7 @@ class NavierEnsemble(Integrate):
                 return carry2, None
 
             final, _ = jax.lax.scan(body, carry, None, length=n)
-            return final
+            return (model._hand_back(final[0]),) + final[1:]
 
         sent_jit = jax.jit(ens_step_n_sent, static_argnames=("n",))
         self._step_n_sent = lambda c, n: sent_jit(

@@ -236,6 +236,15 @@ class Navier2D(CampaignModelBase, Integrate):
         self._inv_dx = jnp.asarray(1.0 / dx0, dtype=rdt)
         self._inv_dy = jnp.asarray(1.0 / dy0, dtype=rdt)
 
+        # the poisoned layout's mesh program is parallel/decomp.py's regions
+        # (or the eager fallback), not GSPMD's to place: its spaces' spectral
+        # operators state no layout of their own there, and its transforms
+        # and solves (built below) keep the x-pencil rest those regions take
+        if self._split_sep_poisoned():
+            for space in (self.velx_space, self.temp_space, self.pres_space,
+                          self.pseu_space, self.field_space):
+                space.states_layout = False
+
         # implicit solvers (/root/reference/src/navier_stokes/navier.rs:263-275)
         sx2, sy2 = self.scale[0] ** 2, self.scale[1] ** 2
         self.solver_velx = HholtzAdi(self.velx_space, (dt * nu / sx2, dt * nu / sy2))
@@ -255,14 +264,6 @@ class Navier2D(CampaignModelBase, Integrate):
         # (manual-partitioned split-sep path, parallel/decomp.py); None
         # keeps the unfused dense chain (the measured default)
         self._conv_impl = self._build_conv_kernels()
-        # the poisoned layout's mesh program is parallel/decomp.py's regions
-        # (or the eager fallback), not GSPMD's to place: its spaces' spectral
-        # operators state no layout of their own there
-        if self._split_sep_poisoned():
-            for space in (self.velx_space, self.temp_space, self.pres_space,
-                          self.pseu_space, self.field_space):
-                space.states_layout = False
-
         # fused projection-gradient operators for the velocity correction
         # (confined only; the periodic x-axis gradient is diagonal logic):
         # velx -= P_u (D S_q) pseu / sx  per axis — one cross-space matrix
@@ -801,6 +802,7 @@ class Navier2D(CampaignModelBase, Integrate):
         w0s, w1s = self._w0, self._w1
         sp_t, sp_u, sp_v = self.temp_space, self.velx_space, self.vely_space
         sp_p, sp_q, sp_f = self.pres_space, self.pseu_space, self.field_space
+        rest = sp_t.rest
         mask = self._dealias
         tb_ortho = self.tempbc_ortho
         tb_dx, tb_dy = self._tempbc_dx, self._tempbc_dy
@@ -888,8 +890,11 @@ class Navier2D(CampaignModelBase, Integrate):
             return sp_f.forward(total) * mask
 
         def step(state: NavierState) -> NavierState:
-            # pin the implicit-solve inputs to the spectral x-pencil layout
-            # (no-op without a mesh; a non-divisible extent is padded inside
+            # pin the implicit-solve inputs to the layout the spaces'
+            # spectral arrays rest in (Space2.rest: the x-pencil of a confined
+            # space, the y-pencil of a periodic one; all five spaces share
+            # their x-base's kind, so one layout serves; no-op without a
+            # mesh; a non-divisible extent is padded inside
             # the jit and only a jit OUTPUT comes back replicated,
             # parallel/mesh.py): asserts the pencil
             # discipline at the solve boundaries so GSPMD propagation cannot
@@ -897,10 +902,10 @@ class Navier2D(CampaignModelBase, Integrate):
             # (divisible) meshes.  NOTE it does NOT cure the fused split-sep
             # miscompile tracked in test_parallel.py::
             # test_sharded_split_periodic_mixed_sep_matches_serial (xfail).
-            from ..parallel.mesh import SPEC, constrain
+            from ..parallel.mesh import constrain
 
             def pin(a):
-                return constrain(a, SPEC)
+                return constrain(a, rest)
 
             temp, velx, vely, pres, pseu = (
                 state.temp, state.velx, state.vely, state.pres, state.pseu
@@ -1007,8 +1012,8 @@ class Navier2D(CampaignModelBase, Integrate):
                             pseu_n = manual_poisson.solve(div)
                         else:
                             # the pressure update below reads the pinned sum
-                            # too: left free it is taken in the solve's
-                            # y-pencil layout and flipped back
+                            # too: left free, a confined space's is taken in
+                            # the solve's y-pencil layout and flipped back
                             div = pin(div)
                             pseu_n = sol_p.solve(div)
                     pseu_n = sp_q.pin_zero_mode(pseu_n)  # remove singularity
@@ -1061,7 +1066,7 @@ class Navier2D(CampaignModelBase, Integrate):
                         )
 
             # pin the step outputs too: the next step's transforms assume the
-            # x-pencil layout, and XLA's sharding propagation is free to emit
+            # resting layout, and XLA's sharding propagation is free to emit
             # replicated outputs otherwise — which silently serializes a
             # multi-chip run
             if has_scal:

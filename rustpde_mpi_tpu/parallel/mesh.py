@@ -2,12 +2,16 @@
 
 TPU rebuild of the reference's distributed backend (funspace::spaces_mpi /
 Decomp2d, SURVEY.md S2.2-S2.3): a 1-D device mesh over which 2-D fields are
-pencil-decomposed.  The reference's convention is kept exactly —
+pencil-decomposed.  The reference's convention is kept exactly on a confined
+(Chebyshev x Chebyshev) space —
 
 * **physical** data in y-pencils: axis 0 (x) distributed, P("p", None)
 * **spectral** data in x-pencils: axis 1 (y) distributed, P(None, "p")
 
-but instead of hand-written MPI all-to-alls
+and turned round on a Fourier x Chebyshev one, whose every spectral
+x-operator but the odd derivative of the split layout is a diagonal: its
+spectral arrays rest as y-pencils and its physical ones as x-pencils
+(``bases.Space2.rest``).  Instead of hand-written MPI all-to-alls
 (/root/reference/src/field_mpi.rs:455-477) the repartitions are expressed as
 ``jax.lax.with_sharding_constraint`` at the pencil-flip points inside
 transforms and solvers; XLA GSPMD inserts the all-to-all collectives and
@@ -32,6 +36,7 @@ _ACTIVE: Mesh | None = None
 # pencil specs (reference convention, /root/reference/src/field_mpi.rs:71-88)
 PHYS = (AXIS, None)  # y-pencil: x distributed
 SPEC = (None, AXIS)  # x-pencil: y distributed
+LOCAL = (SPEC, PHYS)  # LOCAL[axis]: the pencil in which ``axis`` is whole on a device
 
 
 def make_mesh(devices=None) -> Mesh:
@@ -158,13 +163,7 @@ def device_put(x, spec: tuple):
 
     arr = jnp.asarray(x)
     s = sharding(spec, arr.ndim)
-    # one source of truth for the leading-batch padding: read the padded
-    # spec back off the sharding itself
-    divisible = all(
-        sp is None or arr.shape[i] % mesh.shape[sp] == 0
-        for i, sp in enumerate(s.spec)
-    )
-    if divisible:
+    if divides(arr.shape, s):
         return jax.device_put(arr, s)
     import warnings
 
@@ -178,6 +177,34 @@ def device_put(x, spec: tuple):
     return jax.device_put(
         arr, NamedSharding(mesh, PartitionSpec(*([None] * arr.ndim)))
     )
+
+
+def divides(shape: tuple, s: NamedSharding) -> bool:
+    """Whether every sharded extent of ``shape`` divides its mesh axis (one
+    source of truth for the leading-batch padding: the padded spec is read
+    back off the sharding itself)."""
+    return all(
+        sp is None or shape[i] % s.mesh.shape[sp] == 0 for i, sp in enumerate(s.spec)
+    )
+
+
+def settle(x, spec: tuple):
+    """``device_put``'s rule inside a jitted computation, for what a program
+    hands back: the pencil layout where the extent divides the mesh, else
+    whole on every device.  That is the layout ``device_put`` gave the
+    argument, so a chunk's state comes back laid out as it went in, and the
+    next dispatch (or an executable built ahead of it, which refuses any other
+    layout) finds it where it expects it.  Left to the compiler, a result
+    whose constraint does not divide comes back in whatever layout of the
+    mesh does: replicated for the odd extents of a Chebyshev axis, but cut in
+    two for the 1026 rows of a split Fourier axis on four devices.  No-op
+    without an active mesh, and for anything below rank 2 (a scalar leaf)."""
+    if active_mesh() is None or np.ndim(x) < len(spec):
+        return x
+    s = sharding(spec, np.ndim(x))
+    if not divides(np.shape(x), s):
+        s = NamedSharding(s.mesh, PartitionSpec())
+    return jax.lax.with_sharding_constraint(x, s)
 
 
 def _on(leaf, devices: set) -> bool:

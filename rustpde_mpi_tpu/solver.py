@@ -264,6 +264,7 @@ class HholtzAdi:
     def __init__(self, space: Space2, c, method: str | None = None):
         method = method or default_method()
         self.space = space
+        self.rest = space.rest  # the pencil its spectral arrays rest in
         sep = getattr(space, "sep", (False, False))
         self.matvec = []
         self.solvers = []
@@ -292,8 +293,11 @@ class HholtzAdi:
         Under a parallel mesh the axis solves run on the pencil whose solve
         axis is local (the reference's HholtzAdiMpi transpose pattern,
         /root/reference/src/solver_mpi/hholtz_adi.rs:105-145); the pencil
-        flips are sharding constraints, XLA inserts the all-to-alls."""
-        from .parallel.mesh import PHYS, SPEC, constrain
+        flips are sharding constraints, XLA inserts the all-to-alls.  The
+        x-factor runs in the space's resting layout: x is local there, or it
+        is a diagonal (a Fourier axis) and asks for no layout of its own, so
+        such a solve enters, works and returns on the y-pencil."""
+        from .parallel.mesh import LOCAL, constrain
 
         if rhs.ndim < 2:
             raise ValueError(
@@ -303,16 +307,17 @@ class HholtzAdi:
             )
         with jax.named_scope("helmholtz"):
             ax = rhs.ndim - 2
-            out = constrain(rhs, SPEC)
+            rest = self.rest
+            out = constrain(rhs, rest)
             if self.matvec[0] is not None:
                 out = self.matvec[0].apply(out, ax)
-            out = constrain(out, PHYS)
+            out = constrain(out, LOCAL[1])
             if self.matvec[1] is not None:
                 out = self.matvec[1].apply(out, ax + 1)
             out = self.solvers[1].solve(out, ax + 1)  # axis-1 recurrence
-            out = constrain(out, SPEC)
+            out = constrain(out, rest)
             out = self.solvers[0].solve(out, ax)  # axis-0 recurrence
-            return constrain(out, SPEC)
+            return constrain(out, rest)
 
 
 class TensorSolver:
@@ -328,10 +333,12 @@ class TensorSolver:
 
     def __init__(
         self, modal0, a1, c1, precond1, alpha: float, fix_singular=False,
-        sep=(False, False),
+        sep=(False, False), *, rest,
     ):
         from .ops.banded import SepWrapped
         from .ops.folded import parity_perm
+
+        self.rest = rest  # the pencil the space's spectral arrays rest in (Space2.rest)
 
         dt = config.real_dtype()
         lam, fwd0, bwd0 = modal0
@@ -373,9 +380,11 @@ class TensorSolver:
         """Under a parallel mesh: GEMMs run on the x-pencil (axis 0 local),
         the per-eigenvalue banded solves on the y-pencil where the eigenvalue
         lanes (axis 0) are sharded — the reference's PoissonMpi lam-slicing
-        (/root/reference/src/solver_mpi/poisson.rs:139-187).  Extra leading
-        dims are batch (the per-eigenvalue factors broadcast against them)."""
-        from .parallel.mesh import PHYS, SPEC, constrain
+        (/root/reference/src/solver_mpi/poisson.rs:139-187).  A Fourier x-axis
+        has no GEMM (its modes are the eigenvalue lanes already): the solve
+        stays on the y-pencil its space rests in.  Extra leading dims are
+        batch (the per-eigenvalue factors broadcast against them)."""
+        from .parallel.mesh import LOCAL, constrain
 
         if rhs.ndim < 2:
             raise ValueError(
@@ -385,17 +394,18 @@ class TensorSolver:
             )
         with jax.named_scope("tensor_solve"):
             ax = rhs.ndim - 2
-            out = constrain(rhs, SPEC)
+            rest = self.rest
+            out = constrain(rhs, rest)
             if self.matvec1 is not None:
-                out = self.matvec1.apply(constrain(out, PHYS), ax + 1)
-            out = constrain(out, SPEC)
+                out = self.matvec1.apply(constrain(out, LOCAL[1]), ax + 1)
+            out = constrain(out, rest)
             if self.fwd is not None:
                 out = self.fwd.apply(out, ax)
-            out = self.banded.solve(constrain(out, PHYS), ax + 1)
-            out = constrain(out, SPEC)
+            out = self.banded.solve(constrain(out, LOCAL[1]), ax + 1)
+            out = constrain(out, rest)
             if self.bwd is not None:
                 out = self.bwd.apply(out, ax)
-            return constrain(out, SPEC)
+            return constrain(out, rest)
 
 
 class FastDiag:
@@ -413,9 +423,13 @@ class FastDiag:
     identity and their eigenvalues are -k^2.
     """
 
-    def __init__(self, modal0, modal1, alpha: float, fix_singular=False, sep=(False, False)):
+    def __init__(
+        self, modal0, modal1, alpha: float, fix_singular=False, sep=(False, False),
+        *, rest,
+    ):
         from .ops.folded import parity_perm
 
+        self.rest = rest  # the pencil the space's spectral arrays rest in (Space2.rest)
         dt = config.real_dtype()
         lams, self.fwd, self.bwd = [], [], []
         to_dev = lambda m: jnp.asarray(m, dtype=dt)  # noqa: E731
@@ -442,8 +456,11 @@ class FastDiag:
 
     def solve(self, rhs):
         """rhs in ortho space -> solution in composite space (extra leading
-        dims are batch).  Pencil flips sit between the two contractions."""
-        from .parallel.mesh import PHYS, SPEC, constrain
+        dims are batch).  Pencil flips sit between the two contractions;
+        where the x-maps are None (a Fourier axis, modal already) there is no
+        contraction along x and no flip: all of the work is on the y-pencil
+        the space rests in."""
+        from .parallel.mesh import LOCAL, constrain
 
         if rhs.ndim < 2:
             raise ValueError(
@@ -453,19 +470,20 @@ class FastDiag:
             )
         with jax.named_scope("fastdiag"):
             ax = rhs.ndim - 2
-            out = constrain(rhs, SPEC)
+            rest = self.rest
+            out = constrain(rhs, rest)
             if self.fwd[0] is not None:
                 out = self.fwd[0].apply(out, ax)
-            out = constrain(out, PHYS)
+            out = constrain(out, LOCAL[1])
             if self.fwd[1] is not None:
                 out = self.fwd[1].apply(out, ax + 1)
             out = out / self.denom.astype(out.dtype)
             if self.bwd[1] is not None:
                 out = self.bwd[1].apply(out, ax + 1)
-            out = constrain(out, SPEC)
+            out = constrain(out, rest)
             if self.bwd[0] is not None:
                 out = self.bwd[0].apply(out, ax)
-            return constrain(out, SPEC)
+            return constrain(out, rest)
 
 
 class _TensorBased:
@@ -490,7 +508,9 @@ class _TensorBased:
         modal0 = _axis_modal_data(space, 0, c[0], sign)
         if method == "fd":
             modal1 = _axis_modal_data(space, 1, c[1], sign)
-            self._solver = FastDiag(modal0, modal1, alpha, fix_singular, sep=sep)
+            self._solver = FastDiag(
+                modal0, modal1, alpha, fix_singular, sep=sep, rest=space.rest
+            )
         else:
             # mat_c1 = preconditioned mass (pinv S, or I for Fourier),
             # mat_a1 = preconditioned laplacian (peye S, or diag(-k^2))
@@ -503,6 +523,7 @@ class _TensorBased:
                 alpha,
                 fix_singular=fix_singular,
                 sep=sep,
+                rest=space.rest,
             )
 
     def solve(self, rhs):

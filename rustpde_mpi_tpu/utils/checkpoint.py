@@ -1325,6 +1325,16 @@ def _target_region(idx, shape) -> tuple:
     return _normalize_index(idx, shape)
 
 
+def _resting_spec(pde) -> tuple:
+    """The pencil layout ``pde``'s spectral state rests in (``Space2.rest``
+    of the model's spaces, an ensemble's through its template model); the
+    x-pencil for a model that names no space."""
+    from ..parallel.mesh import SPEC
+
+    space = getattr(getattr(pde, "model", pde), "temp_space", None)
+    return SPEC if space is None else space.rest
+
+
 def read_sharded_snapshot(pde, filename: str) -> None:
     """Topology-elastic restore of a sharded checkpoint onto ``pde``.
 
@@ -1341,7 +1351,7 @@ def read_sharded_snapshot(pde, filename: str) -> None:
     import jax
     import jax.numpy as jnp
 
-    from ..parallel.mesh import SPEC, pencil_sharding
+    from ..parallel.mesh import divides, pencil_sharding
 
     with _open_checkpoint(filename) as h5:
         attrs = _verify_open_file(h5, filename)
@@ -1412,16 +1422,12 @@ def read_sharded_snapshot(pde, filename: str) -> None:
                 )
                 updates[leaf] = jnp.asarray(full)
                 continue
-            target = pencil_sharding(mesh, SPEC, ndim=len(arr.shape))
+            target = pencil_sharding(mesh, _resting_spec(pde), ndim=len(arr.shape))
             # explicit placement rejects non-divisible sharded dims (the odd
             # spectral sizes); GSPMD's constraint path rounds those to
             # replicated, so the restore target mirrors that rule — the
             # restored leaf then matches the layout the stepped model holds
-            divisible = all(
-                sp is None or arr.shape[i] % mesh.shape[sp] == 0
-                for i, sp in enumerate(target.spec)
-            )
-            if not divisible:
+            if not divides(arr.shape, target):
                 target = pencil_sharding(mesh, (None,) * len(arr.shape))
             idx_map = target.addressable_devices_indices_map(tuple(arr.shape))
             buffers = []

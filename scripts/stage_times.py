@@ -198,7 +198,8 @@ def main(argv=None) -> int:
     ap.add_argument("--dispatches", type=int, default=2)
     ap.add_argument("--size", type=int, default=None)
     ap.add_argument("--out", default=None,
-                    help="also write the table as JSON here, and the chunk's compiled text beside it (.txt)")
+                    help="also write the table as JSON here (with every device operation of the trace, its "
+                         "scope and its result type), and the chunk's compiled text beside it (.txt)")
     args = ap.parse_args(argv)
     from benchmark import reduce as reducer
     from benchmark.run import load_cell
@@ -279,6 +280,9 @@ def main(argv=None) -> int:
             json.dump({"workload": args.workload, "device": dev.device_kind, "steps": steps,
                        "busy_s": red["busy_s"], "window_s": red["window_s"], "ops_s": total,
                        "stages_s": table, "collectives_s": kinds, "collective_ops": collectives,
+                       "ops": sorted(({"op": name, "s": seconds, "scope": scopes.get(name, ""),
+                                       "result": results.get(name, "")}
+                                      for name, seconds in red["ops"].items()), key=lambda o: -o["s"]),
                        "not_in_text_s": unknown,
                        "launch_to_device_us": host["launch_to_device_us"]}, fh, indent=1)
         with open(os.path.splitext(args.out)[0] + ".txt", "w", encoding="utf-8") as fh:

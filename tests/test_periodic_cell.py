@@ -14,6 +14,7 @@
   per-layer readers.
 """
 
+import collections
 import contextlib
 import importlib.util
 import os
@@ -32,7 +33,7 @@ from benchmark.reference import random_fields
 from benchmark.reference_periodic import Reference
 from rustpde_mpi_tpu import Navier2D, config
 from rustpde_mpi_tpu.parallel.decomp import Decomp2d
-from rustpde_mpi_tpu.parallel.mesh import make_mesh
+from rustpde_mpi_tpu.parallel.mesh import PHYS, SPEC, make_mesh
 from rustpde_mpi_tpu.telemetry import FlightRecorder
 from rustpde_mpi_tpu.telemetry import tracing as ttracing
 
@@ -154,11 +155,41 @@ def test_unmeshed_split_program_follows_the_reference(monkeypatch, path):
     assert max(gaps(model, ref, out).values()) < 1e-9
 
 
-# -- where the meshed step takes its y-operators -------------------------------------
+@needs_x64
+def test_meshed_program_follows_the_unmeshed_one(monkeypatch):
+    """Ten steps on four virtual devices against the same program on one, from
+    the same seeded state.  On the mesh each synthesis takes y first (where
+    the spectral arrays rest), flips, then x, and each analysis the reverse;
+    on one device nothing is distributed and x goes first, as it always has
+    (``Space2.synthesis_axes``).  The two phases of a transform commute but do
+    not round alike, and a distributed product sums its partial rows in
+    another order, so the fields agree to rounding and not bit for bit: read
+    4.3e-15..2.0e-14 relative (CPU, PR 31); held to the 1e-9 this file holds
+    mesh and plain to against the reference."""
+    nx, ny = 32, 33
+    ic = smooth_periodic_fields(nx, ny, 2**31 + 17)
+    fields = {}
+    for devices in (4, 0):
+        model = meshed(monkeypatch, devices, "normal")
+        with model._scope():
+            assert model.temp_space.synthesis_axes == ((1, 0) if devices else (0, 1))
+        for name, values in ic.items():
+            model.set_field(name, values)
+        model.update_n(10)
+        fields[devices] = {k: model.get_field(k) for k in FIELDS}
+    gap = {k: float(np.linalg.norm(fields[4][k] - fields[0][k]) / np.linalg.norm(fields[0][k]))
+           for k in FIELDS}
+    assert 0.0 < max(gap.values()) < 1e-9, gap
+
+
+# -- where the meshed step takes its operators ----------------------------------------
 
 COLLECTIVE = re.compile(
     r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) "
-    r"(all-reduce|all-to-all|all-gather|collective-permute|reduce-scatter)(?:-start)?\(", re.M)
+    r"(all-reduce|all-to-all|all-gather|collective-permute|reduce-scatter)(?:-start)?\((.*)$", re.M)
+FLIPS = 17  # the hand count of test_meshed_chunk_sums_no_field_over_the_devices
+# a confined 17 x 17 chunk of 4 steps on four devices, as the tree before ISSUE 31 compiled it
+CONFINED_17 = {"all-to-all": 23, "all-gather": 39, "collective-permute": 44, "all-reduce": 3}
 
 
 def chunk_text(model, n=4) -> str:
@@ -174,31 +205,59 @@ def largest_operand(result: str) -> int:
     return max(sizes)
 
 
-def sums_no_field(text: str, nx: int, ny: int, flips: int) -> None:
-    found = COLLECTIVE.findall(text)
-    summed = [largest_operand(result) for result, kind in found if kind == "all-reduce"]
-    assert max(summed, default=0) < (nx + 2) * (ny - 2) // 2, summed
-    assert 0 < sum(kind == "all-to-all" for _, kind in found) <= flips
+def collectives(text: str) -> list:
+    """``(kind, entries of the largest result, inside the step body)`` of every
+    collective of a chunk's compiled text.  Inside the body: the instruction's
+    metadata names the scan's loop (the whole-field all-gathers that hand the
+    state back whole, once a chunk, carry none)."""
+    return [(kind, largest_operand(result), "/while/body/" in rest)
+            for result, kind, rest in COLLECTIVE.findall(text)]
+
+
+def sums_no_field(text: str, nx: int, ny: int, flips: int, gathered_flips=None) -> None:
+    """No all-reduce of the chunk moves half a spectral field or more, and the
+    all-to-alls are at most ``flips``.  ``gathered_flips``: how many all-gathers
+    of half a field or more the step body may hold besides (a partitioner may
+    lower a flip of a small array to an all-gather and a slice: then it counts
+    as the flip it is, within ``flips``); None where they cannot be counted."""
+    half = (nx + 2) * (ny - 2) // 2
+    found = collectives(text)
+    summed = [size for kind, size, _ in found if kind == "all-reduce"]
+    assert max(summed, default=0) < half, summed
+    exchanged = sum(kind == "all-to-all" for kind, _, _ in found)
+    assert 0 < exchanged <= flips
+    if gathered_flips is not None:
+        gathered = sum(kind == "all-gather" and size >= half and body for kind, size, body in found)
+        assert gathered <= gathered_flips and exchanged + gathered <= flips, (exchanged, gathered)
 
 
 @pytest.mark.parametrize("grid", [(16, 17), (32, 33)])
 def test_meshed_chunk_sums_no_field_over_the_devices(monkeypatch, no_compile_cache, grid):
-    """Four devices, extents four does not divide.  A Chebyshev derivative or
-    a composite cast along y interleaves parities along the WHOLE y extent;
-    taken on an x-pencil (y distributed) GSPMD lowers the interleave to a
-    select into a zero field on every device and an all-reduce of the fields.
-    Taken where y is local there is nothing to sum: no all-reduce of the
-    chunk moves half a spectral field or more (a tuple all-reduce is named by
-    its first element, so every element is looked at; scalar reductions, the
-    finite check among them, stay), and the flips are the hand count:
-    2 syntheses, 3 per convection chain (two derivative syntheses out, the
-    product back) x 3, 2 per Helmholtz solve x 3, 2 for the Poisson solve,
-    2 each round the y-derivative of the pressure gradient and of the
-    divergence, 2 each round the projection's two corrections (derivative and
-    cast inside one visit): 27.  The compiler may lower a flip of so small an
-    array to an all-gather and a slice, never add one."""
+    """Four devices, extents four does not divide.  On this space (split
+    Fourier along x, Chebyshev along y) spectral arrays rest as y-pencils (x
+    distributed, y whole on a device: ``Space2.rest``), because every spectral
+    x-operator between two transforms is a diagonal but one.  A Chebyshev
+    derivative, a composite cast, a Helmholtz or Poisson solve along y is
+    taken where it is, with nothing to exchange or to sum: no all-reduce of
+    the chunk moves half a spectral field or more (a tuple all-reduce is named
+    by its first element, so every element is looked at; scalar reductions,
+    the finite check among them, stay).  The flips are the hand count:
+    2 syntheses (y where it rests, one flip, x), 3 per convection chain (two
+    derivative syntheses out, the product back) x 3, and 2 round each of the
+    three odd x-derivatives of the split layout, which swaps the Re and Im
+    halves of the x extent and is the one spectral operator that needs x
+    whole (d/dx of the pressure, of the new velx in the divergence, of the
+    pseudo-pressure in the projection); the three Helmholtz solves, the
+    Poisson solve, every d/dy, stencil and cast: 0.  2 + 9 + 6 = 17 (27
+    while the arrays rested as x-pencils).  The compiler may lower a flip of
+    so small an array to an all-gather and a slice, never add one: an
+    all-gather of half a field or more inside the step body counts as a flip
+    (the lowering a sliced and concatenated sharded axis falls into would add
+    some)."""
     nx, ny = grid
-    sums_no_field(chunk_text(meshed(monkeypatch, 4, "normal", nx=nx, ny=ny)), nx, ny, flips=27)
+    model = meshed(monkeypatch, 4, "normal", nx=nx, ny=ny)
+    assert model.temp_space.rest == PHYS
+    sums_no_field(chunk_text(model), nx, ny, flips=FLIPS, gathered_flips=FLIPS)
 
 
 # The chunk compiled for the four chips of a DESCRIBED v5e host: the TPU's
@@ -226,19 +285,26 @@ with model._scope():
 """
 
 
-def test_the_chips_own_compiler_sums_no_field_either():
+@pytest.mark.parametrize("grid", [(32, 33), (256, 257)])
+def test_the_chips_own_compiler_sums_no_field_either(grid):
     """The same program through the TPU's compiler, which propagates layouts
     its own way: with the flips stated only round the visit to the y-local
     layout, it ran the projection's stencil on the x-pencil and summed the
     halves of its result over the devices (two all-reduces of half a field,
     88 us a step on the chip), where the CPU's partitioner of the case above
-    summed nothing.  In a process of its own, which ends with the compile:
-    the TPU's library is loaded into no test worker, and its machine-wide
-    lock is asked for by no one (a compile for a described chip touches no
-    chip).  Skipped only where that library is not installed."""
+    summed nothing.  At 32 x 33 it takes the three analysis flips as
+    all-gathers folded into the products that follow, in steps that cannot be
+    counted; from 256 x 257 up it makes the program it makes at the cell's
+    1024 x 1025 (17 all-to-alls in the step body, no all-gather there and no
+    collective-permute), so that size also holds: no all-gather of half a
+    spectral field or more inside the loop.  In a process of its own, which
+    ends with the compile: the TPU's library is loaded into no test worker,
+    and its machine-wide lock is asked for by no one (a compile for a
+    described chip touches no chip).  Skipped only where that library is not
+    installed."""
     if importlib.util.find_spec("libtpu") is None:
         pytest.skip("no libtpu installed: no compiler for a described v5e")
-    nx, ny = 32, 33
+    nx, ny = grid
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ, JAX_PLATFORMS="cpu", RUSTPDE_FORCE_TPU_PATH="1", RUSTPDE_COMPILE_CACHE="0",
                RUSTPDE_X64="0", ALLOW_MULTIPLE_LIBTPU_LOAD="1", PYTHONPATH=repo)  # float32, as the cell
@@ -246,7 +312,20 @@ def test_the_chips_own_compiler_sums_no_field_either():
     done = subprocess.run([sys.executable, "-c", V5E_CHUNK, str(nx), str(ny)], env=env, cwd=repo,
                           capture_output=True, text=True, timeout=600)
     assert done.returncode == 0, done.stderr[-2000:]
-    sums_no_field(done.stdout, nx, ny, flips=27)
+    sums_no_field(done.stdout, nx, ny, flips=FLIPS, gathered_flips=0 if nx >= 256 else None)
+
+
+def test_meshed_confined_chunk_keeps_the_x_pencil_rest(monkeypatch, no_compile_cache):
+    """The other side of the selection: a confined space (Chebyshev along x,
+    dense x-operators) rests as x-pencils, its y-operators between a pair of
+    flips, and its chunk on four devices holds the collectives the tree before
+    ISSUE 31 compiled for it (counted there at this size, with this jax: the
+    chunk's text was the same but for its metadata)."""
+    monkeypatch.setenv("RUSTPDE_FORCE_TPU_PATH", "1")
+    model = Navier2D.new_confined(17, 17, *PHYSICS, "rbc", mesh=make_mesh(jax.devices()[:4]))
+    assert model.temp_space.rest == SPEC and model.temp_space.synthesis_axes == (0, 1)
+    counts = collections.Counter(kind for kind, _, _ in collectives(chunk_text(model)))
+    assert counts == CONFINED_17, counts
 
 
 def test_unmeshed_confined_chunk_states_no_layout(monkeypatch, no_compile_cache):
@@ -299,12 +378,12 @@ def test_span_counts_the_manual_exchanges(monkeypatch, ring):
 def test_span_counts_no_exchange_where_the_compiler_places_them(monkeypatch, ring):
     args = last_update_n(meshed(monkeypatch, 4, "normal"))
     assert (args["devices"], args["transposes"], args["exchange_bytes"]) == (4, 0, 0)
+    # the state rests as y-pencils: its 34 split rows (17 modes, Re and Im)
+    # divide by 2 and not by 4, so on four devices every leaf is whole
     assert args["replicated_leaves"] == 5
-    # 32 composite columns divide by 2 and 4; pres keeps its 34 ortho columns whole
-    model = meshed(monkeypatch, 2, "normal", ny=34)
-    assert last_update_n(model)["replicated_leaves"] == 0
-    model = meshed(monkeypatch, 4, "normal", ny=34)
-    assert last_update_n(model)["replicated_leaves"] == 1
+    assert last_update_n(meshed(monkeypatch, 2, "normal"))["replicated_leaves"] == 0
+    # 30 points: 16 modes, 32 rows, which divide by 4
+    assert last_update_n(meshed(monkeypatch, 4, "normal", nx=30))["replicated_leaves"] == 0
 
 
 def test_span_of_an_unmeshed_model_says_nothing_of_a_mesh(monkeypatch, ring):
