@@ -280,11 +280,13 @@ class Base:
             # f64-hybrid (SURVEY S7 / VERDICT r4 next #3b): the convection
             # transforms — the step's fast keys, nothing else — run as f32
             # GEMMs (device matrices stored f32, inputs cast in, outputs cast
-            # back to f64), dodging the ~16x f64 MXU emulation on the
-            # dominant transform flops while every solve, analysis forward,
-            # observable and IO stays full f64.  Opt-in; validated against
-            # the 129^2 parity trajectory + shadow gate before any default
-            # flip.
+            # back to f64), dodging the f64 emulation on the dominant
+            # transform flops (on a v5e a float64 513^2 step takes 22.0
+            # times the float32 step's device time and a convection chain
+            # 22-28 times: PERF.md section 5, chip run of PR 33) while every
+            # solve, analysis forward, observable and IO stays full f64.
+            # Opt-in; judged in the cell rbc513_f64.solo under its limits
+            # before any default flip.
             cast = np.float32
         if fast and synth_prec is None and cast is None:
             # no downgrade requested (f64 without hybrid, or

@@ -371,10 +371,19 @@ class CampaignModelBase:
 
         from ..parallel.mesh import unplaced
         from ..telemetry import compile_log
+        from ..utils.jit import dot_generals_by_operand
 
         t0 = _time.perf_counter()
         try:
             self._compile_entry_points_impl()
+            # the ``dot_general``s of one step's traced program by operand
+            # type, counted once per pass for the ``model.update_n`` span:
+            # which arithmetic the step's products were compiled in
+            products = dot_generals_by_operand(self._step_cc.jaxpr)
+            self._step_products = {
+                "f64_products": products.get("float64", 0),
+                "f32_products": products.get("float32", 0),
+            }
             # the scanned chunks' constants, counted once per pass for the
             # span's ``unplaced_args`` (:meth:`_mesh_span_args`)
             self._unplaced_consts = unplaced(
@@ -678,7 +687,7 @@ class CampaignModelBase:
         if self._step_n_sent is not None:
             return self._update_n_sentinel(n)
         with DispatchSpans(
-            "model", _LAYER, steps=int(n), **self._mesh_span_args()
+            "model", _LAYER, steps=int(n), **self._step_products, **self._mesh_span_args()
         ) as seams, self._scope():
             if self._step_n_stats is not None:
                 with seams.handover():
@@ -724,7 +733,7 @@ class CampaignModelBase:
         rdt = config.real_dtype()
         stats_on = self._stats_cc is not None
         with DispatchSpans(
-            "model", _LAYER, steps=int(n), **self._mesh_span_args()
+            "model", _LAYER, steps=int(n), **self._step_products, **self._mesh_span_args()
         ) as seams, self._scope():
             # the running sums + tick ride the sentinel carry (and the
             # rollback snapshot below — a tripped chunk's samples are

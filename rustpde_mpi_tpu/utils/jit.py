@@ -17,6 +17,8 @@ leaving concrete arrays as embedded constants.)
 
 from __future__ import annotations
 
+import collections
+
 import jax
 import jax.extend.core as jex_core
 import jax.numpy as jnp
@@ -89,4 +91,23 @@ def hoist_constants(fn, *example):
         out_flat = replay(*flat_args)
         return jax.tree.unflatten(out_tree, out_flat)
 
+    converted.jaxpr = closed.jaxpr  # what was traced, for whoever counts in it
     return converted, consts
+
+
+def dot_generals_by_operand(jaxpr) -> collections.Counter:
+    """``{dtype name: count}`` of the ``dot_general`` equations of ``jaxpr``
+    and of every jaxpr its equations carry (``pjit``, ``cond``, ``scan``, a
+    custom rule), each by the wider of its two operand types: which arithmetic
+    a program's matrix products were traced in.  A count of the traced
+    program, not of launches: the body of a loop counts once."""
+    out: collections.Counter = collections.Counter()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out[max((v.aval.dtype for v in eqn.invars), key=lambda d: d.itemsize).name] += 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(sub, "jaxpr", sub)  # a ClosedJaxpr holds one
+                if hasattr(inner, "eqns"):
+                    out += dot_generals_by_operand(inner)
+    return out

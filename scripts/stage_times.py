@@ -4,6 +4,7 @@
     python scripts/stage_times.py --workload swarm129_f32.batch --dispatches 6
     python scripts/stage_times.py --workload periodic1024_f32.mesh4   (4 chips)
     python scripts/stage_times.py --workload lnse_opt128_f32.loop     (two tables)
+    python scripts/stage_times.py --workload rbc513_f64.solo          (float64: the cell's env sets RUSTPDE_X64)
 
 Builds the cell's own model (``BENCHMARK.json`` and ``benchmark/`` say what a
 cell is), warms one dispatch, traces a few under ``utils/profiling.trace`` and

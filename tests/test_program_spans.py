@@ -344,4 +344,4 @@ def test_benchmark_selfcheck_passes(capsys):
     from benchmark import selfcheck
 
     assert selfcheck.main([]) == 0
-    assert "16 readers agree" in capsys.readouterr().out
+    assert "17 readers agree" in capsys.readouterr().out
