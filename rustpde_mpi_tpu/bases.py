@@ -339,9 +339,9 @@ class Base:
                 sep_out=True,
                 cast=cast,
             )
-        # only impls that declare the hook honor an override (the
-        # _SynthesisSep family); unstructured _Plain fallbacks stay at
-        # session precision rather than silently carrying a dead attr
+        # a transform's forms honor the override (the _SynthesisSep family
+        # and, below the fold gate of ops/folded.py, the one plain product);
+        # unstructured _Plain fallbacks stay at session precision
         fm.set_precision(synth_prec)
         cache[key] = fm
         return cache[key]

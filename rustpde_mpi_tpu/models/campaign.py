@@ -371,18 +371,20 @@ class CampaignModelBase:
 
         from ..parallel.mesh import unplaced
         from ..telemetry import compile_log
-        from ..utils.jit import dot_generals_by_operand
+        from ..utils.jit import dot_generals_by_operand, reverses
 
         t0 = _time.perf_counter()
         try:
             self._compile_entry_points_impl()
             # the ``dot_general``s of one step's traced program by operand
-            # type, counted once per pass for the ``model.update_n`` span:
-            # which arithmetic the step's products were compiled in
+            # type, counted once per pass for the ``update_n`` spans (the
+            # ensemble's too): which arithmetic the step's products were
+            # compiled in, and how many array flips its parity folds brought
             products = dot_generals_by_operand(self._step_cc.jaxpr)
             self._step_products = {
                 "f64_products": products.get("float64", 0),
                 "f32_products": products.get("float32", 0),
+                "reverses": reverses(self._step_cc.jaxpr),
             }
             # the scanned chunks' constants, counted once per pass for the
             # span's ``unplaced_args`` (:meth:`_mesh_span_args`)

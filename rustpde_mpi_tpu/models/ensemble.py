@@ -662,8 +662,11 @@ class NavierEnsemble(Integrate):
 
     def _seams(self, n: int) -> DispatchSpans:
         """The spans ``ensemble.update_n`` / ``.carry_copy`` / ``.launch``
-        of one chunk (models/campaign.DispatchSpans)."""
-        return DispatchSpans("ensemble", "ensemble", steps=int(n), members=self.k)
+        of one chunk (models/campaign.DispatchSpans); the product and
+        reverse counts are the member step's, the template model's own."""
+        return DispatchSpans(
+            "ensemble", "ensemble", steps=int(n), members=self.k, **self.model._step_products
+        )
 
     def _update_n_sentinel(self, n: int):
         """Sentinel-armed batched chunk (see :meth:`update_n`)."""

@@ -13,11 +13,36 @@ On TPU the equivalent trick halves the MXU flops of the dense transforms:
   inverses) are checkerboard-sparse (``M[j, k] = 0`` unless ``j + k + s``
   is even), foldable the same way by index parity.
 
-Detection is numerical at build time; matrices without the structure (e.g.
-the mixed Dirichlet-Neumann base's operators) fall back to the plain GEMM.
+When a fold engages.  Detection is numerical at build time; matrices without
+the structure (e.g. the mixed Dirichlet-Neumann base's operators) fall back
+to the plain GEMM.  A matrix WITH a reflection structure (Chebyshev analysis /
+synthesis) is folded only where its smaller extent reaches ``_FOLD_MIN_DIM``
+(the circular Fourier folds have a gate of their own, ``_CIRC_MIN_DIM``): the
+MXU's cost counts in 128-wide tiles, so while the two parity blocks are a
+half-empty tile each they stream as many rows through the MXU as the one
+plain product does, and the fold's full-array reverse, slice pair, add,
+subtract and concatenate stay.  Measured on a v5e (PERF.md section 6, PR 34):
+the 8-member 129^2 ensemble step spent 56 of its 248 us in 20 stand-alone
+reverses on folds that saved no MXU pass, and runs 23.6 % shorter plain; at
+193^2 plain is still 19 % shorter; at 257^2, where a block fills a whole
+tile, the fold wins by 11 % (1 % for a single field); at 513^2 the blocks
+are two to three tiles and the fold halves real work.  Below the gate the
+operator is ONE plain product with any sep permutation baked into the host
+matrix, the dealias-dead rows still dropped from it, and the fold's
+precision hook kept.  In float64, which the chip emulates, the fold engages
+at every size: a product's cost there is cutting its field operand into
+float32 pieces, and the plain form measured 6-26 % slower from 129^2 to 257^2
+(same section).  The gate reads the operator's shape and the itemsize of the
+arithmetic its products run in, and nothing else.  The checkerboard operators
+of the sep layout have the same kind of gate, lower (``_SEP_MIN_DIM``): two
+DENSE parity blocks are one product over the whole matrix, exact zeros
+included, below the 193-point grid (129^2 and 128 x 57: 13 % and 22 % of the
+step, same section); banded and trapezoid blocks are not touched.
+
 Folded and plain paths agree to machine epsilon (tests/test_folded.py) —
 each output element is the same reduction, reassociated only across the
-explicitly-zero half of the terms.
+explicitly-zero half of the terms (reflection folds: across the two parity
+halves).
 
 Enable/disable with RUSTPDE_FOLDED (default on).
 """
@@ -39,6 +64,25 @@ from .. import config
 # near-symmetric matrix could be folded and silently perturbed; 1e-14 keeps
 # the folded/plain agreement at genuine machine epsilon.
 _ATOL = 1e-14
+# Reflection folds (Chebyshev analysis / synthesis) engage where the
+# operator's smaller extent reaches this, by the itemsize of the arithmetic
+# its products run in.  Each gate stands at the smallest extent at which the
+# split form measured faster on a v5e; the plain form measured faster at
+# every smaller size tried (module docstring; PERF.md section 6, PR 34).
+# float32: 255, the 257-point grid's, whose plain product needs a third
+# 128-wide tile (8 members: plain 19 % faster at 191, fold 11 % faster at
+# 255).  float64, which the chip emulates: always, because there a product's
+# cost is cutting its field operand into float32 pieces, and the plain form
+# measured 6-26 % slower at every size tried (127, 191, 255).
+_FOLD_MIN_DIM = {4: 255, 8: 4}
+# The same for the two dense parity blocks of a spectral->spectral operator
+# (`_SepBoth` over two `_Plain` blocks: the Helmholtz inverses, the fast-diag
+# modal maps, dense derivative and projection operators).  float32: 191, the
+# 193-point grid's (8 members: one product over the whole checkerboard
+# matrix, exact zeros included, 13 % of the step faster at the 129-point
+# grid and 22 % at 128 x 57; the blocks 10 % faster at 191).  float64:
+# always (the plain form 25 % slower at the 129-point grid).
+_SEP_MIN_DIM = {4: 191, 8: 4}
 _CIRC_MIN_DIM = 256  # circular folds engage only for large transforms
 _MAX_BAND_OFFSETS = 8  # banded shift-apply engages up to this many diagonals
 
@@ -218,6 +262,53 @@ class _Plain:
 
     def device_parts(self, to_dev):
         return (to_dev(self.mat),)
+
+
+class _PlainReflect(_Plain):
+    """A reflection-symmetric transform below ``_FOLD_MIN_DIM``: one product
+    in place of the fold, with what the fold carries besides: the
+    ``precision`` hook (the fast syntheses stay three-pass,
+    RUSTPDE_FWD_PRECISION keeps its meaning) and, for a dealiased analysis,
+    the dead rows dropped from the GEMM and zero-filled after it.
+
+    ``zero_fill``: ``((kept, dead), ...)`` runs of the OUTPUT rows in storage
+    order; ``mat`` then holds the kept rows only."""
+
+    #: optional matmul precision override (None = session default), the same
+    #: hook as _SynthesisSep.precision
+    precision = None
+
+    def __init__(self, mat: np.ndarray, zero_fill=None):
+        super().__init__(mat)
+        self.zero_fill = zero_fill
+
+    @classmethod
+    def analysis_sep(cls, mat: np.ndarray, keep_rows=None):
+        """Sep-layout output with the ``keep_rows`` prefix cut of
+        `_AnalysisSep`: the kept rows of each parity block are one run."""
+        r = mat.shape[0]
+        if keep_rows is None or keep_rows >= r:
+            return cls(mat[parity_perm(r), :])
+        k = max(0, keep_rows)
+        re, ke, ko = (r + 1) // 2, (k + 1) // 2, k // 2
+        impl = cls(
+            mat[:k][parity_perm(k), :],
+            zero_fill=((ke, re - ke), (ko, r - re - ko)),
+        )
+        impl.flops_factor = k / r if r else 0.0
+        return impl
+
+    def apply(self, dev, a, axis: int):
+        (m,) = dev
+        y = jnp.tensordot(m, _move(a, axis), axes=([1], [0]), precision=self.precision)
+        if self.zero_fill is not None:
+            parts, row = [], 0
+            for kept, dead in self.zero_fill:
+                parts.append(y[row : row + kept])
+                parts.append(jnp.zeros((dead,) + y.shape[1:], dtype=y.dtype))
+                row += kept
+            y = jnp.concatenate(parts, axis=0)
+        return _unmove(y, axis)
 
 
 class _AnalysisFold:
@@ -511,15 +602,18 @@ class _SepBoth:
         return _unmove(jnp.concatenate([y_e, y_o], axis=0), axis)
 
 
-def _detect_sep(mat: np.ndarray, sep_in: bool, sep_out: bool, keep_rows=None):
+def _detect_sep(mat: np.ndarray, sep_in: bool, sep_out: bool, keep_rows=None, itemsize=None):
     """Impl selection for sep-layout sides.  Unstructured matrices absorb the
     permutation into the dense operator (conjugation on the host — zero
-    runtime cost); structured ones get the gather-free block applies."""
+    runtime cost); structured ones get the gather-free block applies, the
+    reflection folds from ``_FOLD_MIN_DIM`` up (below it: one product, the
+    permutation absorbed the same way)."""
     if np.iscomplexobj(mat) or mat.ndim != 2:
         raise ValueError("sep layout requires real 2-D operator matrices")
     r, c = mat.shape
     scale = np.abs(mat).max() or 1.0
     structured = folding_enabled() and min(r, c) >= 4
+    fold = min(r, c) >= _gate(_FOLD_MIN_DIM, itemsize)
     if sep_in and sep_out:
         if structured:
             j = np.arange(r)[:, None]
@@ -527,13 +621,20 @@ def _detect_sep(mat: np.ndarray, sep_in: bool, sep_out: bool, keep_rows=None):
             for shift in (0, 1):
                 zero_part = mat[(j + k + shift) % 2 == 1]
                 if np.abs(zero_part).max(initial=0.0) < _ATOL * scale:
-                    return _SepBoth(mat, shift)
+                    both = _SepBoth(mat, shift)
+                    if min(r, c) >= _gate(_SEP_MIN_DIM, itemsize) or any(
+                        b.kind != "plain" for b in both.blocks
+                    ):
+                        return both
+                    break  # two dense blocks below their gate: one product
         return _Plain(mat[np.ix_(parity_perm(r), parity_perm(c))])
     if sep_out:  # physical/natural input -> sep output (analysis position)
         if structured:
             sgn_r = (-1.0) ** np.arange(r)[:, None]
             if np.abs(mat[:, ::-1] - sgn_r * mat).max() < _ATOL * scale:
-                return _AnalysisSep(mat, keep_rows=keep_rows)
+                if fold:
+                    return _AnalysisSep(mat, keep_rows=keep_rows)
+                return _PlainReflect.analysis_sep(mat, keep_rows)
         if keep_rows is not None and keep_rows < r:
             mat = np.where(np.arange(r)[:, None] < keep_rows, mat, 0.0)
         return _Plain(mat[parity_perm(r), :])
@@ -542,13 +643,28 @@ def _detect_sep(mat: np.ndarray, sep_in: bool, sep_out: bool, keep_rows=None):
         sgn_c = (-1.0) ** np.arange(c)[None, :]
         for sign in (1.0, -1.0):
             if np.abs(mat[::-1, :] - sign * sgn_c * mat).max() < _ATOL * scale:
-                return _SynthesisSep(mat, sign)
+                if fold:
+                    return _SynthesisSep(mat, sign)
+                return _PlainReflect(mat[:, parity_perm(c)])
     return _Plain(mat[:, parity_perm(c)])
 
 
-def _detect(mat: np.ndarray, sep_in: bool = False, sep_out: bool = False, keep_rows=None):
+def _gate(min_dim: dict, itemsize=None) -> int:
+    """A size gate's value (``_FOLD_MIN_DIM``, ``_SEP_MIN_DIM``) for
+    products of ``itemsize`` bytes an element (None: the session's real
+    dtype)."""
+    if itemsize is None:
+        itemsize = np.dtype(config.real_dtype()).itemsize
+    return min_dim[itemsize]
+
+
+def _detect(
+    mat: np.ndarray, sep_in: bool = False, sep_out: bool = False, keep_rows=None, itemsize=None
+):
+    """``itemsize``: bytes an element of the arithmetic the products will
+    run in (`_gate`)."""
     if sep_in or sep_out:
-        return _detect_sep(np.asarray(mat), sep_in, sep_out, keep_rows)
+        return _detect_sep(np.asarray(mat), sep_in, sep_out, keep_rows, itemsize)
     if not folding_enabled():
         return _Plain(mat)
     if np.iscomplexobj(mat) or mat.ndim != 2 or min(mat.shape) < 4:
@@ -577,13 +693,16 @@ def _detect(mat: np.ndarray, sep_in: bool = False, sep_out: bool = False, keep_r
     # output-side fold is measured cheaper on TPU — its flip/concat touches
     # the half-size result, while the input-side (analysis) fold streams a
     # full-array reverse before the GEMM
+    # Either engages from _FOLD_MIN_DIM up; below it the transform is one
+    # plain product (module docstring)
+    fold = min(r, c) >= _gate(_FOLD_MIN_DIM, itemsize)
     sgn_c = (-1.0) ** np.arange(c)[None, :]
     if np.abs(mat[::-1, :] - sgn_c * mat).max() < _ATOL * scale:
-        return _SynthesisFold(mat)
+        return _SynthesisFold(mat) if fold else _PlainReflect(mat)
     # analysis-type: input reflection <-> output index parity
     sgn_r = (-1.0) ** np.arange(r)[:, None]
     if np.abs(mat[:, ::-1] - sgn_r * mat).max() < _ATOL * scale:
-        return _AnalysisFold(mat)
+        return _AnalysisFold(mat) if fold else _PlainReflect(mat)
     # checkerboard
     j = np.arange(r)[:, None]
     k = np.arange(c)[None, :]
@@ -633,8 +752,11 @@ class FoldedMatrix:
         """``cast``: store the device parts in this dtype and run apply()
         through it (input cast in, output cast back to the input dtype) —
         the f64-hybrid mode's f32 convection transforms (Base._sep_dev)."""
-        self._impl = _detect(np.asarray(mat), sep_in, sep_out, keep_rows)
         self._cast = np.dtype(cast) if cast is not None else None
+        self._impl = _detect(
+            np.asarray(mat), sep_in, sep_out, keep_rows,
+            self._cast.itemsize if self._cast is not None else None,
+        )
         if self._cast is None:
             place = to_dev
         else:
@@ -675,12 +797,11 @@ class FoldedMatrix:
 
     def set_precision(self, precision: str | None) -> bool:
         """Override the matmul precision of the underlying apply, where the
-        impl supports one (the ``_SynthesisSep`` family declares a
-        ``precision`` hook).  Returns whether the override took — callers
-        must not assume it did: unstructured ``_Plain`` fallbacks stay at
-        session precision rather than silently carrying a dead attr.  The
-        public face of what bases.py used to do by reaching into
-        ``_impl``."""
+        impl supports one (the ``_SynthesisSep`` family and its form below
+        the fold gate, ``_PlainReflect``).  Returns whether the override
+        took — callers must not assume it did: unstructured ``_Plain``
+        fallbacks stay at session precision.  The public face of what
+        bases.py used to do by reaching into ``_impl``."""
         if precision and hasattr(type(self._impl), "precision"):
             self._impl.precision = precision
             return True

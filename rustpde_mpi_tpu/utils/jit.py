@@ -95,19 +95,32 @@ def hoist_constants(fn, *example):
     return converted, consts
 
 
-def dot_generals_by_operand(jaxpr) -> collections.Counter:
-    """``{dtype name: count}`` of the ``dot_general`` equations of ``jaxpr``
-    and of every jaxpr its equations carry (``pjit``, ``cond``, ``scan``, a
-    custom rule), each by the wider of its two operand types: which arithmetic
-    a program's matrix products were traced in.  A count of the traced
-    program, not of launches: the body of a loop counts once."""
-    out: collections.Counter = collections.Counter()
+def equations(jaxpr):
+    """Every equation of ``jaxpr`` and of every jaxpr its equations carry
+    (``pjit``, ``cond``, ``scan``, a custom rule): the traced program, not its
+    launches, so the body of a loop comes once."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "dot_general":
-            out[max((v.aval.dtype for v in eqn.invars), key=lambda d: d.itemsize).name] += 1
+        yield eqn
         for value in eqn.params.values():
             for sub in value if isinstance(value, (tuple, list)) else (value,):
                 inner = getattr(sub, "jaxpr", sub)  # a ClosedJaxpr holds one
                 if hasattr(inner, "eqns"):
-                    out += dot_generals_by_operand(inner)
-    return out
+                    yield from equations(inner)
+
+
+def dot_generals_by_operand(jaxpr) -> collections.Counter:
+    """``{dtype name: count}`` of the ``dot_general`` equations of ``jaxpr``,
+    sub-programs included (:func:`equations`), each by the wider of its two
+    operand types: which arithmetic a program's matrix products were traced
+    in."""
+    return collections.Counter(
+        max((v.aval.dtype for v in eqn.invars), key=lambda d: d.itemsize).name
+        for eqn in equations(jaxpr)
+        if eqn.primitive.name == "dot_general"
+    )
+
+
+def reverses(jaxpr) -> int:
+    """The ``rev`` equations of ``jaxpr``, sub-programs included: the array
+    flips of the parity folds (ops/folded.py), none below their size gate."""
+    return sum(eqn.primitive.name == "rev" for eqn in equations(jaxpr))

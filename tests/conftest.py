@@ -95,3 +95,25 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         if "slow" in item.keywords:
             item.add_marker(skip)
+
+
+@pytest.fixture
+def fold_gate(monkeypatch):
+    """``fold_gate(extent)`` pins the size from which ops/folded.py's parity
+    splits engage (the reflection folds' gate and the dense checkerboard
+    blocks'; ``fold_gate.NEVER``: every such operator one plain product), in
+    either precision, for this test alone,
+    and gives the test a base cache of its own: a ``Base`` keeps the operators
+    it built, so one left alive by another test would hand its forms back."""
+    import weakref
+
+    from rustpde_mpi_tpu import bases
+    from rustpde_mpi_tpu.ops import folded
+
+    def pin(extent: int) -> None:
+        monkeypatch.setattr(folded, "_FOLD_MIN_DIM", {4: extent, 8: extent})
+        monkeypatch.setattr(folded, "_SEP_MIN_DIM", {4: extent, 8: extent})
+        monkeypatch.setattr(bases, "_BASE_CACHE", weakref.WeakValueDictionary())
+
+    pin.NEVER = 1 << 30
+    return pin

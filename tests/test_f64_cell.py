@@ -115,9 +115,11 @@ def test_hybrid_reads_beside_the_limit(files):
         print(f"hybrid at 17 x 17, 64 steps: {key} = {pair['value']:.3e}, {side} the limit "
               f"{limits[key]:.3e} the cell's rule places here")
         assert np.isfinite(pair["value"])
-    # the three convection chains and the synthesis of ux, uy: 44 of the forced
-    # TPU path's 104 dot_generals at this size
-    assert (got["f64_products"], got["f32_products"]) == (60, 44), got
+    # the three convection chains and the synthesis of ux, uy: 22 transforms,
+    # in float32 each one plain product below ops/folded.py's fold gate, of the
+    # forced TPU path's 82 dot_generals at this size (folded they were 44 of
+    # 104; the float64 operators fold at every size)
+    assert (got["f64_products"], got["f32_products"]) == (60, 22), got
 
 
 # -- the refusal ------------------------------------------------------------------
@@ -133,7 +135,7 @@ def test_driver_refuses_a_process_of_another_precision(monkeypatch, files):
 
 #: one-axis operator applications of one confined step that are dense products
 #: at 33 x 33 on the TPU path (``_make_step``, read stage by stage), each two
-#: ``dot_general``s: one per parity block.  Stencils (``to_ortho``, the
+#: ``dot_general``s where it is parity-folded: one per parity block.  Stencils (``to_ortho``, the
 #: Helmholtz preconditions) are shifted adds and count nothing.  At 513 x 513
 #: the four derivative operators (the pressure gradient's and the
 #: divergence's) are cut into two trapezoid strips a block (ops/folded.py,
@@ -157,18 +159,29 @@ def ring(monkeypatch):
     return rec
 
 
-def test_span_counts_one_steps_products_by_operand_type(monkeypatch, ring):
+#: of them the transforms between physical and spectral space, from
+#: ops/folded.py's fold gate up a parity fold each: two products and one array
+#: reverse.  Below the module's gates every entry above is one plain product.
+TRANSFORMS = 4 + 18
+
+
+@pytest.mark.parametrize("folded", [True, False])
+def test_span_counts_one_steps_products_by_operand_type(monkeypatch, ring, fold_gate, folded):
     monkeypatch.setenv("RUSTPDE_FORCE_TPU_PATH", "1")
+    fold_gate(4 if folded else fold_gate.NEVER)
+    products = sum(HAND_COUNT.values()) * (2 if folded else 1)
+    assert products == (80 if folded else 40)
     model = Navier2D.new_confined(33, 33, RA, PR, DT, ASPECT, "rbc")
     model.init_random(0.1, seed=0)
     model.update_n(4)
     args = ttracing.spans("model.update_n")[-1][-1]
-    assert args["f64_products"] == 2 * sum(HAND_COUNT.values()) == 80
+    assert args["f64_products"] == products
     assert args["f32_products"] == 0
+    assert args["reverses"] == (TRANSFORMS if folded else 0)
     # counted in the traced step, once: the chunk's scan does not multiply it
     model.update_n(8)
-    assert ttracing.spans("model.update_n")[-1][-1]["f64_products"] == 80
-    assert f64_products_per_step.read({}, {"traced_dispatches": 2}) == 80.0
+    assert ttracing.spans("model.update_n")[-1][-1]["f64_products"] == products
+    assert f64_products_per_step.read({}, {"traced_dispatches": 2}) == float(products)
     assert f64_products_per_step.read({}, {"traced_dispatches": 3}) is None
     # a span without the count (the parent commit's) reads nothing
     ring.add_complete("model.update_n", ring.now_us(), 5.0, {"id": 9, "parent": None, "steps": 8})

@@ -40,9 +40,11 @@ def _check(mat, expect_kind=None, batch=5, atol=1e-12):
 
 @pytest.mark.parametrize("n", [16, 17])
 @pytest.mark.parametrize("base_fn", [chebyshev, cheb_dirichlet, cheb_neumann])
-def test_transform_matrices_fold(base_fn, n):
+def test_transform_matrices_fold(base_fn, n, fold_gate):
     """Both transform directions fold (for even n both reflection symmetries
-    hold simultaneously and either fold type is valid)."""
+    hold simultaneously and either fold type is valid), from the gate up: it
+    is pinned to this size."""
+    fold_gate(n - 2)
     base = base_fn(n)
     fwd = base.projection @ chb.analysis_matrix(n)
     bwd = chb.synthesis_matrix(n) @ base.stencil
@@ -50,6 +52,10 @@ def test_transform_matrices_fold(base_fn, n):
         fm = _check(mat)
         assert fm.kind in ("analysis", "synthesis"), fm.kind
         assert fm.flops_factor == 0.5
+    fold_gate(n + 1)  # below the gate each is one plain product that keeps the hook
+    for mat in (fwd, bwd, chb.synthesis_matrix(n)):
+        fm = _check(mat, "plain")
+        assert fm.flops_factor == 1.0 and fm.set_precision("high")
 
 
 @pytest.mark.parametrize("n", [16, 17])
@@ -78,18 +84,24 @@ def test_mixed_bc_base_falls_back_to_plain():
 
 def test_unstructured_matrix_is_plain():
     rng = np.random.default_rng(1)
-    _check(rng.standard_normal((12, 14)), "plain")
+    fm = _check(rng.standard_normal((12, 14)), "plain")
+    assert not fm.set_precision("high")  # only a transform's forms take the fast key's
 
 
-def test_folded_accepts_complex_input():
+@pytest.mark.parametrize("kind", ["synthesis", "plain"])
+def test_folded_accepts_complex_input(kind, fold_gate):
+    fold_gate(16 if kind == "synthesis" else 17)
     fm = FoldedMatrix(chb.synthesis_matrix(16), _dev)
+    assert fm.kind == kind
     rng = np.random.default_rng(2)
     x = jnp.asarray(rng.standard_normal((16, 3)) + 1j * rng.standard_normal((16, 3)))
     ref = chb.synthesis_matrix(16) @ np.asarray(x)
     np.testing.assert_allclose(np.asarray(fm.apply(x, 0)), ref, atol=1e-12)
 
 
-def test_disable_env(monkeypatch):
+def test_disable_env(monkeypatch, fold_gate):
+    fold_gate(16)
+    assert FoldedMatrix(chb.synthesis_matrix(16), _dev).kind == "synthesis"
     monkeypatch.setenv("RUSTPDE_FOLDED", "0")
     fm = FoldedMatrix(chb.synthesis_matrix(16), _dev)
     assert fm.kind == "plain"
@@ -226,3 +238,130 @@ def test_hybrid_cast_rejects_complex_input():
     bad = jnp.asarray(rng.standard_normal((8, 5)) + 1j)
     with pytest.raises(TypeError, match="imaginary"):
         fm.apply(bad, 0)
+
+
+# -- the fold gate -----------------------------------------------------------------
+
+GATE = 31
+
+
+def _gated(case, n):
+    """``(host matrix, FoldedMatrix keywords, folded kind)`` of one reflection
+    structure on ``cheb_dirichlet(n)``, whose smaller extent is ``n - 2``."""
+    base = cheb_dirichlet(n)
+    fwd = base.projection @ chb.analysis_matrix(n)
+    if case == "analysis":
+        return fwd, dict(sep_out=True), "analysis_sep"
+    if case == "analysis_cut":
+        return fwd, dict(sep_out=True, keep_rows=base.m * 2 // 3), "analysis_sep_cut"
+    order = 0 if case == "synthesis_plus" else 1  # the odd derivative flips the sign
+    return chb.synthesis_matrix(n) @ base.gradient_matrix(order), dict(sep_in=True), "synthesis_sep"
+
+
+@pytest.mark.parametrize("axis", [0, 1])
+@pytest.mark.parametrize("case", ["analysis", "analysis_cut", "synthesis_plus", "synthesis_minus"])
+@pytest.mark.parametrize("side", ["below", "above"])
+def test_reflection_folds_engage_from_the_gate_up(side, case, axis, fold_gate):
+    """Just below ``_FOLD_MIN_DIM`` a reflection-symmetric transform is one
+    plain product and from it up the fold; at either size the two forms are
+    the same operator to machine epsilon, a cut analysis leaves exact zeros in
+    its dead rows in both, and both carry the precision the fast key sets."""
+    import jax
+
+    from rustpde_mpi_tpu.ops.folded import dense_operator, kept_storage_rows
+    from rustpde_mpi_tpu.utils.jit import equations
+
+    n = GATE + 1 if side == "below" else GATE + 2  # smaller extent 30 | 31
+    mat, kw, folded_kind = _gated(case, n)
+    fold_gate(GATE)
+    here = FoldedMatrix(mat, _dev, **kw)
+    assert here.kind == ("plain" if side == "below" else folded_kind)
+    fold_gate(n if side == "above" else 4)
+    other = FoldedMatrix(mat, _dev, **kw)
+    assert other.kind == (folded_kind if side == "below" else "plain")
+    assert {here.flops_factor, other.flops_factor} == (
+        {0.5 * kw["keep_rows"] / mat.shape[0], kw["keep_rows"] / mat.shape[0]}
+        if "keep_rows" in kw else {0.5, 1.0}
+    )
+
+    dense = dense_operator(mat, **kw)  # the storage-layout operator both must equal
+    rng = np.random.default_rng(3)
+    shape = (mat.shape[1], 5) if axis == 0 else (5, mat.shape[1])
+    x = jnp.asarray(rng.standard_normal(shape))
+    want = np.moveaxis(np.tensordot(dense, np.asarray(x), axes=([1], [axis])), 0, axis)
+    got_here, got_other = np.asarray(here.apply(x, axis)), np.asarray(other.apply(x, axis))
+    eps = 50 * np.finfo(got_here.dtype).eps * np.abs(want).max()
+    np.testing.assert_allclose(got_here, want, atol=eps)
+    np.testing.assert_allclose(got_other, got_here, atol=eps)
+    if "keep_rows" in kw:
+        kept = kept_storage_rows(mat.shape[0], kw["keep_rows"], True)
+        dead = np.setdiff1d(np.arange(mat.shape[0]), kept)
+        assert dead.size
+        for got in (got_here, got_other):
+            assert not np.take(got, dead, axis=axis).any()  # exact zeros
+            assert np.take(got, kept, axis=axis).all()
+
+    def precisions(fm):
+        jaxpr = jax.make_jaxpr(lambda v: fm.apply(v, axis))(x).jaxpr
+        return [e.params["precision"] for e in equations(jaxpr) if e.primitive.name == "dot_general"]
+
+    for fm, products in ((here, 1 if side == "below" else 2), (other, 2 if side == "below" else 1)):
+        high, highest = jax.lax.Precision.HIGH, jax.lax.Precision.HIGHEST
+        assert precisions(fm) == [(highest, highest)] * products  # the session's default
+        assert fm.set_precision("high")
+        assert precisions(fm) == [(high, high)] * products
+
+
+def test_fold_gate_reads_the_shape_and_the_products_itemsize():
+    """The module's own gate: in float32 a reflection fold engages from a
+    smaller extent of 255 (the 257-point grid's: a parity block fills a whole
+    128 tile), in float64, which the chip emulates, at every size; the
+    hybrid's float32 operators of a float64 session take float32's."""
+    from rustpde_mpi_tpu import config
+    from rustpde_mpi_tpu.ops import folded
+
+    small, below, at = (chb.synthesis_matrix(n) for n in (33, 254, 255))
+    assert folded._detect(small, itemsize=4).kind == "plain"
+    assert folded._detect(small, itemsize=8).kind == "synthesis"
+    assert folded._detect(below, itemsize=4).kind == "plain"
+    assert folded._detect(at, itemsize=4).kind == "synthesis"
+    for n, kind in ((257, "analysis_sep"), (256, "plain")):  # 255 x 257 | 254 x 256
+        fwd = cheb_dirichlet(n).projection @ chb.analysis_matrix(n)
+        assert folded._detect(fwd, sep_out=True, itemsize=4).kind == kind
+        assert folded._detect(fwd, sep_out=True, itemsize=8).kind == "analysis_sep"
+    # the dense checkerboard blocks of the sep layout: two products from a
+    # smaller extent of 191 up, one below it (float64: two at every size)
+    for n, kind in ((193, "sep_preserve[plain,plain]"), (192, "plain")):  # 191 x 193 | 190 x 192
+        proj = cheb_dirichlet(n).projection
+        assert folded._detect(proj, sep_in=True, sep_out=True, itemsize=4).kind == kind
+        assert folded._detect(proj, True, True, itemsize=8).kind == "sep_preserve[plain,plain]"
+    stencil = cheb_dirichlet(65).stencil  # banded blocks are not gated
+    assert folded._detect(stencil, True, True, itemsize=4).kind == "sep_preserve[banded,banded]"
+    session = "synthesis" if config.X64 else "plain"
+    assert FoldedMatrix(small, _dev).kind == session
+    assert FoldedMatrix(small, _dev, cast=np.float32).kind == "plain"
+
+
+@pytest.mark.parametrize("axis", [0, 1])
+@pytest.mark.parametrize("case", ["preserve", "flip"])
+def test_dense_checkerboard_blocks_engage_from_their_gate_up(case, axis, fold_gate):
+    """A spectral->spectral operator of the sep layout whose two parity
+    blocks are dense: below ``_SEP_MIN_DIM`` one product over the whole
+    matrix, its exact zeros included, from it up the two blocks; the same
+    operator either way."""
+    from rustpde_mpi_tpu.ops.folded import dense_operator
+
+    base = cheb_dirichlet(33)
+    mat = base.projection if case == "preserve" else base.gradient_matrix(1)
+    fold_gate(4)
+    blocks = FoldedMatrix(mat, _dev, sep_in=True, sep_out=True)
+    assert blocks.kind == f"sep_{case}[plain,plain]" and abs(blocks.flops_factor - 0.5) < 0.01
+    fold_gate(fold_gate.NEVER)
+    plain = FoldedMatrix(mat, _dev, sep_in=True, sep_out=True)
+    assert plain.kind == "plain" and plain.flops_factor == 1.0
+    dense = dense_operator(mat, sep_in=True, sep_out=True)
+    x = jnp.asarray(np.random.default_rng(5).standard_normal((mat.shape[1], 4) if axis == 0 else (4, mat.shape[1])))
+    want = np.moveaxis(np.tensordot(dense, np.asarray(x), axes=([1], [axis])), 0, axis)
+    eps = 50 * np.finfo(want.dtype).eps * np.abs(want).max()
+    np.testing.assert_allclose(np.asarray(blocks.apply(x, axis)), want, atol=eps)
+    np.testing.assert_allclose(np.asarray(plain.apply(x, axis)), want, atol=eps)

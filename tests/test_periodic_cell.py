@@ -315,13 +315,17 @@ def test_the_chips_own_compiler_sums_no_field_either(grid):
     sums_no_field(done.stdout, nx, ny, flips=FLIPS, gathered_flips=0 if nx >= 256 else None)
 
 
-def test_meshed_confined_chunk_keeps_the_x_pencil_rest(monkeypatch, no_compile_cache):
+def test_meshed_confined_chunk_keeps_the_x_pencil_rest(monkeypatch, no_compile_cache, fold_gate):
     """The other side of the selection: a confined space (Chebyshev along x,
     dense x-operators) rests as x-pencils, its y-operators between a pair of
     flips, and its chunk on four devices holds the collectives the tree before
     ISSUE 31 compiled for it (counted there at this size, with this jax: the
-    chunk's text was the same but for its metadata)."""
+    chunk's text was the same but for its metadata).  That tree folded every
+    transform, so the fold gate of ops/folded.py is pinned below this size
+    (with plain products this partitioner takes 8 of the 23 flips as 10
+    all-gathers: PERF.md section 7, found by PR 34)."""
     monkeypatch.setenv("RUSTPDE_FORCE_TPU_PATH", "1")
+    fold_gate(4)
     model = Navier2D.new_confined(17, 17, *PHYSICS, "rbc", mesh=make_mesh(jax.devices()[:4]))
     assert model.temp_space.rest == SPEC and model.temp_space.synthesis_axes == (0, 1)
     counts = collections.Counter(kind for kind, _, _ in collectives(chunk_text(model)))

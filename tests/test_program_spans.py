@@ -184,6 +184,28 @@ def test_launches_counts_leaves_copied_and_buckets(ring, kind, n):
         assert args["members"] == 3 and args["layer"] == "ensemble"
 
 
+@pytest.mark.parametrize("kind", ["model", "ensemble"])
+@pytest.mark.parametrize("folded", [True, False])
+def test_update_n_spans_count_the_parity_folds_reverses(ring, monkeypatch, fold_gate, folded, kind):
+    """``reverses`` beside ``f32_products`` / ``f64_products``: the ``rev``
+    equations of one traced step, the member step's on the ensemble's span.
+    On the matmul-transform path a confined step applies 22 transforms
+    (4 in ``synthesis``, 6 in each of the three convection chains): folded,
+    each is two products and one reverse; below ops/folded.py's gates, where
+    every small float32 grid stands, one plain product and none, and each
+    dense checkerboard operator (24 at this size) one product for its two."""
+    monkeypatch.setenv("RUSTPDE_FORCE_TPU_PATH", "1")
+    fold_gate(4 if folded else fold_gate.NEVER)
+    sim = Navier2D.new_confined(17, 17, 1e4, 1.0, 0.01, 1.0, "rbc")
+    sim.init_random(0.1, seed=0)
+    if kind == "ensemble":
+        sim = NavierEnsemble.from_seeds(sim, seeds=[1, 2], amp=0.1)
+    sim.update_n(2)
+    args = ttracing.spans(f"{kind}.update_n")[-1][-1]
+    assert args["reverses"] == (22 if folded else 0)
+    assert args["f64_products"] + args["f32_products"] == (104 if folded else 104 - 22 - 24)
+
+
 @pytest.mark.parametrize("kind, fresh", [("model", 7), ("ensemble", 5)])
 def test_sentinel_seam_names_the_arrays_it_builds(ring, kind, fresh):
     """The sentinel branch still builds its initial flags and maxima eagerly

@@ -15,6 +15,15 @@ import jax.numpy as jnp
 _dev = lambda m: jnp.asarray(m)  # noqa: E731
 
 
+@pytest.fixture(params=["folded", "plain"])
+def transform_form(request, fold_gate):
+    """Both forms of a reflection-symmetric transform at a small size: its
+    parity fold (the gate of ops/folded.py pinned below the size) and the one
+    plain product every grid below the gate runs."""
+    fold_gate(4 if request.param == "folded" else fold_gate.NEVER)
+    return request.param
+
+
 @pytest.mark.parametrize("n", [513, 512])
 def test_trapezoid_strips_engage_and_match(n):
     S = chb.stencil_dirichlet(n)
@@ -31,10 +40,12 @@ def test_trapezoid_strips_engage_and_match(n):
 
 
 @pytest.mark.parametrize("n", [17, 16, 33])
-def test_fwd_cut_matches_masked_forward(n):
+def test_fwd_cut_matches_masked_forward(n, transform_form):
     """forward_dealiased (dead GEMM rows dropped) == forward * 2/3-mask."""
     sep = rp.Space2(rp.cheb_dirichlet(n), rp.cheb_neumann(n + 1), sep=True, method="matmul")
     assert all(sep.sep)
+    kind = "analysis_sep_cut" if transform_form == "folded" else "plain"
+    assert sep.bases[0]._sep_dev("fwd_cut").kind == kind
     rng = np.random.default_rng(0)
     v = rng.standard_normal(sep.shape_physical)
     got = np.asarray(sep.forward_dealiased(v))
@@ -43,11 +54,13 @@ def test_fwd_cut_matches_masked_forward(n):
 
 
 @pytest.mark.parametrize("deriv", [(1, 0), (0, 1), (2, 0), (1, 1)])
-def test_backward_gradient_fusion_matches(deriv):
+def test_backward_gradient_fusion_matches(deriv, transform_form):
     """Syn @ D @ S fusion (incl. the sign=-1 odd-order synthesis symmetry)
     == backward_ortho(gradient(.))."""
     sep = rp.Space2(rp.cheb_dirichlet(33), rp.cheb_neumann(32), sep=True, method="matmul")
     assert all(sep.sep)
+    kind = "synthesis_sep" if transform_form == "folded" else "plain"
+    assert sep.bases[0]._sep_dev(("bwd_grad", 1)).kind == kind
     rng = np.random.default_rng(1)
     vhat = sep.forward(rng.standard_normal(sep.shape_physical))
     got = np.asarray(sep.backward_gradient(vhat, deriv, (1.0, 2.0)))
@@ -165,7 +178,7 @@ def test_fwd_cut_fast_key_plumbing(monkeypatch):
     np.testing.assert_allclose(got, want, atol=1e-13)
 
 
-def test_mixed_sep_periodic_space(monkeypatch):
+def test_mixed_sep_periodic_space(monkeypatch, transform_form):
     """Periodic (split-Fourier x, Chebyshev y) space with the Chebyshev axis
     sep: the per-axis fused paths — forward_dealiased with a vector cut on
     the Fourier axis, backward_gradient with the fused chain on the sep axis
