@@ -33,14 +33,36 @@ import sys
 import tempfile
 import time
 
-# counts compilations through jax.monitoring; importing it touches neither
-# jax nor the program, so the parent stays off the chip
-from benchmark.meter import CompileMeter
-
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _OUT = os.path.join(_HERE, "chiprun_out")
 _DEADLINE_S = 1150.0  # the contract allows 1200 s, compilation included
 _REMAT = "Involuntary full rematerialization"
+
+
+class CompileMeter:
+    """Compilations and compile-cache loads between a mark and now, from the
+    program's own count of jax's compile events
+    (``telemetry/tracing.compile_totals``: on a span or off, every event is
+    in it), so this script keeps no listener beside the program's."""
+
+    @staticmethod
+    def mark() -> dict:
+        from rustpde_mpi_tpu.telemetry import tracing
+
+        return tracing.compile_totals()
+
+    @staticmethod
+    def since(mark: dict) -> dict:
+        from rustpde_mpi_tpu.telemetry import tracing
+
+        got = tracing.compile_totals_since(mark)
+        return {
+            "compiled": got["compiled"],
+            "cache_loads": got["cache_hits"],
+            "cache_misses": got["cache_misses"],
+            "compile_s": got["compile_s"],
+        }
+
 
 # journal rows that mean a request did not run clean end to end
 _UNCLEAN_EVENTS = frozenset(
@@ -404,6 +426,12 @@ def child_main(precision: str) -> int:
     want_x64 = precision == "f64"
     if config.X64 != want_x64:
         print(f"chip_smoke: child asked for {precision}, X64={config.X64}", file=sys.stderr)
+        return 4
+    from rustpde_mpi_tpu.telemetry import tracing
+
+    if not tracing.enabled():
+        print("chip_smoke: the recorder is off (RUSTPDE_TRACE=0 or RUSTPDE_TELEMETRY=0): the "
+              "compile gates read the program's own count", file=sys.stderr)
         return 4
     cache_dir = config.ensure_compile_cache()
     meter = CompileMeter()

@@ -30,6 +30,7 @@ from .ops import fourier as fou
 from .ops import fourstep
 from .ops import transforms as tr
 from .ops.folded import FoldedMatrix
+from .telemetry import tracing as _tr
 
 
 class BaseKind(enum.Enum):
@@ -805,6 +806,16 @@ class Space2:
     def __init__(
         self, base_x: Base, base_y: Base, method: str | None = None, sep=None
     ):
+        # ``space.build`` (operators and kernels): the layout decisions below.
+        # The one-axis operators themselves are built on the shared ``Base``
+        # objects at first use, inside whichever span first applies them
+        with _tr.span(
+            "space.build", layer="operators and kernels",
+            shape=(base_x.n, base_y.n), bases=(base_x.kind.value, base_y.kind.value),
+        ):
+            self._build(base_x, base_y, method, sep)
+
+    def _build(self, base_x: Base, base_y: Base, method, sep) -> None:
         if base_y.kind.is_periodic and not base_x.kind.is_periodic:
             raise ValueError("periodic y-axis under non-periodic x is unsupported")
         self.bases = (base_x, base_y)

@@ -275,6 +275,16 @@ class CampaignModelBase:
         before artifacts are restored/rebuilt — a wrapper model syncs its
         embedded model here (``Navier2DLnse`` -> inner ``Navier2D``)."""
 
+    @staticmethod
+    def _build_span(nx: int, ny: int, mesh):
+        """The span a model's ``__init__`` holds from its first line to its
+        last: ``model.build``."""
+        return _tr.span(
+            "model.build", layer=_LAYER, nx=int(nx), ny=int(ny),
+            dtype=np.dtype(config.real_dtype()).name,
+            devices=1 if mesh is None else int(mesh.size),
+        )
+
     # -- sharding helpers ----------------------------------------------------
 
     def _scope(self):
@@ -320,6 +330,7 @@ class CampaignModelBase:
 
         with self._scope():
             converted, consts = hoist_constants(fn, *example)
+            _tr.count(consts=len(consts), const_bytes=sum(c.nbytes for c in consts))
             return converted, replicate(consts)
 
     def _exchanges_per_step(self) -> tuple:
@@ -363,39 +374,40 @@ class CampaignModelBase:
         at large grids) and build the chunked ``step_n`` with the in-chunk
         early-exit.
 
-        The wall time of every pass through here is recorded per model
-        kind (telemetry/compile_log.py): dt-ladder re-jits and restores
+        Every pass through here is the span ``model.compile_entry_points``
+        (``consts`` and ``const_bytes`` hoisted, ``pass`` = how many passes
+        this model has made), and its duration is recorded per model kind
+        (telemetry/compile_log.py): dt-ladder re-jits and restores
         re-enter this seam without a model rebuild, and the cold-start
         ROADMAP item needs that attribution separated from build time."""
-        import time as _time
-
         from ..parallel.mesh import unplaced
         from ..telemetry import compile_log
         from ..utils.jit import dot_generals_by_operand, reverses
 
-        t0 = _time.perf_counter()
+        seam = _tr.timed("model.compile_entry_points", layer=_LAYER, consts=0, const_bytes=0)
         try:
-            self._compile_entry_points_impl()
-            # the ``dot_general``s of one step's traced program by operand
-            # type, counted once per pass for the ``update_n`` spans (the
-            # ensemble's too): which arithmetic the step's products were
-            # compiled in, and how many array flips its parity folds brought
-            products = dot_generals_by_operand(self._step_cc.jaxpr)
-            self._step_products = {
-                "f64_products": products.get("float64", 0),
-                "f32_products": products.get("float32", 0),
-                "reverses": reverses(self._step_cc.jaxpr),
-            }
-            # the scanned chunks' constants, counted once per pass for the
-            # span's ``unplaced_args`` (:meth:`_mesh_span_args`)
-            self._unplaced_consts = unplaced(
-                (self._step_consts, self._stats_consts, self._sent_consts),
-                getattr(self, "mesh", None),
-            )
+            with seam:
+                self._compile_entry_points_impl()
+                seam.set(**{"pass": self.recompile_count})
+                # the ``dot_general``s of one step's traced program by operand
+                # type, counted once per pass for the ``update_n`` spans (the
+                # ensemble's too): which arithmetic the step's products were
+                # compiled in, and how many array flips its parity folds brought
+                products = dot_generals_by_operand(self._step_cc.jaxpr)
+                self._step_products = {
+                    "f64_products": products.get("float64", 0),
+                    "f32_products": products.get("float32", 0),
+                    "reverses": reverses(self._step_cc.jaxpr),
+                }
+                # the scanned chunks' constants, counted once per pass for the
+                # span's ``unplaced_args`` (:meth:`_mesh_span_args`)
+                self._unplaced_consts = unplaced(
+                    (self._step_consts, self._stats_consts, self._sent_consts),
+                    getattr(self, "mesh", None),
+                )
         finally:
             compile_log.observe_entry_compile(
-                str(getattr(self, "MODEL_KIND", type(self).__name__)),
-                _time.perf_counter() - t0,
+                str(getattr(self, "MODEL_KIND", type(self).__name__)), seam.seconds
             )
 
     def _compile_entry_points_impl(self) -> None:

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry import tracing as _tr
 from ..utils.integrate import Integrate
 from .campaign import DispatchSpans
 from .navier import Navier2D, NavierState
@@ -65,6 +66,11 @@ class NavierEnsemble(Integrate):
     journal_writer = None
 
     def __init__(self, model, states):
+        with _tr.span("ensemble.build", layer="ensemble") as sp:
+            self._build(model, states)
+            sp.set(members=self.k)
+
+    def _build(self, model, states) -> None:
         if hasattr(states, "_fields"):  # a state pytree, maybe pre-stacked
             if np.ndim(states.temp) != np.ndim(model.state.temp) + 1:
                 raise TypeError(
@@ -216,7 +222,7 @@ class NavierEnsemble(Integrate):
     def get_field(self, name: str, member: int) -> np.ndarray:
         """Physical values of one member's variable."""
         space = getattr(self.model, f"{name}_space")
-        with self.model._scope():
+        with _tr.span("model.get_field", layer="model step", fields=(name,)), self.model._scope():
             return np.asarray(space.backward(getattr(self.member_state(member), name)))
 
     # -- the batched step ----------------------------------------------------
@@ -256,21 +262,20 @@ class NavierEnsemble(Integrate):
 
     def _compile_entry_points(self) -> None:
         # same attribution seam as the base model's entry-point compile
-        # (models/campaign.py): the K-member vmap trace is the serving
-        # path's dominant build cost and is re-entered by set_dt/
-        # set_stability without a model rebuild — it must not vanish from
-        # the per-kind compile metrics
-        import time as _time
-
+        # (models/campaign.py), as the span ``ensemble.compile_entry_points``:
+        # the K-member vmap trace is the serving path's dominant build cost
+        # and is re-entered by set_dt/set_stability without a model rebuild —
+        # it must not vanish from the per-kind compile metrics
         from ..telemetry import compile_log as _compile_log
 
-        t0 = _time.perf_counter()
+        seam = _tr.timed("ensemble.compile_entry_points", layer="model step")
         try:
-            self._compile_entry_points_impl()
+            with seam:
+                self._compile_entry_points_impl()
+                seam.set(**{"pass": self.recompile_count})
         finally:
             _compile_log.observe_entry_compile(
-                f"ensemble:{getattr(self.model, 'MODEL_KIND', 'model')}",
-                _time.perf_counter() - t0,
+                f"ensemble:{getattr(self.model, 'MODEL_KIND', 'model')}", seam.seconds
             )
 
     def _compile_entry_points_impl(self) -> None:

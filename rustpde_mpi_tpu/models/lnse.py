@@ -83,6 +83,10 @@ class Navier2DLnse(CampaignModelBase, Integrate):
         mean: MeanFields | None = None,
         mesh=None,
     ):
+        with self._build_span(nx, ny, mesh):
+            self._build(nx, ny, ra, pr, dt, aspect, bc, periodic, mean, mesh)
+
+    def _build(self, nx, ny, ra, pr, dt, aspect, bc, periodic, mean, mesh) -> None:
         self.navier = Navier2D(nx, ny, ra, pr, dt, aspect, bc, periodic, mesh=mesh)
         if mean is None:
             mean = MeanFields.read_from(nx, ny, "mean.h5", bc=bc, periodic=periodic)
@@ -509,7 +513,6 @@ class Navier2DLnse(CampaignModelBase, Integrate):
     # dt-baked artifacts (campaign rung cache) include the sweeps' entries
     _DT_ARTIFACTS = (
         "_adj_n", "_adj_n_jit", "_adj_consts", "_fwd_n", "_fwd_n_jit", "_fwd_consts",
-        "_sweep_programs",
     ) + CampaignModelBase._DT_ARTIFACTS
 
     def _dt_changed(self, dt: float) -> None:
@@ -535,9 +538,6 @@ class Navier2DLnse(CampaignModelBase, Integrate):
         hook (not the timed wrapper), so the per-kind compile attribution
         covers the sweeps' hoist+jit too."""
         super()._compile_entry_points_impl()
-        #: (direction, bucket) of every sweep program dispatched so far: a
-        #: bucket length seen before builds nothing
-        self._sweep_programs = set()
         self._compile_sweep_entry_points(self._state_example())
 
     def _compile_sweep_entry_points(self, example) -> None:
@@ -702,10 +702,11 @@ class Navier2DLnse(CampaignModelBase, Integrate):
         as one dispatch (pres and pseu untouched)."""
         rdt = config.real_dtype()
         nav = self.navier
-        with nav._scope():
+        fields = ("velx", "vely", "temp")
+        with _tr.span("model.set_field", layer=_LAYER, fields=fields), nav._scope():
             vhats = self._host_seam("forward")(*(jnp.asarray(a, dtype=rdt) for a in (velx, vely, temp)))
             self.state = self.state._replace(
-                **{k: nav._place(v) for k, v in zip(("velx", "vely", "temp"), vhats)}
+                **{k: nav._place(v) for k, v in zip(fields, vhats)}
             )
         self._obs_cache = None
 
@@ -730,36 +731,33 @@ class Navier2DLnse(CampaignModelBase, Integrate):
         weights (minus target) with pressure kept (lnse_adj_grad.rs:155-168)."""
         return self._host_seam("terminal")(state, self._target_fields(target), beta1, beta2)
 
-    def _launch_sweep(self, name: str, step_k, carry, lengths):
+    def _launch_sweep(self, step_k, carry, lengths):
         """``carry = step_k(carry, k)`` for each program length of a sweep,
-        each under a ``model.launch`` span as ``update_n``'s buckets are;
-        returns the carry, the launches made and how many of the programs
-        were dispatched for the first time (built, or loaded from the
-        persistent compile cache)."""
-        built = 0
+        each under a ``model.launch`` span as ``update_n``'s buckets are (a
+        length dispatched for the first time leaves its ``lowerings`` and
+        ``backend_compiles`` on that span); returns the carry and the
+        launches made."""
         for k in lengths:
-            built += (name, int(k)) not in self._sweep_programs
-            self._sweep_programs.add((name, int(k)))
             with _tr.span("model.launch", layer=_LAYER, steps=int(k), aot=False):
                 carry = step_k(carry, int(k))
-        return carry, len(lengths), built
+        return carry, len(lengths)
 
     def _forward_sweep(self, n: int):
-        """n forward steps from ``self.state``; returns ``(history, launches,
-        programs built)``.  The linear adjoint reads no trajectory, so the
-        sweep is ``update_n`` itself and the history is empty."""
+        """n forward steps from ``self.state``; returns ``(history,
+        launches)``.  The linear adjoint reads no trajectory, so the sweep is
+        ``update_n`` itself and the history is empty."""
         self.update_n(n)
-        return (), len(scan_buckets(n)), 0
+        return (), len(scan_buckets(n))
 
-    def _adjoint_sweep(self, n: int, history):
+    def _adjoint_sweep(self, n: int, history) -> int:
         """n adjoint steps from ``self.state`` (the terminal condition);
-        returns ``(launches, programs built)``.  The linear adjoint carries
-        nothing but its state, so it runs in ``run_scanned``'s power-of-two
-        buckets, as ``update_n`` does."""
-        self.state, launches, built = self._launch_sweep(
-            "adjoint", lambda s, k: self._adj_n(s, history, k), self.state, scan_buckets(n)
+        returns the launches.  The linear adjoint carries nothing but its
+        state, so it runs in ``run_scanned``'s power-of-two buckets, as
+        ``update_n`` does."""
+        self.state, launches = self._launch_sweep(
+            lambda s, k: self._adj_n(s, history, k), self.state, scan_buckets(n)
         )
-        return launches, built
+        return launches
 
     def grad_adjoint(
         self,
@@ -781,16 +779,17 @@ class Navier2DLnse(CampaignModelBase, Integrate):
         Both sweeps go through the hoisting compile seam and their programs
         are kept with the model, so a horizon seen before builds nothing.
         Spans (model step): ``lnse.grad_adjoint`` (``steps``, ``launches``,
-        ``history_bytes`` of the stacked trajectory, ``compiles`` = sweep
-        programs dispatched for the first time) > ``lnse.forward_sweep`` (to
-        the moment J is on the host) and ``lnse.adjoint_sweep`` (terminal
-        condition to the gradient on the host) > ``model.launch``.
+        ``history_bytes`` of the stacked trajectory) > ``lnse.forward_sweep``
+        (to the moment J is on the host) and ``lnse.adjoint_sweep`` (terminal
+        condition to the gradient on the host) > ``model.launch``, which
+        carries ``backend_compiles`` where a sweep program was dispatched for
+        the first time.
         """
         del save_intervall  # device loop; intermediate snapshots not written
         n = max(1, round(max_time / self.dt))
         with _tr.span("lnse.grad_adjoint", layer=_LAYER, steps=2 * n) as whole:
             with _tr.span("lnse.forward_sweep", layer=_LAYER, steps=n):
-                history, launches, built = self._forward_sweep(n)
+                history, launches = self._forward_sweep(n)
                 fun_val = self.energy(beta1, beta2, target)
             with _tr.span("lnse.adjoint_sweep", layer=_LAYER, steps=n):
                 with self.navier._scope():
@@ -800,8 +799,7 @@ class Navier2DLnse(CampaignModelBase, Integrate):
                 fac = 1.0 if MAXIMIZE else -1.0
                 grads = tuple(fac * a for a in jax.device_get(self.physical()))
             whole.set(
-                launches=launches + more[0],
-                compiles=built + more[1],
+                launches=launches + more,
                 history_bytes=sum(
                     leaf.nbytes for leaf in jax.tree.leaves(history)
                 ),
@@ -996,14 +994,14 @@ class Navier2DNonLin(Navier2DLnse):
 
     def _forward_sweep(self, n: int):
         with self.navier._scope():
-            (self.state, history), launches, built = self._launch_sweep(
-                "forward", self._fwd_n, self.state, [n]
+            (self.state, history), launches = self._launch_sweep(
+                self._fwd_n, self.state, [n]
             )
         self.time += n * self.dt
-        return history, launches, built
+        return history, launches
 
-    def _adjoint_sweep(self, n: int, history):
-        self.state, launches, built = self._launch_sweep(
-            "adjoint", lambda s, k: self._adj_n(s, history, k), self.state, [n]
+    def _adjoint_sweep(self, n: int, history) -> int:
+        self.state, launches = self._launch_sweep(
+            lambda s, k: self._adj_n(s, history, k), self.state, [n]
         )
-        return launches, built
+        return launches

@@ -38,10 +38,11 @@ from ..bases import (
 )
 from ..field import average_weights, norm_l2
 from ..solver import HholtzAdi, Poisson
+from ..telemetry import tracing as _tr
 from ..utils.integrate import Integrate
 from . import boundary_conditions as bcs
 from . import functions as fns
-from .campaign import CampaignModelBase
+from .campaign import _LAYER, CampaignModelBase
 
 
 class NavierState(NamedTuple):
@@ -171,6 +172,10 @@ class Navier2D(CampaignModelBase, Integrate):
         mesh=None,
         scenario=None,
     ):
+        with self._build_span(nx, ny, mesh):
+            self._build(nx, ny, ra, pr, dt, aspect, bc, periodic, mesh, scenario)
+
+    def _build(self, nx, ny, ra, pr, dt, aspect, bc, periodic, mesh, scenario) -> None:
         if bc not in ("rbc", "hc"):
             raise ValueError(f"boundary condition type {bc!r} not recognized")
         # pencil-sharding mesh (None = single device); one model serves both —
@@ -274,8 +279,9 @@ class Navier2D(CampaignModelBase, Integrate):
         gy = fused_projection_gradient(self.vely_space, self.pseu_space, (0, 1))
         self._proj_grad = (*gx, *gy) if gx and gy else None
 
-        # boundary-condition lift fields as device constants
-        with self._scope():
+        # boundary-condition lift fields as device constants: set from
+        # physical values through the scratch space's transforms, op by op
+        with _tr.span("model.set_field", layer=_LAYER, fields=("tempbc",)), self._scope():
             self._build_bc_fields(xs, ys)
 
         # fused implicit-half stage kernels (RUSTPDE_STEP_KERNEL=pallas,
@@ -774,14 +780,14 @@ class Navier2D(CampaignModelBase, Integrate):
     def set_field(self, name: str, values: np.ndarray) -> None:
         """Set one variable from physical values (host -> device forward)."""
         space: Space2 = getattr(self, f"{name}_space")
-        with self._scope():
+        with _tr.span("model.set_field", layer=_LAYER, fields=(name,)), self._scope():
             vhat = space.forward(jnp.asarray(values, dtype=config.real_dtype()))
             self.state = self.state._replace(**{name: self._place(vhat)})
 
     def get_field(self, name: str) -> np.ndarray:
         """Physical values of one variable (device backward -> host)."""
         space: Space2 = getattr(self, f"{name}_space")
-        with self._scope():
+        with _tr.span("model.get_field", layer=_LAYER, fields=(name,)), self._scope():
             return np.asarray(space.backward(getattr(self.state, name)))
 
     # -- the time step -------------------------------------------------------

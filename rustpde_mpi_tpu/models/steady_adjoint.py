@@ -82,6 +82,10 @@ class Navier2DAdjoint(CampaignModelBase, Integrate):
         mesh=None,
         res_tol: float = RES_TOL,
     ):
+        with self._build_span(nx, ny, mesh):
+            self._build(nx, ny, ra, pr, dt, aspect, bc, periodic, mesh, res_tol)
+
+    def _build(self, nx, ny, ra, pr, dt, aspect, bc, periodic, mesh, res_tol) -> None:
         # the embedded forward model is built at DT_NAVIER so its implicit
         # Helmholtz solvers carry the correct dt (steady_adjoint.rs:286-300)
         self.navier = Navier2D(nx, ny, ra, pr, DT_NAVIER, aspect, bc, periodic, mesh=mesh)

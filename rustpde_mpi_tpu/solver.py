@@ -40,6 +40,7 @@ from . import config
 from .bases import Base, BaseKind, Space2  # noqa: F401
 from .ops.banded import BandedSolver, DenseSolver, DiagSolver
 from .ops.folded import FoldedMatrix
+from .telemetry import tracing as _tr
 
 _P, _Q = 2, 4  # lower/upper bandwidth of every preconditioned Chebyshev operator
 
@@ -142,7 +143,10 @@ def _axis_modal_data(space: Space2, axis: int, ci: float, sign: float):
     solution back to composite coefficients.  Fourier axes are already modal:
     ``lam = sign*ci*(-k^2)``, no maps.  This is the pencil the reference's
     FdmaTensor diagonalizes (/root/reference/src/solver/fdma_tensor.rs:106-154);
-    under the truncated quasi-inverse its spectrum is exactly real."""
+    under the truncated quasi-inverse its spectrum is exactly real.
+
+    On the span that is open (``solver.build``): ``eigs`` counts the axes
+    decomposed here, ``eig_cached`` those the host-eig disk cache served."""
     base = space.bases[axis]
     if base.kind.is_periodic:
         return sign * ci * (-(base.wavenumbers**2)), None, None
@@ -170,9 +174,12 @@ def _axis_modal_data(space: Space2, axis: int, ci: float, sign: float):
         )
         try:
             with np.load(cache_path) as z:
-                return z["lam"], z["fwd"], z["q"]
+                modal = z["lam"], z["fwd"], z["q"]
+            _tr.count(eigs=1, eig_cached=1)
+            return modal
         except Exception:  # missing/corrupt/format-drift: recompute
             pass
+    _tr.count(eigs=1)
     if (
         _checker_shift(mat_c) == 0
         and _checker_shift(mat_a) == 0
@@ -262,6 +269,13 @@ class HholtzAdi:
     """
 
     def __init__(self, space: Space2, c, method: str | None = None):
+        with _tr.span(
+            "solver.build", layer="operators and kernels",
+            kind="hholtz_adi", shape=space.shape_physical,
+        ):
+            self._build(space, c, method)
+
+    def _build(self, space: Space2, c, method) -> None:
         method = method or default_method()
         self.space = space
         self.rest = space.rest  # the pencil its spectral arrays rest in
@@ -502,6 +516,14 @@ class _TensorBased:
         fix_singular=False,
         method: str | None = None,
     ):
+        with _tr.span(
+            "solver.build", layer="operators and kernels",
+            kind=type(self).__name__.lower(), shape=space.shape_physical,
+            eigs=0, eig_cached=0,
+        ):
+            self._build(space, c, alpha, negate_lap, fix_singular, method)
+
+    def _build(self, space, c, alpha, negate_lap, fix_singular, method) -> None:
         method = method or ("fd" if config.is_tpu_like() else "banded")
         sign = -1.0 if negate_lap else 1.0
         sep = getattr(space, "sep", (False, False))

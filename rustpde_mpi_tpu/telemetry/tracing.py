@@ -27,13 +27,33 @@ beside an idle gap of the chip (trace times count from the session's start,
 so a ``perf_counter`` reading cannot be matched to them afterwards).
 :func:`spans` is the read side for code in the same process.
 
+jax's own compile events land on the span that is open.  One
+``jax.monitoring`` duration listener and one event listener, registered once
+unless the process starts with tracing off, add each event to the ``args`` of
+the innermost span open on the thread that fired it: ``traces``/``trace_s``
+(a jaxpr traced), ``lowerings``/``lower_s`` (a jaxpr lowered to a module),
+``backend_compiles``/``compile_s`` (XLA compiled a program or loaded it from
+the persistent cache), ``cache_hits``, ``cache_misses`` and ``cache_load_s``
+(the persistent cache), so ``backend_compiles - cache_hits`` is what XLA
+really compiled inside that span.  The counts are SELF counts: a parent's
+total is its own plus its children's, which ``id``/``parent`` give.  A span
+that saw no event carries none of the keys.  Events fired with no span open
+go to a process-wide ``unattributed`` total; :func:`compile_totals` returns
+both cumulative totals, so a caller takes a mark and a difference without a
+listener of its own.  For that the per-thread stack of open spans holds the
+``_Span`` objects themselves (not their ids): an event costs a dict lookup,
+a list read, two dict adds and one lock for the totals (about a
+microsecond), and jax fires none on a warm dispatch.  jax cannot take a
+listener back, so with tracing switched off later each returns at its first
+branch, as :func:`span` does.
+
 Overhead contract: with tracing disabled (:func:`set_enabled` or
 ``RUSTPDE_TRACE=0``) :func:`span` returns a shared no-op context manager —
-one function call and one branch (~ns, no allocation, no annotation);
-enabled spans cost two ``perf_counter`` reads, one TraceAnnotation and one
-deque append.  Spans wrap HOST-side seams only and never add device work,
-so traced runs stay bit-identical (CI-asserted together with the metrics
-layer)."""
+one function call and one branch (~ns, no allocation, no annotation), and a
+process that starts that way registers no listener; enabled spans cost two
+``perf_counter`` reads, one TraceAnnotation and one deque append.  Spans
+wrap HOST-side seams only and never add device work, so traced runs stay
+bit-identical (CI-asserted together with the metrics layer)."""
 
 from __future__ import annotations
 
@@ -44,6 +64,7 @@ import threading
 import time as _time
 from collections import deque
 
+from jax import monitoring as _monitoring
 from jax.profiler import TraceAnnotation
 
 from .. import config as _config
@@ -62,9 +83,13 @@ def enabled() -> bool:
 
 def set_enabled(flag: bool) -> None:
     """Turn span recording on/off globally (``RUSTPDE_TRACE`` env default;
-    the bench overhead gate toggles this together with the metrics flag)."""
+    the bench overhead gate toggles this together with the metrics flag).
+    Switching it on in a process that started with it off registers the
+    compile-event listeners then."""
     global _ENABLED
     _ENABLED = bool(flag)
+    if _ENABLED:
+        _register_listeners()
 
 
 class FlightRecorder:
@@ -188,14 +213,14 @@ _IDS = itertools.count(1)
 
 class _OpenSpans(threading.local):
     def __init__(self):
-        self.stack: list[int] = []
+        self.stack: list[_Span] = []
 
 
 _OPEN = _OpenSpans()
 
 
 class _Span:
-    __slots__ = ("name", "args", "id", "parent", "_t0", "_annotation")
+    __slots__ = ("name", "args", "id", "parent", "seconds", "_t0", "_annotation")
 
     def __init__(self, name: str, args: dict):
         self.name = name
@@ -207,9 +232,9 @@ class _Span:
 
     def __enter__(self):
         stack = _OPEN.stack
-        self.parent = stack[-1] if stack else None
+        self.parent = stack[-1].id if stack else None
         self.id = next(_IDS)
-        stack.append(self.id)
+        stack.append(self)
         self._annotation = TraceAnnotation("rustpde:" + self.name)
         self._annotation.__enter__()
         self._t0 = RECORDER.now_us()
@@ -217,6 +242,7 @@ class _Span:
 
     def __exit__(self, exc_type, exc, tb):
         dur = RECORDER.now_us() - self._t0
+        self.seconds = dur * 1e-6
         self._annotation.__exit__(exc_type, exc, tb)
         _OPEN.stack.pop()
         args = {"id": self.id, "parent": self.parent, **self.args}
@@ -246,6 +272,21 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+class _Stopwatch(_NullSpan):
+    """What :func:`timed` hands out while the recorder is off: the seam's
+    duration and nothing else."""
+
+    __slots__ = ("seconds", "_t0")
+
+    def __enter__(self):
+        self._t0 = _time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.seconds = _time.perf_counter() - self._t0
+        return False
+
+
 def span(name: str, layer: str | None = None, **args):
     """Context manager recording one complete trace event; the shared
     no-op object when tracing is disabled (one branch, no allocation).
@@ -255,6 +296,24 @@ def span(name: str, layer: str | None = None, **args):
     if layer is not None:
         args["layer"] = layer
     return _Span(name, args)
+
+
+def timed(name: str, layer: str | None = None, **args):
+    """:func:`span` for a seam whose duration the caller also hands on (to a
+    ``/metrics`` histogram): once closed, ``.seconds`` is the span's own
+    duration, so the ring and the histogram say one number.  With the
+    recorder off a bare stopwatch stands in, and the metrics half keeps
+    recording."""
+    return span(name, layer, **args) if _ENABLED else _Stopwatch()
+
+
+def count(**increments) -> None:
+    """Add to counts on the innermost span open on this thread, for a callee
+    that has no handle on it; nothing with the recorder off or no span open."""
+    if _ENABLED and _OPEN.stack:
+        into = _OPEN.stack[-1].args
+        for key, value in increments.items():
+            into[key] = into.get(key, 0) + value
 
 
 def spans(name: str) -> list[tuple]:
@@ -273,6 +332,83 @@ def spans(name: str) -> list[tuple]:
                     args,
                 )
             )
+    return out
+
+
+# -- jax's compile events, on the span that is open ---------------------------
+
+#: jax event -> (counter, seconds) added to the open span's args
+_EVENT_KEYS = {
+    "/jax/core/compile/jaxpr_trace_duration": ("traces", "trace_s"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": ("lowerings", "lower_s"),
+    "/jax/core/compile/backend_compile_duration": ("backend_compiles", "compile_s"),
+    "/jax/compilation_cache/cache_hits": ("cache_hits", None),
+    "/jax/compilation_cache/cache_misses": ("cache_misses", None),
+    "/jax/compilation_cache/cache_retrieval_time_sec": (None, "cache_load_s"),
+}
+COMPILE_KEYS = tuple(key for pair in _EVENT_KEYS.values() for key in pair if key)
+
+_totals = {
+    side: {key: 0.0 if key.endswith("_s") else 0 for key in COMPILE_KEYS}
+    for side in ("attributed", "unattributed")
+}
+_totals_lock = threading.Lock()
+_listening = False
+
+
+def _on_event(name: str, secs: float = 0.0, **_) -> None:
+    """Both listeners: jax hands a duration event its seconds, a plain event
+    none."""
+    if not _ENABLED:
+        return
+    keys = _EVENT_KEYS.get(name)
+    if keys is None:
+        return
+    adds = [(key, add) for key, add in zip(keys, (1, secs)) if key is not None]
+    stack = _OPEN.stack
+    if stack:
+        into = stack[-1].args
+        for key, add in adds:
+            into[key] = into.get(key, 0) + add
+    total = _totals["attributed" if stack else "unattributed"]
+    with _totals_lock:
+        for key, add in adds:
+            total[key] += add
+
+
+def _register_listeners() -> None:
+    global _listening
+    with _totals_lock:
+        if _listening:
+            return
+        _listening = True
+    _monitoring.register_event_duration_secs_listener(_on_event)
+    _monitoring.register_event_listener(_on_event)
+
+
+if _ENABLED:
+    _register_listeners()
+
+
+def compile_totals() -> dict:
+    """Cumulative compile events of this process since the listeners were
+    registered: ``{"attributed": {...}, "unattributed": {...}}``, each with
+    every key of :data:`COMPILE_KEYS`; ``attributed`` is the sum over every
+    span's own counts, ``unattributed`` what fired with no span open on its
+    thread.  Take it twice and subtract (:func:`compile_totals_since`)."""
+    with _totals_lock:
+        return {side: dict(total) for side, total in _totals.items()}
+
+
+def compile_totals_since(mark: dict) -> dict:
+    """``compile_totals()`` less an earlier ``mark``, both sides summed, plus
+    ``compiled`` = ``backend_compiles - cache_hits``: what XLA really
+    compiled since the mark, on a span or off."""
+    now = compile_totals()
+    out = {
+        key: sum(now[side][key] - mark[side][key] for side in now) for key in COMPILE_KEYS
+    }
+    out["compiled"] = max(0, out["backend_compiles"] - out["cache_hits"])
     return out
 
 

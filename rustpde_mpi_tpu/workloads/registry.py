@@ -77,11 +77,9 @@ def build_model_for_key(key: tuple, *, mesh=None, phase: str = "build"):
     ROADMAP item's baseline numbers.  ``phase`` stamps the attribution row
     ("build" for live campaign opens, "aot" when the warm pool builds
     ahead of traffic)."""
-    import time as _time
-
     from ..telemetry import compile_log
+    from ..telemetry import tracing as _tr
 
-    t0 = _time.perf_counter()
     key = tuple(key)
     if len(key) == 11:
         key = key[:10]
@@ -97,18 +95,20 @@ def build_model_for_key(key: tuple, *, mesh=None, phase: str = "build"):
 
         if scenario_signature(scenario) != tuple(scenario_sig):
             raise ValueError(f"non-canonical scenario signature {scenario_sig}")
-    model = build_model(
-        kind, nx, ny, ra, pr, dt, aspect, bc, periodic,
-        mesh=mesh, scenario=scenario,
-    )
+    # one clock for the seam: the span ``registry.build_model`` (it holds the
+    # model's own ``model.build``) is what the histogram observes
+    seam = _tr.timed("registry.build_model", layer="model step", kind=str(kind), phase=phase)
+    with seam:
+        model = build_model(
+            kind, nx, ny, ra, pr, dt, aspect, bc, periodic,
+            mesh=mesh, scenario=scenario,
+        )
     if model.compat_key != tuple(key):
         raise ValueError(
             f"registry builder for {kind!r} produced compat_key "
             f"{model.compat_key} for requested key {tuple(key)}"
         )
-    compile_log.observe_build(
-        key, _time.perf_counter() - t0, kind=str(kind), phase=phase
-    )
+    compile_log.observe_build(key, seam.seconds, kind=str(kind), phase=phase)
     return model
 
 

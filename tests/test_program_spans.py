@@ -7,7 +7,9 @@ What is held here: a span opened inside a profiler session is in the trace's
 host plane under ``rustpde:<name>``, nested as the calls are; ids and parents;
 the ``launches`` count; the scopes change no instruction of the compiled
 chunk; states are bit-identical with the recorder on and off, and off opens
-no annotation; each reader gives the mean it should.  No wall-clock cost is
+no annotation; each reader gives the mean it should; the build path's spans
+(``model.build`` and what it holds) and jax's compile events on the span that
+is open; the five readers of the set-up's spans.  No wall-clock cost is
 asserted."""
 
 import contextlib
@@ -15,6 +17,8 @@ import glob
 import importlib
 import os
 import re
+import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -359,11 +363,274 @@ def test_reader_means_the_last_traced_spans(ring, reader):
     assert mod.read({}, run) is None  # the recorder is off
 
 
-# -- (g) the yardstick's own checks ----------------------------------------------
+# -- (g) the build path and jax's compile events --------------------------------
+
+BUILD_LAYERS = {"model.build": "model step", "space.build": "operators and kernels",
+                "solver.build": "operators and kernels",
+                "model.compile_entry_points": "model step", "model.set_field": "model step",
+                "ensemble.build": "ensemble", "ensemble.compile_entry_points": "model step"}
+
+
+def _events(ring):
+    return [ev for ev in ring.events() if ev["ph"] == "X"]
+
+
+def test_a_build_opens_its_spans_under_model_build(ring):
+    """Five spaces, three solvers (velx and vely share one), the lift field's
+    transforms and the entry points, all children of ``model.build``."""
+    m = Navier2D.new_confined(17, 17, 1e4, 1.0, 0.01, 1.0, "rbc")
+    (build,) = ttracing.spans("model.build")
+    assert build[3] is None
+    assert {"nx": 17, "ny": 17, "devices": 1}.items() <= build[4].items()
+    assert build[4]["dtype"] in ("float32", "float64")
+    children = [ev for ev in _events(ring) if ev["args"]["parent"] == build[2]]
+    names = [ev["name"] for ev in children]
+    assert names.count("space.build") == 5 and names.count("solver.build") == 3
+    assert names.count("model.compile_entry_points") == 1 and names.count("model.set_field") == 1
+    assert set(names) == {"space.build", "solver.build", "model.compile_entry_points",
+                          "model.set_field"}
+    for ev in children:
+        assert ev["args"]["layer"] == BUILD_LAYERS[ev["name"]], ev["name"]
+    kinds = [ev["args"]["kind"] for ev in children if ev["name"] == "solver.build"]
+    assert kinds == ["hholtz_adi", "hholtz_adi", "poisson"]
+    (poisson,) = [ev for ev in children if ev["args"].get("kind") == "poisson"]
+    assert poisson["args"]["eigs"] == 1 and poisson["args"]["eig_cached"] == 0  # below the cache's gate
+    (entry,) = [ev for ev in children if ev["name"] == "model.compile_entry_points"]
+    assert entry["args"]["pass"] == 1 and entry["args"]["traces"] > 0
+    assert entry["args"]["consts"] == len(m._step_consts) + len(m._obs_consts)
+    assert entry["args"]["const_bytes"] == sum(
+        c.nbytes for c in (*m._step_consts, *m._obs_consts))
+    m.set_field("temp", np.zeros((17, 17)))
+    (*_, parent, args) = ttracing.spans("model.set_field")[-1]
+    assert parent is None and args["fields"] == ("temp",) and args["layer"] == "model step"
+    m._compile_entry_points()  # a dt-ladder re-jit: the same seam, outside any build
+    again = ttracing.spans("model.compile_entry_points")[-1]
+    assert again[3] is None and again[4]["pass"] == 2
+
+
+def test_an_ensemble_build_is_a_span_of_its_own(ring):
+    ens = NavierEnsemble.from_seeds(_model(), seeds=[1, 2, 3], amp=0.1)
+    (build,) = ttracing.spans("ensemble.build")
+    assert build[3] is None and build[4]["members"] == 3 == ens.k
+    assert build[4]["layer"] == "ensemble"
+    (entry,) = ttracing.spans("ensemble.compile_entry_points")
+    assert entry[3] == build[2] and entry[4]["layer"] == "model step" and entry[4]["pass"] == 1
+    # from_seeds sets three fields a member, before the ensemble is built
+    assert sum(s[3] is None for s in ttracing.spans("model.set_field")) >= 9
+
+
+def test_compile_events_land_on_the_span_that_is_open(ring):
+    """The first dispatch lowers and compiles under ``model.launch``, the
+    second fires nothing; every event of the stretch is on a span or in
+    ``unattributed``, and the two sum to the harness's meter."""
+    meter = CompileMeter()
+    mark, before = meter.mark(), ttracing.compile_totals()
+    m = _model()
+    m.update_n(8)
+    m.get_observables()
+    jax.block_until_ready(m.state)
+    first = ttracing.spans("model.launch")[-1][4]
+    assert first["lowerings"] >= 1 and first["backend_compiles"] >= 1
+    assert first["lower_s"] > 0 and first["compile_s"] > 0 and first["traces"] >= 1
+    assert ttracing.spans("model.observe_launch")[-1][4]["backend_compiles"] >= 1
+    since = meter.since(mark)
+    totals = ttracing.compile_totals_since(before)
+    assert totals["backend_compiles"] == since["compiled"] + since["cache_loads"] > 0
+    assert totals["cache_hits"] == since["cache_loads"]
+    assert totals["cache_misses"] == since["cache_misses"]
+    assert totals["compiled"] == since["compiled"]
+    assert totals["compile_s"] == pytest.approx(since["compile_s"])
+    # the ring's own counts are the attributed side of the totals
+    now = ttracing.compile_totals()
+    for key in ("backend_compiles", "lowerings", "traces", "cache_hits"):
+        on_spans = sum(ev["args"].get(key, 0) for ev in _events(ring))
+        assert on_spans == now["attributed"][key] - before["attributed"][key], key
+    # the read-back is a span too: nothing the program ran was off a span
+    m.get_field("temp")
+    assert ttracing.spans("model.get_field")[-1][4]["fields"] == ("temp",)
+    assert ttracing.compile_totals()["unattributed"] == before["unattributed"]
+    mark, before = meter.mark(), ttracing.compile_totals()
+    m.update_n(8)
+    jax.block_until_ready(m.state)
+    warm = ttracing.spans("model.launch")[-1][4]
+    assert not set(warm) & set(ttracing.COMPILE_KEYS)
+    assert meter.since(mark)["compiled"] + meter.since(mark)["cache_loads"] == 0
+    assert ttracing.compile_totals() == before
+
+
+def test_an_event_with_no_span_open_is_unattributed_and_threads_do_not_share(ring):
+    name = "/jax/core/compile/backend_compile_duration"
+    before = ttracing.compile_totals()
+    ttracing._on_event(name, 0.25)  # nothing open on this thread
+    ttracing._on_event("/jax/compilation_cache/cache_hits")
+    ttracing._on_event("/jax/compilation_cache/cache_retrieval_time_sec", 0.125)
+    ttracing._on_event("/jax/some/other/event", 9.0)  # not a compile event
+    after = ttracing.compile_totals()
+    assert after["attributed"] == before["attributed"]
+    assert after["unattributed"]["backend_compiles"] == before["unattributed"]["backend_compiles"] + 1
+    assert after["unattributed"]["compile_s"] == pytest.approx(before["unattributed"]["compile_s"] + 0.25)
+    assert after["unattributed"]["cache_hits"] == before["unattributed"]["cache_hits"] + 1
+    assert after["unattributed"]["cache_load_s"] == pytest.approx(
+        before["unattributed"]["cache_load_s"] + 0.125)
+    fired = threading.Event()
+
+    def sibling():
+        ttracing._on_event(name, 0.5)  # this thread has no span open
+        fired.set()
+
+    with ttracing.span("main_outer") as outer:
+        thread = threading.Thread(target=sibling)
+        thread.start()
+        assert fired.wait(10)
+        thread.join(10)
+        with ttracing.span("main_inner"):
+            ttracing._on_event(name, 1.0)
+        ttracing._on_event("/jax/core/compile/jaxpr_to_mlir_module_duration", 2.0)
+    (inner,) = ttracing.spans("main_inner")
+    assert inner[4]["backend_compiles"] == 1 and inner[4]["compile_s"] == 1.0
+    assert outer.args == {"lowerings": 1, "lower_s": 2.0}  # self counts: not the child's
+    end = ttracing.compile_totals()
+    assert end["unattributed"]["backend_compiles"] == after["unattributed"]["backend_compiles"] + 1
+    assert end["attributed"]["backend_compiles"] == after["attributed"]["backend_compiles"] + 1
+    ttracing.set_enabled(False)  # switched off later: each listener returns at its first branch
+    ttracing._on_event(name, 1.0)
+    ttracing._on_event("/jax/compilation_cache/cache_hits")
+    assert ttracing.compile_totals() == end
+
+
+def test_compile_totals_lose_no_event_under_threads(ring):
+    """More threads than cores firing events, each under a span of its own:
+    every span holds its thread's events and the totals hold all of them."""
+    threads, each = 16, 500
+    before = ttracing.compile_totals()["attributed"]["lowerings"]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+
+    def fire(i):
+        with ttracing.span(f"stress{i}"):
+            for _ in range(each):
+                ttracing._on_event("/jax/core/compile/jaxpr_to_mlir_module_duration", 1e-3)
+
+    try:
+        workers = [threading.Thread(target=fire, args=(i,)) for i in range(threads)]
+        for w in workers:
+            w.start()
+        for w in workers:
+            w.join(60)
+        assert not any(w.is_alive() for w in workers)
+    finally:
+        sys.setswitchinterval(old)
+    for i in range(threads):
+        (found,) = ttracing.spans(f"stress{i}")
+        assert found[4]["lowerings"] == each
+    assert ttracing.compile_totals()["attributed"]["lowerings"] == before + threads * each
+
+
+def test_a_process_that_starts_with_tracing_off_registers_no_listener():
+    code = (
+        "from jax._src import monitoring as m\n"
+        "n = (len(m.get_event_listeners()), len(m.get_event_duration_listeners()))\n"
+        "from rustpde_mpi_tpu.telemetry import tracing\n"
+        "print(len(m.get_event_listeners()) - n[0], len(m.get_event_duration_listeners()) - n[1],"
+        " tracing.enabled())\n"
+    )
+    out = {}
+    for value in ("0", "1"):
+        env = dict(os.environ, RUSTPDE_TRACE=value, JAX_PLATFORMS="cpu")
+        out[value] = subprocess.run([sys.executable, "-c", code], env=env, text=True,
+                                    capture_output=True, check=True).stdout.split()
+    assert out == {"0": ["0", "0", "False"], "1": ["1", "1", "True"]}
+
+
+@pytest.mark.parametrize("seam", ["model", "ensemble", "registry"])
+def test_compile_log_observes_the_spans_own_duration(ring, monkeypatch, seam):
+    """One timing per seam: what ``/metrics``' histograms are handed is the
+    duration the ring holds."""
+    seen = []
+    monkeypatch.setattr(compile_log, "observe_entry_compile",
+                        lambda kind, wall_s: seen.append((kind, wall_s)))
+    sound = compile_log.observe_build
+    monkeypatch.setattr(compile_log, "observe_build",
+                        lambda key, wall_s, **kw: seen.append(("build", wall_s)) or sound(key, wall_s, **kw))
+    if seam == "registry":
+        from rustpde_mpi_tpu.workloads.registry import build_model_for_key
+
+        build_model_for_key(_model().compat_key)
+        name, kind = "registry.build_model", "build"
+    elif seam == "model":
+        _model()
+        name, kind = "model.compile_entry_points", "dns"
+    else:
+        NavierEnsemble.from_seeds(_model(), seeds=[1, 2], amp=0.1)
+        name, kind = "ensemble.compile_entry_points", "ensemble:dns"
+    (_, dur_ns, *_), = ttracing.spans(name)[-1:]
+    walls = [wall for k, wall in seen if k == kind]
+    assert walls[-1] == pytest.approx(dur_ns * 1e-9, abs=1e-9)
+    # with the recorder off the seam still hands the histogram a duration
+    ttracing.set_enabled(False)
+    seen.clear()
+    _model()
+    assert seen and seen[-1][1] > 0 and ttracing.timed("x") is not ttracing._NULL_SPAN
+
+
+SETUP_READERS = ("operator_build_s", "eager_programs", "entry_trace_s", "first_dispatch_s",
+                 "cache_load_s")
+
+
+def test_setup_readers_read_the_spans_before_the_first_traced_dispatch(ring):
+    """The five readers on a real set-up: a build, initial values, a warm-up
+    that compiles, an interval that only runs, then two "traced" dispatches.
+    What they read is the set-up's spans, not the last traced ones."""
+    mods = {r: importlib.import_module(f"benchmark.layer_metrics.{r}") for r in SETUP_READERS}
+    run = {"traced_dispatches": 2}
+    assert all(mod.read({}, run) is None for mod in mods.values())  # an empty ring
+    m = _model()
+    for _ in range(2):
+        m.update_n(8)
+        m.get_observables()
+    setup = _events(ring)
+    for _ in range(2):  # the traced ones
+        m.update_n(8)
+        m.get_observables()
+    got = {r: mod.read({}, run) for r, mod in mods.items()}
+    launches = [ev for ev in setup if ev["name"] in ("model.launch", "model.observe_launch")]
+    assert len(launches) == 4
+    by_name = lambda n: [ev for ev in setup if ev["name"] == n]  # noqa: E731
+    assert got["operator_build_s"] == pytest.approx(
+        1e-6 * sum(ev["dur"] for ev in by_name("space.build") + by_name("solver.build")))
+    assert got["entry_trace_s"] == pytest.approx(
+        1e-6 * by_name("model.compile_entry_points")[0]["dur"])
+    assert got["first_dispatch_s"] == pytest.approx(1e-6 * sum(
+        ev["dur"] for ev in launches if ev["args"].get("lowerings")))
+    assert sum(1 for ev in launches if ev["args"].get("lowerings")) == 2
+    eager = sum(ev["args"].get("backend_compiles", 0) for ev in setup if ev not in launches)
+    assert got["eager_programs"] == eager  # 0 once an earlier test ran the same small programs
+    assert got["cache_load_s"] == pytest.approx(
+        sum(ev["args"].get("cache_load_s", 0.0) for ev in setup))
+    assert isinstance(got["cache_load_s"], float)
+    # a traced dispatch that compiled (it must not) would not be counted as set-up
+    assert mods["first_dispatch_s"].read({}, {"traced_dispatches": 4}) < got["first_dispatch_s"]
+    assert mods["eager_programs"].read({}, {"traced_dispatches": 5}) is None  # four dispatches
+    ttracing.set_enabled(False)
+    assert all(mod.read({}, run) is None for mod in mods.values())  # the recorder is off
+
+
+def test_setup_readers_read_nothing_without_build_spans_or_from_a_full_ring(ring, monkeypatch):
+    mod = importlib.import_module("benchmark.layer_metrics.eager_programs")
+    m = _model()
+    m.update_n(8), m.update_n(8)
+    run = {"traced_dispatches": 1}
+    assert mod.read({}, run) is not None
+    monkeypatch.setattr(ring, "capacity", len(ring.events()))  # as if the head were gone
+    assert mod.read({}, run) is None
+    monkeypatch.undo()
+
+
+# -- (h) the yardstick's own checks ----------------------------------------------
 
 
 def test_benchmark_selfcheck_passes(capsys):
     from benchmark import selfcheck
 
     assert selfcheck.main([]) == 0
-    assert "17 readers agree" in capsys.readouterr().out
+    assert "22 readers agree" in capsys.readouterr().out
