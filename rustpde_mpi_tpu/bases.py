@@ -989,28 +989,84 @@ class Space2:
             )
         return constrain(out, self.rest)
 
-    def _synthesis(self, name: str, vhat):
-        """``name`` (a base's ``backward`` or ``backward_ortho``) axis by
-        axis, each where it is local."""
+    # A synthesis in two steps: the first axis of ``synthesis_axes`` alone,
+    # then a finish along the second.  The four public syntheses below are
+    # compositions of the two, so an axis is synthesised in one place
+    # (``_synthesise``); a caller that needs two syntheses of one array that
+    # differ only along the second axis (a velocity and the derivative of its
+    # own convection chain, models/navier.py) takes the first step once and
+    # finishes it twice: one product, and under a mesh one flip, for both.
+
+    def _synthesise(self, out, axis: int, order, fast: bool, ortho: bool):
+        """One axis of a synthesis, on an array in which ``axis`` is local.
+        ``ortho``: from orthogonal coefficients (the base's
+        ``backward_ortho``).  ``order`` None: the base's plain ``backward``
+        of composite coefficients; an int: the synthesis of that derivative,
+        ONE synthesis-of-derivative GEMM on a sep axis (key
+        ("bwd_grad", order); order 0 is the plain fused backward), gradient
+        then synthesis on any other (e.g. the split-Fourier axis of a periodic
+        space).  ``fast`` selects the 3-pass variants of the sep keys (DNS
+        convection path only, see Base._sep_dev); a plain synthesis takes
+        them only where both axes are sep, and is the exact ``backward`` off
+        it."""
+        b = self.bases[axis]
+        a = self._batch_ax(out) + axis
+        method = self._axis_method(axis)
+        if ortho:
+            return b.backward_ortho(out, a, method, sep=self.sep[axis])
+        if order is None and not (fast and all(self.sep)):
+            return b.backward(out, a, method, sep=self.sep[axis])
+        if self.sep[axis]:
+            key = ("bwd_grad", order) if order else "bwd"
+            if fast:
+                key = (key, "fast") if isinstance(key, str) else key + ("fast",)
+            return b._sep_dev(key).apply(out, a)
+        return b.backward_ortho(b.gradient(out, order, a, sep=False), a, method)
+
+    def synthesis_first(self, vhat, deriv=None, fast=False, ortho=False):
+        """The first step of a synthesis: the first axis of
+        ``synthesis_axes`` alone, where it is local, handed on in the pencil
+        of the second (under a mesh the flip is here, so whoever shares the
+        partial shares the flip).  ``deriv``: the derivative orders of
+        ``backward_gradient``, of which this step takes its own axis's (None:
+        the plain ``backward``); ``fast`` and ``ortho`` as in
+        ``_synthesise``."""
         from .parallel.mesh import LOCAL, constrain
 
-        ax = self._batch_ax(vhat)
-        out = vhat
-        for axis in self.synthesis_axes:
-            out = getattr(self.bases[axis], name)(
-                constrain(out, LOCAL[axis]), ax + axis, self._axis_method(axis),
-                sep=self.sep[axis],
-            )
-        return constrain(out, self.physical)
+        first, second = self.synthesis_axes
+        out = self._synthesise(
+            constrain(vhat, LOCAL[first]), first,
+            None if deriv is None else deriv[first], fast, ortho,
+        )
+        return constrain(out, LOCAL[second])
+
+    def synthesis_finish(self, partial, deriv=None, scale=None, fast=False, ortho=False):
+        """The second step: derivative and synthesis along the second axis of
+        ``synthesis_axes`` on a ``synthesis_first`` partial, the division by
+        scale^deriv, and the ``physical`` pin.  ``deriv`` names both orders
+        (the first axis's is the one the partial was taken with); one partial
+        may be finished with several orders along the second axis."""
+        from .parallel.mesh import constrain
+
+        second = self.synthesis_axes[1]
+        out = self._synthesise(
+            partial, second, None if deriv is None else deriv[second], fast, ortho
+        )
+        out = constrain(out, self.physical)
+        if scale is not None and deriv is not None:
+            factor = (scale[0] ** deriv[0]) * (scale[1] ** deriv[1])
+            if factor != 1.0:
+                out = out / factor
+        return out
 
     def backward(self, vhat):
         """Spectral (..., m_x, m_y) -> physical (..., n_x, n_y)."""
-        return self._synthesis("backward", vhat)
+        return self.synthesis_finish(self.synthesis_first(vhat))
 
     def backward_ortho(self, c):
         """Physical values from orthogonal-space coefficients (the space the
         reference's scratch ``field`` provides, /root/reference/src/navier_stokes/navier.rs:256)."""
-        return self._synthesis("backward_ortho", c)
+        return self.synthesis_finish(self.synthesis_first(c, ortho=True), ortho=True)
 
     def forward_dealiased(self, v, fast: bool = False):
         """Physical -> spectral with the 2/3-rule mask applied, in one fused
@@ -1046,46 +1102,20 @@ class Space2:
     def backward_gradient(self, vhat, deriv, scale=None, fast=False):
         """Physical values of d^deriv[0]/dx d^deriv[1]/dy — the fused
         ``backward_ortho(gradient(...))``: each sep axis is ONE
-        synthesis-of-derivative GEMM (key ("bwd_grad", order); order 0 is the
-        plain fused backward), saving the separate gradient apply.  Non-sep
-        axes (e.g. the split-Fourier axis of a periodic space) run
-        gradient-then-synthesis on that axis, so mixed spaces still fuse
-        their Chebyshev axis, and under a mesh each axis's derivative runs
-        with its synthesis, where that axis is local (the odd x-derivative of
-        a split base too).  ``fast=True`` selects the 3-pass
+        synthesis-of-derivative GEMM, saving the separate gradient apply;
+        non-sep axes run gradient-then-synthesis on that axis, so mixed
+        spaces still fuse their Chebyshev axis, and under a mesh each axis's
+        derivative runs with its synthesis, where that axis is local (the odd
+        x-derivative of a split base too).  ``fast=True`` selects the 3-pass
         synthesis variants (DNS convection path only — see Base._sep_dev)."""
-        from .parallel.mesh import LOCAL, constrain
-
-        ax = self._batch_ax(vhat)
-        out = vhat
-        for axis in self.synthesis_axes:
-            b = self.bases[axis]
-            a = ax + axis
-            # the pencil in which this axis is local: the resting one for the
-            # first axis, after the flip for the second, as in
-            # backward()/backward_ortho()
-            out = constrain(out, LOCAL[axis])
-            if self.sep[axis]:
-                key = ("bwd_grad", deriv[axis]) if deriv[axis] else "bwd"
-                if fast:
-                    key = (key, "fast") if isinstance(key, str) else key + ("fast",)
-                out = b._sep_dev(key).apply(out, a)
-            else:
-                out = b.gradient(out, deriv[axis], a, sep=False)
-                out = b.backward_ortho(out, a, self._axis_method(axis))
-        out = constrain(out, self.physical)
-        if scale is not None:
-            factor = (scale[0] ** deriv[0]) * (scale[1] ** deriv[1])
-            if factor != 1.0:
-                out = out / factor
-        return out
+        return self.synthesis_finish(
+            self.synthesis_first(vhat, deriv, fast), deriv, scale, fast
+        )
 
     def backward_fast(self, vhat):
         """``backward`` via the fast synthesis variants (DNS convection
-        velocities only); falls back to the exact backward off-sep."""
-        if not all(self.sep):
-            return self.backward(vhat)
-        return self.backward_gradient(vhat, (0, 0), None, fast=True)
+        velocities only); the exact backward off-sep (``_synthesise``)."""
+        return self.synthesis_finish(self.synthesis_first(vhat, fast=True), fast=True)
 
     def to_ortho(self, vhat):
         ax = self._batch_ax(vhat)
@@ -1107,6 +1137,13 @@ class Space2:
     # propagation, GSPMD runs the parity interleave or the half swap of such
     # an operator ALONG the sharded axis: every device scatters its rows into
     # a zero field and the fields are summed, a whole-field all-reduce each.
+    # These operators map rest to rest and flip in pairs.  A synthesis leaves
+    # rest for good and flips once, inside ``synthesis_first``: its partial
+    # (first axis done, second still spectral) lives in the pencil of the
+    # second axis, so two syntheses of one array that differ only along the
+    # second axis are two ``synthesis_finish`` of one partial and pay one flip
+    # between them (a velocity and its own chain's derivative: 15 flips a
+    # periodic step where each stating its own made 17).
 
     def _pin(self, a, spec):
         """``constrain`` for the spectral operators below, unless the model

@@ -174,6 +174,10 @@ class CampaignModelBase:
     # statistics failures surface as typed journal events
     # (models/stats.report_stats_event) instead of swallowed prints
     journal_writer = None
+    #: first-axis syntheses of one step that two consumers are finished from
+    #: (``Navier2D._make_step`` sets it: a velocity's plain synthesis and its
+    #: own chain's derivative); a step that shares none says 0 on its spans
+    _shared_syntheses = 0
 
     # -- construction-time bookkeeping ---------------------------------------
 
@@ -392,12 +396,14 @@ class CampaignModelBase:
                 # the ``dot_general``s of one step's traced program by operand
                 # type, counted once per pass for the ``update_n`` spans (the
                 # ensemble's too): which arithmetic the step's products were
-                # compiled in, and how many array flips its parity folds brought
+                # compiled in, how many array flips its parity folds brought,
+                # and how many first-axis syntheses served two consumers
                 products = dot_generals_by_operand(self._step_cc.jaxpr)
                 self._step_products = {
                     "f64_products": products.get("float64", 0),
                     "f32_products": products.get("float32", 0),
                     "reverses": reverses(self._step_cc.jaxpr),
+                    "shared_syntheses": self._shared_syntheses,
                 }
                 # the scanned chunks' constants, counted once per pass for the
                 # span's ``unplaced_args`` (:meth:`_mesh_span_args`)

@@ -859,17 +859,33 @@ class Navier2D(CampaignModelBase, Integrate):
         manual_synth = getattr(self, "_manual_synth", None)
         manual_poisson = getattr(self, "_manual_poisson", None)
 
+        # A velocity is synthesised along the first axis of ``synthesis_axes``
+        # once: ``ux``/``uy`` and the derivative synthesis of the velocity's
+        # own chain along the second axis are finished from that one partial
+        # (Space2.synthesis_first / synthesis_finish), which under a mesh
+        # carries the pencil flip with it.  The fused chain (``conv_impl``)
+        # and the hand-partitioned synthesis (``manual_synth``) take their
+        # inputs whole and share nothing.
+        self._shared_syntheses = 2 if conv_impl is None and manual_synth is None else 0
+
         @stage("convection")  # named under each caller's stage
-        def conv(ux, uy, space, vhat, with_bc=False):
+        def conv(ux, uy, space, vhat, with_bc=False, partial=None):
             """u . grad(v), dealiased, in scratch-ortho space
             (/root/reference/src/navier_stokes/functions.rs:56-69 +
-            navier_eq.rs:60-101).
+            navier_eq.rs:60-101).  ``partial``: the first-axis synthesis of
+            ``vhat`` that the caller already took for the plain synthesis of
+            the same field (``velx`` for ``ux``, ``vely`` for ``uy``; the two
+            share one space object, so it is handed in by field): the
+            derivative along the second axis of ``synthesis_axes`` is
+            finished from it, and only the other one is synthesised whole.
 
             Deliberately per-field, NOT stacked: batching the two derivative
             syntheses into one (2, n, n) transform was measured 18% SLOWER
             for the whole step at 1025^2 f32 (4.01 vs 3.41 ms) — inside one
             compiled program the extra stack/unstack HBM copies and the
-            batched dot_generals cost more than the saved op count."""
+            batched dot_generals cost more than the saved op count.  Sharing
+            a partial stacks nothing: one product fewer, the others as they
+            were."""
             if conv_impl is not None:
                 # the whole chain as one fused region: the Pallas VMEM
                 # kernel (physical intermediates never touch HBM, dealias
@@ -883,8 +899,14 @@ class Navier2D(CampaignModelBase, Integrate):
             # fused synthesis-of-derivative: one GEMM per axis on sep spaces
             # (Space2.backward_gradient == backward_ortho(gradient(.)));
             # fast=True: 3-pass synthesis for the dealiased products
-            dvdx = space.backward_gradient(vhat, (1, 0), scale, fast=True)
-            dvdy = space.backward_gradient(vhat, (0, 1), scale, fast=True)
+            second = space.synthesis_axes[1]
+
+            def grad(deriv):
+                if partial is not None and deriv[second]:
+                    return space.synthesis_finish(partial, deriv, scale, fast=True)
+                return space.backward_gradient(vhat, deriv, scale, fast=True)
+
+            dvdx, dvdy = grad((1, 0)), grad((0, 1))
             total = ux * dvdx + uy * dvdy
             if with_bc:
                 total = total + ux * tb_dx + uy * tb_dy
@@ -923,13 +945,21 @@ class Navier2D(CampaignModelBase, Integrate):
             # 3-pass synthesis — feeds only the dealiased products); the
             # manual split-sep path runs these through their own shard_map
             # region (decomp.ShardedSynthesis)
+            part_u = part_v = None
             with stage("synthesis"):
                 if manual_synth is not None:
                     ux = manual_synth[id(sp_u)].apply(velx)
                     uy = manual_synth[id(sp_v)].apply(vely)
                 else:
-                    ux = sp_u.backward_fast(velx)
-                    uy = sp_v.backward_fast(vely)
+                    # backward_fast in its two steps; the partials go on to
+                    # the velocities' own chains.  The shared product runs as
+                    # the plain synthesis asks (the same fast key as the
+                    # chain's on a confined space, the exact backward
+                    # elsewhere): never below what either consumer had
+                    part_u = sp_u.synthesis_first(velx, fast=True)
+                    part_v = sp_v.synthesis_first(vely, fast=True)
+                    ux = sp_u.synthesis_finish(part_u, fast=True)
+                    uy = sp_v.synthesis_finish(part_v, fast=True)
 
             if with_sentinels:
                 # sentinels of the consumed state, from the velocities the
@@ -951,11 +981,11 @@ class Navier2D(CampaignModelBase, Integrate):
                 # precision themselves, so no solve_scope here.  Mesh-free
                 # by construction (_build_step_kernels), hence no pins.
                 with stage("momentum_x"):
-                    cx = conv(ux, uy, sp_u, velx)
+                    cx = conv(ux, uy, sp_u, velx, partial=part_u)
                     args = (velx, pres, cx) + ((vely,) if coriolis else ())
                     velx_n = step_impl["velx"].apply(*args)
                 with stage("momentum_y"):
-                    cy = conv(ux, uy, sp_v, vely)
+                    cy = conv(ux, uy, sp_v, vely, partial=part_v)
                     args = (vely, pres, temp, cy) + ((velx,) if coriolis else ())
                     vely_n = step_impl["vely"].apply(*args)
                 with stage("divergence"):
@@ -979,7 +1009,7 @@ class Navier2D(CampaignModelBase, Integrate):
                 with stage("momentum_x"):
                     rhs = sp_u.to_ortho(velx)
                     rhs = rhs - dt * sp_p.gradient(pres, (1, 0), scale)
-                    rhs = rhs - dt * conv(ux, uy, sp_u, velx)
+                    rhs = rhs - dt * conv(ux, uy, sp_u, velx, partial=part_u)
                     if coriolis:
                         # rotating-frame f-plane term +f*v (velx/vely share one
                         # space, so the cross-coupling is a plain ortho-space
@@ -996,7 +1026,7 @@ class Navier2D(CampaignModelBase, Integrate):
                     rhs = sp_v.to_ortho(vely)
                     rhs = rhs - dt * sp_p.gradient(pres, (0, 1), scale)
                     rhs = rhs + dt * that
-                    rhs = rhs - dt * conv(ux, uy, sp_v, vely)
+                    rhs = rhs - dt * conv(ux, uy, sp_v, vely, partial=part_v)
                     if coriolis:
                         rhs = rhs - dt * coriolis * sp_u.to_ortho(velx)
                     with solve_scope():

@@ -151,6 +151,95 @@ def test_gradient_into_a_space_is_its_from_ortho(layout):
         np.testing.assert_allclose(got, want, atol=1e-12 * max(1.0, np.abs(want).max()))
 
 
+def _two_step_space(layout: str):
+    """The three layouts a chip runs: confined (both axes sep), Fourier x
+    Chebyshev (split Re/Im along x, no sep axis) and the mixed one (split
+    along x, sep along y)."""
+    if layout == "confined":
+        return rp.Space2(rp.cheb_dirichlet(17), rp.cheb_neumann(16), method="matmul", sep=True)
+    return rp.Space2(
+        rp.fourier_r2c_split(16), rp.cheb_dirichlet(17), method="matmul", sep=layout == "mixed_sep"
+    )
+
+
+def _axis_by_axis(space, vhat, how, order=0):
+    """The synthesis from the one-axis operators of the two bases, x then y
+    (they commute): ``how`` names a base's ``backward`` or ``backward_ortho``,
+    and a derivative is taken axis by axis before an ortho synthesis."""
+    out = vhat
+    for axis, base in enumerate(space.bases):
+        a = out.ndim - 2 + axis
+        if how == "gradient":
+            out = base.gradient(out, order[axis], a, sep=space.sep[axis])
+            out = base.backward_ortho(out, a, "matmul", sep=space.sep[axis])
+        else:
+            out = getattr(base, how)(out, a, "matmul", sep=space.sep[axis])
+    return np.asarray(out)
+
+
+@pytest.mark.parametrize("devices", [0, 4], ids=["one_device", "four_devices"])
+@pytest.mark.parametrize("batch", [False, True], ids=["plain", "batched"])
+@pytest.mark.parametrize("layout", ["confined", "periodic", "mixed_sep"])
+def test_two_step_synthesis_is_every_synthesis(layout, batch, devices):
+    """``synthesis_finish(synthesis_first(.))`` is ``backward``,
+    ``backward_ortho`` and ``backward_gradient`` (orders 0 and 1 on either
+    axis), each against the bases' one-axis operators; and ONE first-axis
+    partial finished with order 0 and with order 1 along the second axis is
+    the plain synthesis and that derivative's, which is what a velocity and
+    its own convection chain share (models/navier.py).  On four devices a
+    Fourier x Chebyshev space takes y first and flips once (x first on one
+    device and on a confined space), so both orders are gone through."""
+    import jax
+
+    from rustpde_mpi_tpu.parallel.mesh import make_mesh, use_mesh
+
+    space = _two_step_space(layout)
+    scale = (2.0, 0.5)
+    rng = np.random.default_rng(11)
+    shape = ((3,) if batch else ()) + space.shape_spectral
+    vhat = rng.standard_normal(shape)
+    ortho = np.asarray(space.to_ortho(vhat))  # n coefficients an axis, not m
+
+    def close(got, want):
+        np.testing.assert_allclose(
+            np.asarray(got), want, atol=1e-11 * max(1.0, np.abs(want).max())
+        )
+
+    with use_mesh(make_mesh(jax.devices()[:devices]) if devices else None):
+        y_first = bool(devices) and layout != "confined"
+        assert space.synthesis_axes == ((1, 0) if y_first else (0, 1))
+        second = space.synthesis_axes[1]
+
+        def two_step(deriv=None, scale=None, ortho=False, of=vhat):
+            return jax.jit(
+                lambda v: space.synthesis_finish(
+                    space.synthesis_first(v, deriv, ortho=ortho), deriv, scale, ortho=ortho
+                )
+            )(of)
+
+        close(two_step(), _axis_by_axis(space, vhat, "backward"))
+        close(two_step(), np.asarray(jax.jit(space.backward)(vhat)))
+        close(two_step(ortho=True, of=ortho), _axis_by_axis(space, ortho, "backward_ortho"))
+        close(two_step(ortho=True, of=ortho), np.asarray(jax.jit(space.backward_ortho)(ortho)))
+        for deriv in [(0, 0), (1, 0), (0, 1)]:
+            want = _axis_by_axis(space, vhat, "gradient", deriv)
+            want = want / (scale[0] ** deriv[0] * scale[1] ** deriv[1])
+            close(two_step(deriv, scale), want)
+            close(jax.jit(lambda v, d=deriv: space.backward_gradient(v, d, scale))(vhat), want)
+
+        # one partial, two finishes
+        unit = (int(second == 0), int(second == 1))
+
+        @jax.jit
+        def shared(v):
+            partial = space.synthesis_first(v)
+            return space.synthesis_finish(partial), space.synthesis_finish(partial, unit, scale)
+
+        plain, derivative = shared(vhat)
+        close(plain, _axis_by_axis(space, vhat, "backward"))
+        close(derivative, _axis_by_axis(space, vhat, "gradient", unit) / scale[second])
+
+
 # ---------------------------------------------------------------------------
 # composite bases: boundary conditions + ortho casts
 # ---------------------------------------------------------------------------

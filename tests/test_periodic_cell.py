@@ -17,6 +17,7 @@
 import collections
 import contextlib
 import importlib.util
+import json
 import os
 import re
 import subprocess
@@ -187,9 +188,11 @@ def test_meshed_program_follows_the_unmeshed_one(monkeypatch):
 COLLECTIVE = re.compile(
     r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) "
     r"(all-reduce|all-to-all|all-gather|collective-permute|reduce-scatter)(?:-start)?\((.*)$", re.M)
-FLIPS = 17  # the hand count of test_meshed_chunk_sums_no_field_over_the_devices
-# a confined 17 x 17 chunk of 4 steps on four devices, as the tree before ISSUE 31 compiled it
-CONFINED_17 = {"all-to-all": 23, "all-gather": 39, "collective-permute": 44, "all-reduce": 3}
+FLIPS = 15  # the hand count of test_meshed_chunk_sums_no_field_over_the_devices
+# a confined 17 x 17 chunk of 4 steps on four devices, as the tree before ISSUE 31 compiled it,
+# less the two flips behind the x-synthesis of velx and of vely that ux / uy and the velocity's
+# own d/dy share since ISSUE 36 (23 all-to-alls before)
+CONFINED_17 = {"all-to-all": 21, "all-gather": 39, "collective-permute": 44, "all-reduce": 3}
 
 
 def chunk_text(model, n=4) -> str:
@@ -242,14 +245,18 @@ def test_meshed_chunk_sums_no_field_over_the_devices(monkeypatch, no_compile_cac
     the chunk moves half a spectral field or more (a tuple all-reduce is named
     by its first element, so every element is looked at; scalar reductions,
     the finite check among them, stay).  The flips are the hand count:
-    2 syntheses (y where it rests, one flip, x), 3 per convection chain (two
-    derivative syntheses out, the product back) x 3, and 2 round each of the
+    1 a velocity for ``ux`` / ``uy`` AND the d/dx synthesis of its own chain
+    (y where it rests, ONE flip, then x with and without the derivative:
+    ``Space2.synthesis_first``), 2 a velocity chain besides (the d/dy
+    synthesis out, the product back), 3 for the temperature's chain (two
+    derivative syntheses out, the product back), and 2 round each of the
     three odd x-derivatives of the split layout, which swaps the Re and Im
     halves of the x extent and is the one spectral operator that needs x
     whole (d/dx of the pressure, of the new velx in the divergence, of the
     pseudo-pressure in the projection); the three Helmholtz solves, the
-    Poisson solve, every d/dy, stencil and cast: 0.  2 + 9 + 6 = 17 (27
-    while the arrays rested as x-pencils).  The compiler may lower a flip of
+    Poisson solve, every d/dy, stencil and cast: 0.  2 + 4 + 3 + 6 = 15 (17
+    while a velocity's y-synthesis was stated twice, 27 while the arrays
+    rested as x-pencils).  The compiler may lower a flip of
     so small an array to an all-gather and a slice, never add one: an
     all-gather of half a field or more inside the step body counts as a flip
     (the lowering a sliced and concatenated sharded axis falls into would add
@@ -295,7 +302,7 @@ def test_the_chips_own_compiler_sums_no_field_either(grid):
     summed nothing.  At 32 x 33 it takes the three analysis flips as
     all-gathers folded into the products that follow, in steps that cannot be
     counted; from 256 x 257 up it makes the program it makes at the cell's
-    1024 x 1025 (17 all-to-alls in the step body, no all-gather there and no
+    1024 x 1025 (15 all-to-alls in the step body, no all-gather there and no
     collective-permute), so that size also holds: no all-gather of half a
     spectral field or more inside the loop.  In a process of its own, which
     ends with the compile: the TPU's library is loaded into no test worker,
@@ -346,6 +353,54 @@ def test_unmeshed_confined_chunk_states_no_layout(monkeypatch, no_compile_cache)
     assert "sharding=" not in text and not COLLECTIVE.findall(text)
 
 
+# What one traced step holds at the sizes from which ops/folded.py's gates make
+# the cells' own programs, in float32 as the cells run: in a process of its own,
+# because a process has one precision.  Nothing is compiled.
+TRACED_PRODUCTS = """
+import json
+import jax
+from rustpde_mpi_tpu import Navier2D
+from rustpde_mpi_tpu.parallel.mesh import make_mesh
+
+physics = (1e6, 1.0, 2e-3, 1.0, "rbc")
+out = {
+    "periodic_mesh4": Navier2D.new_periodic(256, 257, *physics, mesh=make_mesh(jax.devices()[:4])),
+    "periodic_solo": Navier2D.new_periodic(256, 257, *physics),
+    "confined_solo": Navier2D.new_confined(257, 257, *physics),
+}
+print(json.dumps({name: model._step_products for name, model in out.items()}))
+"""
+
+
+@pytest.fixture(scope="module")
+def traced_products():
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", RUSTPDE_FORCE_TPU_PATH="1", RUSTPDE_X64="0",
+               PYTHONPATH=repo, XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    env.pop("RUSTPDE_SEP", None)
+    done = subprocess.run([sys.executable, "-W", "ignore", "-c", TRACED_PRODUCTS], env=env,
+                          cwd=repo, capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-2000:]
+    return json.loads(done.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("model, products",
+                         [("periodic_mesh4", 66), ("periodic_solo", 66), ("confined_solo", 76)])
+def test_a_velocitys_first_axis_synthesis_is_traced_once(traced_products, model, products):
+    """``ux`` / ``uy`` and the derivative synthesis of the velocity's own
+    chain along the second axis of ``synthesis_axes`` are finished from ONE
+    first-axis partial (d/dx under a mesh, where y goes first; d/dy on one
+    device and on a confined space): the step states a parity-folded product,
+    two ``dot_general``s, less for each velocity than it did while both
+    consumers stated the product themselves (70, 70 and 80 in the tree before
+    ISSUE 36, where only the confined step's pair was merged, by the
+    compiler).  The span's ``shared_syntheses`` says how many partials have
+    two consumers."""
+    got = traced_products[model]
+    assert (got["f32_products"], got["f64_products"]) == (products, 0)
+    assert got["shared_syntheses"] == 2
+
+
 # -- the span's counters -----------------------------------------------------------
 
 
@@ -373,6 +428,7 @@ def test_span_counts_the_manual_exchanges(monkeypatch, ring):
     args = last_update_n(meshed(monkeypatch, 4, "manual"))
     itemsize = 8 if config.X64 else 4
     assert args["devices"] == 4
+    assert args["shared_syntheses"] == 0  # the hand-partitioned regions take their inputs whole
     assert args["transposes"] == 13
     assert args["exchange_bytes"] == 2643 * itemsize
     # neither 31, 33 composite nor 33 ortho columns divide by 4: every leaf whole
@@ -382,6 +438,7 @@ def test_span_counts_the_manual_exchanges(monkeypatch, ring):
 def test_span_counts_no_exchange_where_the_compiler_places_them(monkeypatch, ring):
     args = last_update_n(meshed(monkeypatch, 4, "normal"))
     assert (args["devices"], args["transposes"], args["exchange_bytes"]) == (4, 0, 0)
+    assert args["shared_syntheses"] == 2  # a partial a velocity, finished twice
     # the state rests as y-pencils: its 34 split rows (17 modes, Re and Im)
     # divide by 2 and not by 4, so on four devices every leaf is whole
     assert args["replicated_leaves"] == 5

@@ -193,8 +193,10 @@ def test_launches_counts_leaves_copied_and_buckets(ring, kind, n):
 def test_update_n_spans_count_the_parity_folds_reverses(ring, monkeypatch, fold_gate, folded, kind):
     """``reverses`` beside ``f32_products`` / ``f64_products``: the ``rev``
     equations of one traced step, the member step's on the ensemble's span.
-    On the matmul-transform path a confined step applies 22 transforms
-    (4 in ``synthesis``, 6 in each of the three convection chains): folded,
+    On the matmul-transform path a confined step applies 20 transforms
+    (4 in ``synthesis``, 6 in each of the three convection chains, less the
+    x-synthesis of ``velx`` and of ``vely``, which ``ux`` / ``uy`` and the
+    velocity's own d/dy share: ``shared_syntheses``): folded,
     each is two products and one reverse; below ops/folded.py's gates, where
     every small float32 grid stands, one plain product and none, and each
     dense checkerboard operator (24 at this size) one product for its two."""
@@ -206,8 +208,9 @@ def test_update_n_spans_count_the_parity_folds_reverses(ring, monkeypatch, fold_
         sim = NavierEnsemble.from_seeds(sim, seeds=[1, 2], amp=0.1)
     sim.update_n(2)
     args = ttracing.spans(f"{kind}.update_n")[-1][-1]
-    assert args["reverses"] == (22 if folded else 0)
-    assert args["f64_products"] + args["f32_products"] == (104 if folded else 104 - 22 - 24)
+    assert args["reverses"] == (20 if folded else 0)
+    assert args["f64_products"] + args["f32_products"] == (100 if folded else 100 - 20 - 24)
+    assert args["shared_syntheses"] == 2
 
 
 @pytest.mark.parametrize("kind, fresh", [("model", 7), ("ensemble", 5)])
