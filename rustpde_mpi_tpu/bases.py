@@ -1451,6 +1451,18 @@ class BiPeriodicSpace2:
         self.method = method
         self.kx = fou.wavenumbers_c2c(nx)
         self.ky = fou.wavenumbers_r2c(ny)
+        # ``space.build`` (operators and kernels).  The space owns its
+        # operators (no shared ``Base`` objects), so the matmul path's are
+        # built here, inside the span: a transform's first trace builds the
+        # device constants it applies (four-step plan or folded matrix)
+        with _tr.span(
+            "space.build", layer="operators and kernels",
+            shape=(nx, ny), bases=("fourier_c2c", "fourier_r2c"),
+        ):
+            if method == "matmul":
+                rdt = config.real_dtype()
+                jax.eval_shape(self.forward, jax.ShapeDtypeStruct(self.shape_physical, rdt))
+                jax.eval_shape(self.backward, jax.ShapeDtypeStruct(self.shape_spectral, rdt))
 
     # -- geometry -----------------------------------------------------------
 

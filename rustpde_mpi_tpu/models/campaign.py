@@ -34,9 +34,14 @@ chunk with divergence early-exit, the sentinel-armed
 variant, the deferred-commit pending chunk, the dt-rung cache, the cached
 observable future, exit/exit_future.  A model supplies the physics hooks:
 
-* ``_make_step(with_sentinels=False)`` — the pure step (with the optional
-  ``(cfl, ke, div)`` sentinel tuple),
-* ``_make_observables()`` — the fused per-state scalar diagnostics,
+* ``_make_step(with_sentinels=False)`` — the pure step, with the optional
+  sentinel triple: a rate the chunk holds under ``max_cfl``, an energy whose
+  growth it tracks and a residual norm, for the DNS ``(cfl, ke, |div|)``; a
+  model without one of them gives what stands in its slot (a scalar PDE with
+  nothing to advect: ``(0, energy, |mean|)``, models/swift_hohenberg.py),
+* ``_make_observables()`` — the fused per-state scalar diagnostics, four
+  floats named by ``observable_names`` (more may follow), the NaN detector
+  at index 3,
 * ``_state_example()`` — ShapeDtypeStructs of one state,
 * ``_scan_ok(state)`` — the in-scan continue criterion (default: temp is
   finite; the steady-state finder additionally stops on residual
@@ -46,6 +51,12 @@ observable future, exit/exit_future.  A model supplies the physics hooks:
 ``Navier2D`` inherits this base (its PR 1–4 behavior is unchanged — the
 code moved, the traced programs did not), and ``Navier2DLnse`` /
 ``Navier2DAdjoint`` ride the same machinery instead of hand-rolled loops.
+So do the Swift–Hohenberg models (models/swift_hohenberg.py), the first that
+are no variant of ``Navier2D``: one field, no velocity, no pressure, no mesh.
+What the base takes from a DNS it takes through a hook whose default is the
+DNS's (``_scan_ok``: the leaf ``temp``; ``_rest_layout``: ``temp_space``;
+``_build_span``: a 2-D grid and a mesh); the stats engine alone refuses
+another kind (models/stats.py).
 """
 
 from __future__ import annotations
@@ -282,7 +293,8 @@ class CampaignModelBase:
     @staticmethod
     def _build_span(nx: int, ny: int, mesh):
         """The span a model's ``__init__`` holds from its first line to its
-        last: ``model.build``."""
+        last: ``model.build``.  A 1-D model says ``ny=1``, one without a mesh
+        ``None``."""
         return _tr.span(
             "model.build", layer=_LAYER, nx=int(nx), ny=int(ny),
             dtype=np.dtype(config.real_dtype()).name,
@@ -301,13 +313,18 @@ class CampaignModelBase:
             return contextlib.nullcontext()
         return use_mesh(self.mesh)
 
+    def _rest_layout(self):
+        """The pencil layout a meshed model's spectral arrays rest in between
+        dispatches (``Space2.rest``; all of a model's spaces rest alike).  The
+        default reads the DNS's ``temp_space``; asked only under a mesh."""
+        return self.temp_space.rest
+
     def _place(self, arr):
         """Put a spectral array into the pencil layout its space rests in
-        under the mesh (``Space2.rest``; all of a model's spaces rest
-        alike)."""
+        under the mesh (:meth:`_rest_layout`)."""
         from ..parallel.mesh import device_put
 
-        return device_put(arr, self.temp_space.rest)
+        return device_put(arr, self._rest_layout())
 
     def _hand_back(self, state):
         """A chunk program's state as it leaves the program: every leaf laid
@@ -320,7 +337,7 @@ class CampaignModelBase:
 
         if getattr(self, "mesh", None) is None:
             return state
-        rest = self.temp_space.rest
+        rest = self._rest_layout()
         return jax.tree.map(lambda leaf: settle(leaf, rest), state)
 
     def _hoist(self, fn, *example):
@@ -386,7 +403,7 @@ class CampaignModelBase:
         ROADMAP item needs that attribution separated from build time."""
         from ..parallel.mesh import unplaced
         from ..telemetry import compile_log
-        from ..utils.jit import dot_generals_by_operand, reverses
+        from ..utils.jit import dot_generals_by_operand, gathers, reverses
 
         seam = _tr.timed("model.compile_entry_points", layer=_LAYER, consts=0, const_bytes=0)
         try:
@@ -397,12 +414,14 @@ class CampaignModelBase:
                 # type, counted once per pass for the ``update_n`` spans (the
                 # ensemble's too): which arithmetic the step's products were
                 # compiled in, how many array flips its parity folds brought,
-                # and how many first-axis syntheses served two consumers
+                # how many index gathers (the circular folds of the periodic
+                # axes), and how many first-axis syntheses served two consumers
                 products = dot_generals_by_operand(self._step_cc.jaxpr)
                 self._step_products = {
                     "f64_products": products.get("float64", 0),
                     "f32_products": products.get("float32", 0),
                     "reverses": reverses(self._step_cc.jaxpr),
+                    "gathers": gathers(self._step_cc.jaxpr),
                     "shared_syntheses": self._shared_syntheses,
                 }
                 # the scanned chunks' constants, counted once per pass for the
@@ -1177,9 +1196,11 @@ class CampaignModelBase:
             self._obs_cache = (self.state, fut)
         return self._obs_cache[1]
 
-    def get_observables(self) -> tuple[float, float, float, float]:
-        """The four per-model scalars (:attr:`observable_names`) — one fused
-        device dispatch, cached per state, fetched in ONE host transfer."""
+    def get_observables(self) -> tuple:
+        """The model's scalars as floats, in the order of
+        :attr:`observable_names` (four, the NaN detector at index 3; a
+        scenario may append more) — one fused device dispatch, cached per
+        state, fetched in ONE host transfer."""
         with _tr.span("model.observe", layer=_LAYER) as sp:
             before = self._obs_cache
             fut = self.get_observables_async()

@@ -20,9 +20,28 @@ Reference-parity details kept: the 1-D model dealiases the cubic term and
 does not pin the mean mode; the 2-D model pins the (0,0) mode and enforces
 Hermitian symmetry of the ky=0 column each step (without which the implicit
 update drifts unstable — swift_hohenberg_2d.rs enforce_hermitian_symmetry).
+
+Both are campaign models (models/campaign.py): a one-leaf ``state`` (the leaf
+is ``temp``, as the source's snapshot group is ``temp/``), the step and the
+observables hoisted through ``CampaignModelBase._compile_entry_points``, and
+``update_n`` / ``set_dt`` / the snapshot surface from the base.  What a scalar
+PDE with one field, no velocity and no pressure gives where the base was grown
+on a DNS:
+
+* observables ``(norm, energy, amp, mean)``: the source's ``|F|``, the domain
+  mean of theta^2, max |theta| and |(0) mode| (which the 2-D pin holds at 0);
+  ``mean`` is the NaN detector (index 3), a NaN anywhere in the state shows in it;
+* stability sentinels ``(0, energy, mean)`` in the base's ``(cfl, ke, div)``
+  slots: nothing advects, so no rate can pass ``max_cfl`` and a sentinel chunk
+  stops on a non-finite state only; the energy's growth and the mean are
+  tracked as the DNS's kinetic energy and divergence are;
+* no mesh (``BiPeriodicSpace2`` has no pencil form), no scenario, and the
+  statistics engine refuses it (models/stats.py reads DNS fields).
 """
 
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +49,9 @@ import numpy as np
 
 from .. import config
 from ..bases import BiPeriodicSpace2, Space1, fourier_r2c
+from ..telemetry import tracing as _tr
 from ..utils.integrate import Integrate
+from .campaign import _LAYER, CampaignModelBase
 
 
 def _h5():
@@ -39,63 +60,148 @@ def _h5():
     return h5py
 
 
-class _SwiftHohenbergBase(Integrate):
-    """Shared driver plumbing (time bookkeeping, scanned update_n, IO)."""
+class SwiftState(NamedTuple):
+    """theta's spectral coefficients (the source's snapshot group ``temp/``)."""
 
-    def __init__(self, r: float, dt: float):
-        self.r = r
-        self.dt = dt
-        self.time = 0.0
+    temp: jax.Array
+
+
+class _SwiftHohenbergBase(CampaignModelBase, Integrate):
+    """What the two models share: the IMEX step, the observables, the dt
+    artifact (the diagonal implicit operator) and the source's snapshot IO.
+    A subclass names its space, its wavenumbers and what it does to the cubic
+    term's spectrum and to the new state."""
+
+    MODEL_KIND = "swift"
+    observable_names = ("norm", "energy", "amp", "mean")
+    _DT_ARTIFACTS = ("_matl",) + CampaignModelBase._DT_ARTIFACTS
+    mesh = None
+
+    def _setup(self, space, shape, r: float, dt: float, length: float) -> None:
+        self.space = space
+        self.nx, self.ny = shape
+        self.r, self.dt, self.length = float(r), float(dt), float(length)
+        self.params = {"r": self.r, "length": self.length}
+        self.scale = (self.length,) * len(space.coords())
+        self.x = [p * self.length for p in space.coords()]
         self.write_intervall: float | None = None
+        self._init_campaign()
+        # the model's own writes and reads of the physical field (set_theta,
+        # theta_physical), one program each and not a launch per operator
+        self._to_spectral = jax.jit(space.forward)
+        self._to_physical = jax.jit(space.backward)
+        self._matl = self._build_matl()
+        self.state = SwiftState(space.ndarray_spectral())
+        self._compile_entry_points()
 
-    def _compile(self):
-        from ..utils.jit import hoist_constants
+    # -- per subclass ---------------------------------------------------------
 
-        step = self._make_step()
-        converted, consts = hoist_constants(step, self.theta)
-        self._consts = consts
+    def _k2(self) -> np.ndarray:
+        """|k|^2 over the spectral shape (host)."""
+        raise NotImplementedError
 
-        @jax.jit
-        def step_1(consts, theta):
-            return converted(consts, theta)
+    def _analysis(self):
+        """Physical cubic term -> its spectrum (the 1-D model dealiases)."""
+        return self.space.forward
 
-        from functools import partial
+    def _symmetry(self):
+        """What the step does to the new spectrum last (the 2-D model's pin
+        and Hermitian projection)."""
+        return lambda theta: theta
 
-        @partial(jax.jit, static_argnums=2)
-        def step_n(consts, theta, n):
-            return jax.lax.scan(
-                lambda th, _: (converted(consts, th), None), theta, None, length=n
-            )[0]
+    def _mean_mode(self, theta):
+        """|c_0|, the modulus of the constant mode."""
+        raise NotImplementedError
 
-        self._step_1 = lambda th: step_1(self._consts, th)
-        self._step_n = lambda th, n: step_n(self._consts, th, n)
+    # -- the state as the examples and tests read it ---------------------------
 
-    def update(self) -> None:
-        self.theta = self._step_1(self.theta)
-        self.time += self.dt
+    @property
+    def theta(self):
+        return self.state.temp
 
-    def update_n(self, n: int) -> None:
-        from ..utils.jit import run_scanned
+    @theta.setter
+    def theta(self, value) -> None:
+        self.state = SwiftState(value)
 
-        self.theta = run_scanned(self._step_n, self.theta, n)
-        self.time += n * self.dt
+    def set_theta(self, values: np.ndarray) -> None:
+        with _tr.span("model.set_field", layer=_LAYER, fields=("temp",)):
+            self.theta = self._to_spectral(jnp.asarray(values, dtype=config.real_dtype()))
 
-    def get_time(self) -> float:
-        return self.time
+    def theta_physical(self) -> np.ndarray:
+        with _tr.span("model.get_field", layer=_LAYER, fields=("temp",)):
+            return np.asarray(self._to_physical(self.theta))
 
-    def get_dt(self) -> float:
-        return self.dt
+    # -- physics hooks (models/campaign.py) -------------------------------------
+
+    def _build_matl(self):
+        matl = 1.0 + self.dt * ((1.0 - self._k2()) ** 2 - self.r)
+        return jnp.asarray(matl, dtype=config.real_dtype())
+
+    def _rebuild_dt_artifacts(self) -> None:
+        self._matl = self._build_matl()
+        self._compile_entry_points()
+
+    def _state_example(self):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), self.state)
+
+    def _make_step(self, with_sentinels: bool = False):
+        space, dt, matl = self.space, self.dt, self._matl
+        analysis, symmetry, mean_mode = self._analysis(), self._symmetry(), self._mean_mode
+        stage = jax.named_scope  # metadata only: names the stage in the compiled text
+
+        def step(state: SwiftState):
+            theta = state.temp
+            with stage("synthesis"):
+                v = space.backward(theta)
+            with stage("cubic"):
+                cube = v * v * v
+            with stage("analysis"):
+                cubic = analysis(cube)
+            with stage("implicit"):
+                out = (theta - dt * cubic) / matl
+            with stage("symmetry"):
+                out = symmetry(out)
+            if with_sentinels:
+                with stage("sentinels"):
+                    zero = jnp.zeros((), v.dtype)
+                    return SwiftState(out), (zero, jnp.mean(v * v), mean_mode(out))
+            return SwiftState(out)
+
+        return step
+
+    def _make_observables(self):
+        """``(norm, energy, amp, mean)``: |F| is the coefficient-space L2 norm
+        over the complex mode count (the reference's norm_l2_c64 diagnostic;
+        the split Re/Im representation stores |c|^2 as re^2 + im^2 across its
+        two blocks, so the value is backend-independent)."""
+        space, norm_len, mean_mode = self.space, self._norm_len, self._mean_mode
+
+        def observables(state: SwiftState):
+            theta = state.temp
+            v = space.backward(theta)
+            norm = jnp.sqrt(jnp.sum(jnp.abs(theta) ** 2)) / norm_len
+            # a NaN or an infinity anywhere in the state shows in ``norm``;
+            # the pin would hide it from the constant mode alone
+            return norm, jnp.mean(v * v), jnp.max(jnp.abs(v)), mean_mode(theta) + 0.0 * norm
+
+        return observables
+
+    def _compat_fields(self) -> tuple:
+        return (
+            self.nx, self.ny, self.r, self.dt, self.length,
+            np.dtype(config.real_dtype()).name,
+        )
+
+    # -- diagnostics and IO ----------------------------------------------------
 
     def norm(self) -> float:
-        """|F|: coefficient-space L2 norm / complex mode count (the
-        reference's norm_l2_c64 diagnostic, swift_hohenberg_2d.rs).  The
-        split Re/Im representation stores |c|^2 as re^2 + im^2 across its two
-        blocks, so the value is backend-independent."""
-        a = np.asarray(self.theta)
-        return float(np.sqrt(np.sum(np.abs(a) ** 2)) / self._norm_len)
+        """|F| (observable 0)."""
+        return self.get_observables()[0]
 
-    def exit(self) -> bool:
-        return bool(np.any(np.isnan(np.asarray(self.theta))))
+    def pattern_energy(self) -> float:
+        """Domain-averaged theta^2 — the pattern-amplitude trace of the
+        upstream example (examples/swift_hohenberg_2d.rs)."""
+        return self.get_observables()[1]
 
     def callback(self) -> None:
         import os
@@ -114,6 +220,25 @@ class _SwiftHohenbergBase(Integrate):
             print(f" ==> {filename}")
         except OSError as exc:
             print(f"Error while writing file {filename}: {exc}")
+
+    def _write(self, filename: str) -> None:
+        from ..field import grid_deltas
+
+        with _h5().File(filename, "w") as f:
+            g = f.create_group("temp")
+            g.create_dataset("v", data=self.theta_physical())
+            vc = self.space.vhat_as_complex(self.theta)
+            if np.iscomplexobj(vc):
+                g.create_dataset("vhat_re", data=vc.real)
+                g.create_dataset("vhat_im", data=vc.imag)
+            else:
+                g.create_dataset("vhat", data=vc)
+            for name, arr in zip("xy", self.x):
+                g.create_dataset(name, data=arr)
+                g.create_dataset("d" + name, data=grid_deltas(arr, True))
+            f.create_dataset("time", data=self.time)
+            f.create_dataset("dt", data=self.dt)
+            f.create_dataset("r", data=self.r)
 
     def read(self, filename: str) -> None:
         with _h5().File(filename, "r") as f:
@@ -137,23 +262,27 @@ class SwiftHohenberg1D(_SwiftHohenbergBase):
     (/root/reference/examples/swift_hohenberg_1d.rs)."""
 
     def __init__(self, nx: int, r: float, dt: float, length: float):
-        super().__init__(r, dt)
-        self.nx = nx
-        self.space = Space1(fourier_r2c(nx))
-        self.scale = (float(length),)
-        self.x = [self.space.base.points * length]
-        k = self.space.base.wavenumbers / length
-        matl = 1.0 + dt * ((1.0 - k**2) ** 2 - r)
-        self._matl = jnp.asarray(matl, dtype=config.real_dtype())
-        self._dealias = jnp.asarray(
-            self.space.dealias_mask(), dtype=config.real_dtype()
-        )
-        self.theta = self.space.ndarray_spectral()
-        # complex mode count (the split representation has 2x real rows)
+        with self._build_span(nx, 1, None):
+            space = Space1(fourier_r2c(nx))
+            self._dealias = jnp.asarray(space.dealias_mask(), dtype=config.real_dtype())
+            # complex mode count (the split representation has 2x real rows)
+            base = space.base
+            self._norm_len = base.m_complex if base.kind.is_split else base.m
+            self._setup(space, (nx, 1), r, dt, length)
+            self.init_cos(1e-5)
+
+    def _k2(self) -> np.ndarray:
+        return (self.space.base.wavenumbers / self.length) ** 2
+
+    def _analysis(self):
+        space, mask = self.space, self._dealias
+        return lambda cube: space.forward(cube) * mask
+
+    def _mean_mode(self, theta):
         base = self.space.base
-        self._norm_len = base.m_complex if base.kind.is_split else base.m
-        self.init_cos(1e-5)
-        self._compile()
+        if base.kind.is_split:
+            return jnp.hypot(theta[0], theta[base.m_complex])
+        return jnp.abs(theta[0])
 
     def init_cos(self, c: float) -> None:
         """One-cosine disturbance over the domain span (reference init_cos)."""
@@ -166,43 +295,6 @@ class SwiftHohenberg1D(_SwiftHohenbergBase):
         rng = np.random.default_rng(seed)
         self.set_theta(rng.uniform(-c, c, size=self.nx))
 
-    def set_theta(self, values: np.ndarray) -> None:
-        self.theta = self.space.forward(
-            jnp.asarray(values, dtype=config.real_dtype())
-        )
-
-    def theta_physical(self) -> np.ndarray:
-        return np.asarray(self.space.backward(self.theta))
-
-    def _make_step(self):
-        space, dt = self.space, self.dt
-        matl, mask = self._matl, self._dealias
-
-        def step(theta):
-            v = space.backward(theta)
-            cubic = space.forward(v * v * v) * mask
-            return (theta - dt * cubic) / matl
-
-        return step
-
-    def _write(self, filename: str) -> None:
-        from ..field import grid_deltas
-
-        with _h5().File(filename, "w") as f:
-            g = f.create_group("temp")
-            g.create_dataset("v", data=self.theta_physical())
-            vc = self.space.vhat_as_complex(self.theta)
-            if np.iscomplexobj(vc):
-                g.create_dataset("vhat_re", data=vc.real)
-                g.create_dataset("vhat_im", data=vc.imag)
-            else:
-                g.create_dataset("vhat", data=vc)
-            g.create_dataset("x", data=self.x[0])
-            g.create_dataset("dx", data=grid_deltas(self.x[0], True))
-            f.create_dataset("time", data=self.time)
-            f.create_dataset("dt", data=self.dt)
-            f.create_dataset("r", data=self.r)
-
 
 class SwiftHohenberg2D(_SwiftHohenbergBase):
     """2-D Swift–Hohenberg on a doubly-periodic square of side
@@ -210,20 +302,23 @@ class SwiftHohenberg2D(_SwiftHohenbergBase):
     /root/reference/examples/swift_hohenberg_2d.rs)."""
 
     def __init__(self, nx: int, ny: int, r: float, dt: float, length: float):
-        super().__init__(r, dt)
-        self.nx, self.ny = nx, ny
-        self.space = BiPeriodicSpace2(nx, ny)
-        self.scale = (float(length), float(length))
-        self.x = [p * length for p in self.space.coords()]
-        kx = self.space.kx / length
-        ky = self.space.ky / length
-        k2 = kx[:, None] ** 2 + ky[None, :] ** 2
-        matl = 1.0 + dt * ((1.0 - k2) ** 2 - r)
-        self._matl = jnp.asarray(matl, dtype=config.real_dtype())
-        self.theta = self.space.ndarray_spectral()
-        self._norm_len = nx * self.space.my
-        self.init_random(1e-1)
-        self._compile()
+        with self._build_span(nx, ny, None):
+            space = BiPeriodicSpace2(nx, ny)
+            self._norm_len = nx * space.my
+            self._setup(space, (nx, ny), r, dt, length)
+            self.init_random(1e-1)
+
+    def _k2(self) -> np.ndarray:
+        kx = self.space.kx / self.length
+        ky = self.space.ky / self.length
+        return kx[:, None] ** 2 + ky[None, :] ** 2
+
+    def _symmetry(self):
+        space = self.space
+        return lambda theta: space.enforce_hermitian_x(space.pin_zero_mode(theta))
+
+    def _mean_mode(self, theta):
+        return jnp.hypot(theta[0, 0, 0], theta[1, 0, 0])
 
     def init_random(self, c: float, seed: int = 0) -> None:
         rng = np.random.default_rng(seed)
@@ -238,46 +333,3 @@ class SwiftHohenberg2D(_SwiftHohenbergBase):
             * np.cos((y[None, :] - y[0]) / sy * ky * np.pi)
         )
         self.set_theta(v)
-
-    def set_theta(self, values: np.ndarray) -> None:
-        self.theta = self.space.forward(
-            jnp.asarray(values, dtype=config.real_dtype())
-        )
-
-    def theta_physical(self) -> np.ndarray:
-        return np.asarray(self.space.backward(self.theta))
-
-    def _make_step(self):
-        space, dt = self.space, self.dt
-        matl = self._matl
-
-        def step(theta):
-            v = space.backward(theta)
-            cubic = space.forward(v * v * v)
-            out = (theta - dt * cubic) / matl
-            out = space.pin_zero_mode(out)
-            return space.enforce_hermitian_x(out)
-
-        return step
-
-    def pattern_energy(self) -> float:
-        """Domain-averaged theta^2 — the pattern-amplitude trace of the
-        upstream example (examples/swift_hohenberg_2d.rs)."""
-        v = self.theta_physical()
-        return float(np.mean(v**2))
-
-    def _write(self, filename: str) -> None:
-        from ..field import grid_deltas
-
-        with _h5().File(filename, "w") as f:
-            g = f.create_group("temp")
-            g.create_dataset("v", data=self.theta_physical())
-            vc = self.space.vhat_as_complex(self.theta)
-            g.create_dataset("vhat_re", data=vc.real)
-            g.create_dataset("vhat_im", data=vc.imag)
-            for name, arr in (("x", self.x[0]), ("y", self.x[1])):
-                g.create_dataset(name, data=arr)
-                g.create_dataset("d" + name, data=grid_deltas(arr, True))
-            f.create_dataset("time", data=self.time)
-            f.create_dataset("dt", data=self.dt)
-            f.create_dataset("r", data=self.r)

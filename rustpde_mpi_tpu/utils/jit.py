@@ -120,7 +120,20 @@ def dot_generals_by_operand(jaxpr) -> collections.Counter:
     )
 
 
+def _count(jaxpr, primitive: str) -> int:
+    """The equations of ``jaxpr`` called ``primitive``, sub-programs included:
+    the traced program, so a scan's length does not multiply the count."""
+    return sum(eqn.primitive.name == primitive for eqn in equations(jaxpr))
+
+
 def reverses(jaxpr) -> int:
     """The ``rev`` equations of ``jaxpr``, sub-programs included: the array
     flips of the parity folds (ops/folded.py), none below their size gate."""
-    return sum(eqn.primitive.name == "rev" for eqn in equations(jaxpr))
+    return _count(jaxpr, "rev")
+
+
+def gathers(jaxpr) -> int:
+    """The ``gather`` equations of ``jaxpr``, sub-programs included: the index
+    gathers of a step (the circular folds of the periodic axes' transforms in
+    ops/folded.py, the conjugate pairing of the Hermitian projection)."""
+    return _count(jaxpr, "gather")

@@ -15,7 +15,14 @@ Built-in kinds:
 * ``lnse`` — :class:`~rustpde_mpi_tpu.models.lnse.Navier2DLnse` linearized
   about the analytic conduction base state (eigenmode sweeps),
 * ``adjoint`` — :class:`~rustpde_mpi_tpu.models.steady_adjoint.Navier2DAdjoint`
-  (steady-state finds by adjoint descent).
+  (steady-state finds by adjoint descent),
+* ``swift`` — :class:`~rustpde_mpi_tpu.models.swift_hohenberg.SwiftHohenberg2D`
+  (``SwiftHohenberg1D`` for ``ny == 1``): the upstream's bring-your-own-PDE
+  demo.  The builders' signature is the DNS's, so the control parameter ``r``
+  rides the ``ra`` slot and ``length`` the ``aspect`` slot; ``pr``, ``bc`` and
+  ``periodic`` say nothing of it.  Its ``compat_key`` is its own 7-tuple
+  ``(kind, nx, ny, r, dt, length, dtype)``, so it registers a ``from_key`` and
+  :func:`build_model_for_key` builds it from that.
 """
 
 from __future__ import annotations
@@ -23,12 +30,17 @@ from __future__ import annotations
 from ..models.campaign import CAMPAIGN_MODEL_ATTRS
 
 _REGISTRY: dict[str, callable] = {}
+_FROM_KEY: dict[str, callable] = {}
 
 
-def register_model_kind(kind: str, builder) -> None:
+def register_model_kind(kind: str, builder, from_key=None) -> None:
     """Register ``builder(nx, ny, ra, pr, dt, aspect, bc, periodic, *,
-    mesh=None, scenario=None) -> CampaignModel`` under ``kind``."""
+    mesh=None, scenario=None) -> CampaignModel`` under ``kind``.  A kind whose
+    ``compat_key`` is not the DNS's 10-tuple also gives ``from_key(key, *,
+    mesh=None) -> CampaignModel``, the model its own key describes."""
     _REGISTRY[str(kind)] = builder
+    if from_key is not None:
+        _FROM_KEY[str(kind)] = from_key
 
 
 def model_kinds() -> tuple:
@@ -69,7 +81,8 @@ def build_model_for_key(key: tuple, *, mesh=None, phase: str = "build"):
     ``(kind, nx, ny, ra, pr, dt, aspect, bc, periodic, scenario_sig)``,
     or the 11-tuple SERVE key with the sub-mesh stamp appended
     (two-level serving) — the stamp selects the mesh upstream and is
-    stripped here; the model's own compat key stays the 10-tuple.
+    stripped here; the model's own compat key stays the 10-tuple.  A kind
+    registered with a ``from_key`` is built from its own key instead.
 
     This is THE model-build/jit seam for every bucket, so compile
     attribution hangs here: build wall time and the recompile count are
@@ -77,10 +90,11 @@ def build_model_for_key(key: tuple, *, mesh=None, phase: str = "build"):
     ROADMAP item's baseline numbers.  ``phase`` stamps the attribution row
     ("build" for live campaign opens, "aot" when the warm pool builds
     ahead of traffic)."""
-    from ..telemetry import compile_log
-    from ..telemetry import tracing as _tr
-
     key = tuple(key)
+    kind = key[0]
+    from_key = _FROM_KEY.get(str(kind))
+    if from_key is not None:
+        return _observed_build(key, kind, phase, lambda: from_key(key, mesh=mesh))
     if len(key) == 11:
         key = key[:10]
     kind, nx, ny, ra, pr, dt, aspect, bc, periodic, scenario_sig = key
@@ -95,14 +109,23 @@ def build_model_for_key(key: tuple, *, mesh=None, phase: str = "build"):
 
         if scenario_signature(scenario) != tuple(scenario_sig):
             raise ValueError(f"non-canonical scenario signature {scenario_sig}")
-    # one clock for the seam: the span ``registry.build_model`` (it holds the
-    # model's own ``model.build``) is what the histogram observes
+    return _observed_build(
+        key, kind, phase,
+        lambda: build_model(
+            kind, nx, ny, ra, pr, dt, aspect, bc, periodic, mesh=mesh, scenario=scenario
+        ),
+    )
+
+
+def _observed_build(key: tuple, kind, phase: str, build):
+    """``build()`` under the seam's one clock: the span ``registry.build_model``
+    (it holds the model's own ``model.build``) is what the histogram observes."""
+    from ..telemetry import compile_log
+    from ..telemetry import tracing as _tr
+
     seam = _tr.timed("registry.build_model", layer="model step", kind=str(kind), phase=phase)
     with seam:
-        model = build_model(
-            kind, nx, ny, ra, pr, dt, aspect, bc, periodic,
-            mesh=mesh, scenario=scenario,
-        )
+        model = build()
     if model.compat_key != tuple(key):
         raise ValueError(
             f"registry builder for {kind!r} produced compat_key "
@@ -172,6 +195,23 @@ def _build_adjoint(
     )
 
 
+def _build_swift(nx, ny, ra, pr, dt, aspect, bc, periodic, *, mesh=None, scenario=None):
+    from ..models.swift_hohenberg import SwiftHohenberg1D, SwiftHohenberg2D
+
+    del pr, bc, periodic  # a doubly periodic scalar PDE has none of them
+    if mesh is not None or scenario:
+        raise ValueError("the Swift-Hohenberg models take no mesh and no scenario")
+    if ny == 1:
+        return SwiftHohenberg1D(nx, ra, dt, aspect)
+    return SwiftHohenberg2D(nx, ny, ra, dt, aspect)
+
+
+def _swift_from_key(key, *, mesh=None):
+    _, nx, ny, r, dt, length, _dtype = key  # the dtype is the process's: the seam checks it
+    return _build_swift(nx, ny, r, None, dt, length, None, True, mesh=mesh)
+
+
 register_model_kind("dns", _build_dns)
 register_model_kind("lnse", _build_lnse)
 register_model_kind("adjoint", _build_adjoint)
+register_model_kind("swift", _build_swift, from_key=_swift_from_key)

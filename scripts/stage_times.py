@@ -5,6 +5,7 @@
     python scripts/stage_times.py --workload periodic1024_f32.mesh4   (4 chips)
     python scripts/stage_times.py --workload lnse_opt128_f32.loop     (two tables)
     python scripts/stage_times.py --workload rbc513_f64.solo          (float64: the cell's env sets RUSTPDE_X64)
+    python scripts/stage_times.py --workload swift512_f32.solo        (the Swift-Hohenberg step's five stages)
 
 Builds the cell's own model (``BENCHMARK.json`` and ``benchmark/`` say what a
 cell is), warms one dispatch, traces a few under ``utils/profiling.trace`` and
@@ -12,7 +13,7 @@ reduces the trace with the benchmark's own reducer.  Every device operation of
 the trace is then looked up, by instruction name and result shape, in the
 compiled text of the same chunk program, whose ``op_name`` metadata carries
 the ``jax.named_scope`` of the step stage it was traced under
-(``Navier2D._make_step``).  A fusion is counted under the stage of the
+(``Navier2D._make_step``, ``models/swift_hohenberg.py``).  A fusion is counted under the stage of the
 instruction XLA took its metadata from.  Printed: ms per step by stage and
 solve, the share under no stage, and the share of device time whose
 instruction the chunk's text does not hold (the observables).  Also: the
@@ -43,7 +44,9 @@ sys.path.insert(0, ROOT)
 
 STAGES = ("buoyancy", "synthesis", "sentinels", "momentum_x", "momentum_y", "divergence",
           "poisson", "projection", "pressure", "temperature", "scalar", "solid",
-          "history", "history_terms")
+          "history", "history_terms",
+          # the Swift-Hohenberg step (models/swift_hohenberg.py); its synthesis is named above
+          "cubic", "analysis", "implicit", "symmetry")
 INNER = ("convection", "helmholtz", "fastdiag", "tensor_solve")
 #: the mesh's own scopes (parallel/decomp.py): the manual regions and, inside
 #: them, each hand-placed exchange by direction
@@ -213,13 +216,17 @@ def main(argv=None) -> int:
     import jax
     import numpy as np
 
-    from rustpde_mpi_tpu import Navier2D, NavierEnsemble
+    from rustpde_mpi_tpu import Navier2D, NavierEnsemble, SwiftHohenberg2D
     from rustpde_mpi_tpu.utils import profiling
 
     g, ph = cfg["grid"], cfg["physics"]
     nx, ny = (args.size, args.size) if args.size else (g["nx"], g["ny"])
     n, k = int(traffic["steps_per_interval"]), int(traffic.get("members", 0))
-    if "new_periodic" in cfg["entry"]:
+    if "SwiftHohenberg2D" in cfg["entry"]:
+        # keeps the critical wavelength's points when --size shrinks the grid
+        length = ph["length"] * nx / g["nx"]
+        model = SwiftHohenberg2D(nx, ny, ph["r"], ph["dt"], length)
+    elif "new_periodic" in cfg["entry"]:
         from rustpde_mpi_tpu.parallel.mesh import make_mesh
 
         chips = int(traffic.get("mesh", 0))

@@ -636,4 +636,4 @@ def test_benchmark_selfcheck_passes(capsys):
     from benchmark import selfcheck
 
     assert selfcheck.main([]) == 0
-    assert "22 readers agree" in capsys.readouterr().out
+    assert "7 cells, 24 readers agree" in capsys.readouterr().out
