@@ -403,6 +403,7 @@ class CampaignModelBase:
         ROADMAP item needs that attribution separated from build time."""
         from ..parallel.mesh import unplaced
         from ..telemetry import compile_log
+        from ..ops.folded import sliced_products
         from ..utils.jit import dot_generals_by_operand, gathers, reverses
 
         seam = _tr.timed("model.compile_entry_points", layer=_LAYER, consts=0, const_bytes=0)
@@ -413,13 +414,17 @@ class CampaignModelBase:
                 # the ``dot_general``s of one step's traced program by operand
                 # type, counted once per pass for the ``update_n`` spans (the
                 # ensemble's too): which arithmetic the step's products were
-                # compiled in, how many array flips its parity folds brought,
+                # compiled in (float64 ones on the TPU path as sliced products,
+                # ops/folded.py, and the int8 products behind them), how many
+                # array flips its parity folds brought,
                 # how many index gathers (the circular folds of the periodic
                 # axes), and how many first-axis syntheses served two consumers
                 products = dot_generals_by_operand(self._step_cc.jaxpr)
                 self._step_products = {
                     "f64_products": products.get("float64", 0),
                     "f32_products": products.get("float32", 0),
+                    "sliced_products": sliced_products(self._step_cc.jaxpr),
+                    "int8_products": products.get("int8", 0),
                     "reverses": reverses(self._step_cc.jaxpr),
                     "gathers": gathers(self._step_cc.jaxpr),
                     "shared_syntheses": self._shared_syntheses,

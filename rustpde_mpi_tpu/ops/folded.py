@@ -29,15 +29,32 @@ tile, the fold wins by 11 % (1 % for a single field); at 513^2 the blocks
 are two to three tiles and the fold halves real work.  Below the gate the
 operator is ONE plain product with any sep permutation baked into the host
 matrix, the dealias-dead rows still dropped from it, and the fold's
-precision hook kept.  In float64, which the chip emulates, the fold engages
-at every size: a product's cost there is cutting its field operand into
-float32 pieces, and the plain form measured 6-26 % slower from 129^2 to 257^2
-(same section).  The gate reads the operator's shape and the itemsize of the
-arithmetic its products run in, and nothing else.  The checkerboard operators
-of the sep layout have the same kind of gate, lower (``_SEP_MIN_DIM``): two
-DENSE parity blocks are one product over the whole matrix, exact zeros
-included, below the 193-point grid (129^2 and 128 x 57: 13 % and 22 % of the
-step, same section); banded and trapezoid blocks are not touched.
+precision hook kept.  In float64 the fold engages at every size: measured
+while XLA emulated each float64 dot, a product's cost was cutting its field
+operand into float32 pieces and the plain form ran 6-26 % slower from 129^2
+to 257^2 (same section).  The gate reads the operator's shape and the
+itemsize of the arithmetic its products run in, and nothing else.  The
+checkerboard operators of the sep layout have the same kind of gate, lower
+(``_SEP_MIN_DIM``): two DENSE parity blocks are one product over the whole
+matrix, exact zeros included, below the 193-point grid (129^2 and 128 x 57:
+13 % and 22 % of the step, same section); banded and trapezoid blocks are
+not touched.
+
+Float64 products on the TPU path.  The chip has no float64 unit, and a
+float64 ``dot_general`` left to XLA is emulated by cutting both operands into
+float32 pieces once for every product.  Where an operator's products run in
+float64 (itemsize 8) on the TPU path (``config.is_tpu_like()``), each is
+instead a sliced product (section below): the operator cut on the host at
+build into int8 slices, the field cut on the device in one pass, exact
+int8 x int8 -> int32 products on the MXU, one float64 recombination.  The
+fold gates still read itemsize 8: a sliced product's cost is the field's
+slicing and the int8 work, both of which the fold halves as it halved the
+emulation's, and a fold's two halves are one sliced product; the gates were
+not measured again.  ``SLICES`` (9 slices of 7 bits) comes from float64's 53
+significand bits and 10 guard bits for the exponent spread that the
+power-of-two scaling of rows and columns leaves (tests/test_sliced_f64.py).
+Float32 products, and float64 products off the TPU path (the CPU's native
+float64), are XLA's own dots.
 
 Folded and plain paths agree to machine epsilon (tests/test_folded.py) —
 each output element is the same reduction, reassociated only across the
@@ -49,6 +66,7 @@ Enable/disable with RUSTPDE_FOLDED (default on).
 
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -194,6 +212,326 @@ def _unmove(a, axis):
     return jnp.moveaxis(a, 0, axis)
 
 
+# ---------------------------------------------------------------------------
+# Float64 products as exact int8 slice products
+# ---------------------------------------------------------------------------
+#
+# The chip has no float64 unit: XLA stores a float64 as a pair of float32
+# (high, low) and emulates a float64 dot by cutting both operands into
+# float32 pieces, once for every product that reads them.  On the TPU path a
+# float64 product is instead stated here, error-free on the MXU's int8 unit
+# (Ozaki's splitting).  Each operand is scaled by powers of two (the operator
+# per output row, the field per output column, both along the contraction;
+# the operator's columns balanced against the field's rows first) to
+# magnitudes under 1/2 and cut into ``SLICES`` balanced base-128 digits,
+# integers in [-64, 64]: ``x = sum_s d_s 2^(-7(s+1))`` up to 2^(-7 SLICES).
+# The operator is cut once, on the host, at build; a field once per product,
+# in one vectorised pass over its float32 pieces.
+#
+# The slice pairs (s, t) with s + t = d make the partial ``P_d``, exact in
+# int32 ((d + 1) K terms of at most 64 x 96).  The pairs with
+# s + t >= SLICES are left out: each is below 2^(-7 SLICES) of the row's and
+# the column's scale, as is what the cut leaves of either operand.  The
+# partials are int8 products of the operator's slices laid out as a block
+# Toeplitz matrix (block (d, t) holds slice d - t, zero above the diagonal)
+# against the field's slices: ``_GROUPS`` splits the field's slices into runs
+# and each run is ONE product over the rows it reaches, which leaves
+# 45 + 16 of the 81 blocks a single product would stream, for two products.
+# The partials are summed with carries in int32 and turned into float64 in
+# four exact float32 pieces and three float64 additions.
+#
+# A parity fold's two halves (and a trapezoid block's strips) are one sliced
+# product over a batch (the smaller operands padded with zeros), so a fold
+# states one program where it states two dots.
+
+#: bits of one slice: a balanced digit in [-64, 64] (int8)
+_SLICE_BITS = 7
+#: guard bits beyond float64's 53-bit significand: the exponent spread that
+#: the scaling leaves.  An operand's element below its row's (column's)
+#: largest keeps its bits down to 2^-63 of that largest, and the K terms that
+#: the cut and the dropped slice pairs perturb at that level sum to under
+#: 2^-53 of the product's scale for every K up to 2^10 = 1024 (at random
+#: signs, far beyond)
+_GUARD_BITS = 10
+#: slices an operand is cut into, set by accuracy alone: 53 + 10 bits, 9 x 7
+SLICES = -(-(53 + _GUARD_BITS) // _SLICE_BITS)
+#: runs of the field's slices, each one int8 product (see above)
+_GROUPS = ((0, 5), (5, SLICES))
+#: the ``jax.jit`` name of the sliced product: one traced program for each
+#: signature, and what the step's ``sliced_products`` count reads
+SLICED_PRODUCT = "sliced_product"
+
+
+class SlicedOperator:
+    """One float64 operator ``(r, K)``, or several applied side by side (a
+    parity fold's two halves, a trapezoid block's strips), as the sliced
+    product reads them: ``toeplitz`` holds, for each run of
+    ``_GROUPS``, the int8 blocks ``(B, SLICES - t0, r, t1 - t0, K)``,
+    ``scale`` the float64 ``(B, r)`` power of two of each row times 2^-14
+    (the weight of the partial ``P_0``), ``balance`` the float32 ``(B, K)``
+    power of two of each column relative to the largest, in [2^-48, 1] (0
+    for a column of zeros), which multiplies the field's row instead;
+    ``rows`` and ``cols`` the shape of each of the ``B`` operators, before
+    the padding that stacks them.  ``lone`` names
+    the columns with a single nonzero, ``(b, row, col, value)`` each, which
+    the sliced product leaves out (their ``balance`` is 0) and adds back as
+    ``y[b, row] += value x[b, col]`` in float64: a field's mode that
+    such a column passes through, the nudged null mode of a Neumann solve,
+    may be ten decades above the rest and would otherwise set the scale
+    every other mode is cut to."""
+
+    __slots__ = ("toeplitz", "scale", "balance", "lone", "rows", "cols")
+
+    def __init__(self, toeplitz, scale, balance, lone, rows, cols):
+        self.toeplitz, self.scale, self.balance = toeplitz, scale, balance
+        self.lone, self.rows, self.cols = lone, rows, cols
+
+
+def _digits(x, xp, axis: int = 0):
+    """The ``SLICES`` balanced base-128 digits of ``x`` (|x| < 1/2, any real
+    dtype whose products by 2^(7 s) are exact) along ``axis``, where ``x``
+    has an axis of extent 1, most significant first:
+    ``d_s = round(x 2^(7(s+1))) - 128 round(x 2^(7s))``, each an integer in
+    [-64, 64], exactly, and ``sum_s d_s 2^(-7(s+1))`` over all ``SLICES`` is
+    ``x`` rounded at 2^(-7 SLICES)."""
+    exponents = _SLICE_BITS * np.arange(1, SLICES + 1)
+    shape = [1] * x.ndim
+    shape[axis] = exponents.size
+    above = np.ldexp(1.0, exponents).astype(x.dtype).reshape(shape)
+    below = np.ldexp(1.0, exponents - _SLICE_BITS).astype(x.dtype).reshape(shape)
+    return xp.round(x * above) - 128 * xp.round(x * below)
+
+
+def slice_operator(mats):
+    """Host float64 matrices (one, or several applied side by side) ->
+    :class:`SlicedOperator` as numpy arrays.  Each column is first divided by
+    the power of two of its largest element relative to the largest column's
+    (the field's row is multiplied by it instead, and a column of zeros takes
+    the field's row out: the constant mode a derivative annihilates), so
+    that an operator whose columns grow along the spectrum meets a field that
+    decays along it on even terms, and a column with one nonzero is left to
+    ``lone``; then each
+    row is scaled by the power of two that brings its largest element into
+    [1/4, 1/2), exactly, cut into ``SLICES`` digit matrices, laid out as the
+    block Toeplitz runs of ``_GROUPS``."""
+    mats = [np.asarray(m, dtype=np.float64) for m in mats]
+    rows = tuple(m.shape[0] for m in mats)
+    cols = tuple(m.shape[1] for m in mats)
+    r, c = max(rows), max(cols)
+    digits = np.zeros((len(mats), SLICES, r, c), np.int8)
+    scale = np.ones((len(mats), r))
+    balance = np.zeros((len(mats), c))
+    lone = []
+    for i, m in enumerate(mats):
+        for k in np.flatnonzero(np.count_nonzero(m, axis=0) == 1):
+            row = int(np.flatnonzero(m[:, k])[0])
+            lone.append((i, row, int(k), float(m[row, k])))
+            m = np.where(np.arange(m.shape[1]) == k, 0.0, m)
+        largest = np.abs(m).max(axis=0, initial=0.0)
+        _, e = np.frexp(largest)
+        # relative to the largest column: at most 1, at least 2^-48
+        e = np.clip(e - e.max(initial=0), -48, 0)
+        column = np.where(largest > 0, np.ldexp(1.0, e), 0.0)
+        balance[i, : m.shape[1]] = column
+        m = m / np.where(column > 0, column, 1.0)
+        _, e = np.frexp(np.abs(m).max(axis=1, initial=0.0))
+        scale[i, : m.shape[0]] = np.ldexp(1.0, e + 1 - 2 * _SLICE_BITS)
+        row = np.ldexp(1.0, e + 1)[:, None]
+        digits[i, :, : m.shape[0], : m.shape[1]] = _digits((m / row)[None], np)
+    toeplitz = []
+    for t0, t1 in _GROUPS:
+        blocks = np.zeros((len(mats), SLICES - t0, r, t1 - t0, c), np.int8)
+        for d in range(t0, SLICES):
+            for t in range(t0, min(t1, d + 1)):
+                blocks[:, d - t0, :, t - t0, :] = digits[:, d - t]
+        toeplitz.append(blocks)
+    return SlicedOperator(tuple(toeplitz), scale, balance, tuple(lone), rows, cols)
+
+
+def _column_digits(x, balance):
+    """Fields ``(B, K, ...)`` float64 and the operators' ``balance``
+    ``(B, K)`` (float32: :func:`slice_operator`) -> the digits
+    ``(B, SLICES, K, ...)`` int8 of the balanced fields, most significant
+    first, and each column's scale ``(B, 1, ...)`` (float32): the power of
+    two that brings the column's largest balanced element under 1/2.
+
+    One float64 product scales a field (by ``balance`` times the column's
+    power of two) and two float64 subtractions cut it into three float32
+    pieces (``x = hi + mid + lo`` exactly, 72 bits for 53; on the chip a
+    float64 IS the pair hi + mid); the rest is float32 arithmetic on the
+    pieces, their digits summed.  A piece is under half an ulp of the one
+    before, so in one slot at most two pieces have digits, of at most 64 and
+    32: a slot's digit stays within [-96, 96].  Fields are held under 2^75."""
+    lax, f32 = jax.lax, jnp.float32
+    balance = lax.expand_dims(balance, tuple(range(2, x.ndim)))
+    # the exponent field E of the column's largest balanced |x| (read off
+    # float32): a scale 2^(125 - E) brings it into [1/4, 1/2), held to
+    # [2^-76, 2^124] (E in [1, 201]: a column of zeros, or of values float32
+    # flushes, gets 2^124 and stays under 1/4, as balance <= 1).  Balance and
+    # scale are one float32 power of two in range, one float64 product,
+    # before the pieces are cut, so that no piece of a small column falls
+    # below float32's range
+    largest = lax.reduce_max(lax.abs(lax.convert_element_type(x, f32)) * balance, (1,))
+    biased = lax.shift_right_logical(lax.bitcast_convert_type(largest, jnp.int32), np.int32(23))
+    biased = lax.clamp(np.int32(1), biased, np.int32(201))
+    scale = lax.bitcast_convert_type(lax.shift_left(np.int32(252) - biased, np.int32(23)), f32)
+    scale = lax.expand_dims(scale, (1,))
+    x = x * lax.convert_element_type(balance * scale, x.dtype)
+    hi = lax.convert_element_type(x, f32)
+    rest = lax.sub(x, lax.convert_element_type(hi, x.dtype))
+    mid = lax.convert_element_type(rest, f32)
+    lo = lax.convert_element_type(lax.sub(rest, lax.convert_element_type(mid, x.dtype)), f32)
+    digits = [_digits(lax.expand_dims(piece, (1,)), jnp, axis=1) for piece in (hi, mid, lo)]
+    digits = lax.add(lax.add(*digits[:2]), digits[2])
+    return lax.convert_element_type(digits, jnp.int8), 1 / scale
+
+
+def _recombine(partials, scale, column):
+    """``sum_d 2^(-7 d) P_d`` times each row's and column's scale, in
+    float64: the int32 partials summed with carries into one integer and
+    ``SLICES - 1`` digits of 7 bits, the digits packed three to a float32 (21
+    bits: exact), and the four pieces added in float64."""
+    lax, f64 = jax.lax, jnp.float64
+    carry = None
+    rest = [None] * SLICES
+    for d in range(SLICES - 1, 0, -1):
+        v = partials[d] if carry is None else lax.add(partials[d], carry)
+        carry = lax.shift_right_arithmetic(v, np.int32(_SLICE_BITS))
+        rest[d] = lax.sub(v, lax.shift_left(carry, np.int32(_SLICE_BITS)))  # in [0, 128)
+    total = lax.convert_element_type(lax.add(partials[0], carry), f64)
+    for first in range(1, SLICES, 3):
+        run = range(first, min(first + 3, SLICES))
+        packed = rest[run[0]]
+        for d in run[1:]:
+            packed = lax.add(lax.shift_left(packed, np.int32(_SLICE_BITS)), rest[d])
+        weight = np.float32(2.0 ** (-_SLICE_BITS * run[-1]))
+        piece = lax.mul(lax.convert_element_type(packed, jnp.float32), weight)
+        total = lax.add(total, lax.convert_element_type(piece, f64))
+    rows = lax.expand_dims(scale, tuple(range(2, total.ndim)))
+    return total * (rows * lax.convert_element_type(column, f64))
+
+
+@partial(jax.jit, static_argnames=("lone", "rows"))
+def sliced_product(toeplitz, scale, balance, xs, lone, rows):
+    """``[tensordot(A_b, x_b, axes=([1], [0])) for b]`` for the ``B``
+    float64 operators of a :class:`SlicedOperator` (``toeplitz``, ``scale``,
+    ``balance``, ``lone``, ``rows``) and the float64 fields
+    ``xs`` ``(K_b, ...)``: stacked (padded with zeros to one length),
+    balanced, sliced, multiplied run by run, recombined, the lone columns
+    added."""
+    lax = jax.lax
+    k = toeplitz[0].shape[-1]
+    x = jnp.stack([lax.pad(v, 0.0, [(0, k - v.shape[0], 0)] + [(0, 0, 0)] * (v.ndim - 1)) for v in xs])
+    field, column = _column_digits(x, balance)
+    partials = [None] * SLICES
+    for (t0, t1), blocks in zip(_GROUPS, toeplitz):
+        out = lax.dot_general(
+            blocks, lax.slice_in_dim(field, t0, t1, axis=1),
+            (((3, 4), (1, 2)), ((0,), (0,))), preferred_element_type=jnp.int32,
+        )  # (B, SLICES - t0, r, ...)
+        for d in range(t0, SLICES):
+            part = lax.index_in_dim(out, d - t0, axis=1, keepdims=False)
+            partials[d] = part if partials[d] is None else lax.add(partials[d], part)
+    y = _recombine(partials, scale, column)
+    out = [lax.slice_in_dim(y[b], 0, r, axis=0) for b, r in enumerate(rows)]
+    for b, row, col, value in lone:  # static: slices, no gather
+        update = out[b][row : row + 1] + np.float64(value) * xs[b][col : col + 1]
+        out[b] = lax.concatenate([out[b][:row], update, out[b][row + 1 :]], 0)
+    return tuple(out)
+
+
+def sliced_products(jaxpr) -> int:
+    """The float64 products ``jaxpr`` states as sliced products, sub-programs
+    included (``utils/jit.equations``): each call of :func:`sliced_product`
+    counts its operators (the leading extent of ``scale``, which no ``vmap``
+    batches: it is a constant)."""
+    from ..utils.jit import equations
+
+    return sum(
+        eqn.invars[len(_GROUPS)].aval.shape[0]  # scale
+        for eqn in equations(jaxpr)
+        if eqn.params.get("name") == SLICED_PRODUCT
+    )
+
+
+def _products(ops, xs, precision=None):
+    """``[tensordot(m, x, axes=([1], [0])) for m, x in zip(ops, xs)]``, where
+    every dense product of this module is stated: ``ops`` is what ``place``
+    or ``place.group`` made.  A :class:`SlicedOperator` is one sliced product
+    over its operators, anything else XLA's own dot in ``precision``, one
+    each."""
+    if not isinstance(ops, SlicedOperator):
+        return [jnp.tensordot(m, x, axes=([1], [0]), precision=precision) for m, x in zip(ops, xs)]
+    if any(jnp.iscomplexobj(x) for x in xs):
+        # a complex spectrum (a space off the TPU path that shares this base's
+        # operators) is its real and imaginary parts, each a float64 field
+        parts = [_products(ops, [x.real for x in xs]), _products(ops, [x.imag for x in xs])]
+        return [jax.lax.complex(re, im) for re, im in zip(*parts)]
+    from ..parallel.mesh import LOCAL, constrain
+
+    # under a mesh the contracted axis of a field is whole on a device and
+    # the other is cut: stated, so that the digits and partials keep that
+    # layout (left to propagation they are gathered); inside a hand-
+    # partitioned region (``shard_map``) a field is its device's block, and a
+    # vector has no pencil: neither states anything
+    manual = any(getattr(jax.typeof(x), "vma", None) for x in xs)
+    pin = lambda v: v if manual or v.ndim < 2 else constrain(v, LOCAL[0])  # noqa: E731
+    xs = tuple(pin(x.astype(jnp.float64)) for x in xs)
+    out = sliced_product(ops.toeplitz, ops.scale, ops.balance, xs, lone=ops.lone, rows=ops.rows)
+    return [pin(y) for y in out]
+
+
+def _product(m, x, precision=None):
+    """One ``tensordot(m, x, axes=([1], [0]))`` (:func:`_products`)."""
+    return _products(m if isinstance(m, SlicedOperator) else (m,), (x,), precision)[0]
+
+
+def _halves(dev, first, second, precision=None):
+    """A parity fold's two products ``(m_e first(), m_o second())`` for its
+    halves ``dev`` (``place.group``): one sliced product for both where they
+    are sliced, else two dots, each operand made just before its own dot (the
+    equations, in order, of two plain products)."""
+    if isinstance(dev, SlicedOperator):
+        return tuple(_products(dev, (first(), second())))
+    m_e, m_o = dev
+    y_e = _product(m_e, first(), precision)
+    return y_e, _product(m_o, second(), precision)
+
+
+def _sliced(itemsize: int) -> bool:
+    """Whether an operator's products, of ``itemsize`` bytes an element, are
+    sliced products: float64 on the TPU path."""
+    return itemsize == 8 and config.is_tpu_like()
+
+
+class _Place:
+    """The host -> device placement an impl's ``device_parts`` is handed:
+    ``place(m)`` for the operator of one product, ``place.group(*mats)`` for
+    operators applied side by side, a fold's two halves or a trapezoid's
+    strips (each cut into int8 slices, the group as one, where the products
+    are sliced), ``place.plain(m)`` for any other constant."""
+
+    def __init__(self, plain, sliced: bool):
+        self.plain = plain
+        self.sliced = sliced
+
+    def _slice(self, mats):
+        op = slice_operator(mats)
+        with jax.ensure_compile_time_eval():
+            op.toeplitz = tuple(jnp.asarray(t) for t in op.toeplitz)
+            op.scale = self.plain(op.scale)
+            op.balance = jnp.asarray(op.balance, jnp.float32)
+        return op
+
+    def __call__(self, m):
+        return self._slice([m]) if self.sliced else self.plain(m)
+
+    def group(self, *mats):
+        if self.sliced:
+            return self._slice(mats)
+        return tuple(self.plain(m) for m in mats)
+
+
 # even/odd row interleave shared with the cumsum-derivative kernel
 from .transforms import _interleave0 as _interleave  # noqa: E402
 
@@ -227,7 +565,7 @@ class _BandedApply:
             self.flops_factor = 0.0
 
     def device_parts(self, to_dev):
-        return (to_dev(self.weights),)
+        return (to_dev.plain(self.weights),)
 
     def apply(self, dev, a, axis: int):
         (w,) = dev
@@ -258,6 +596,8 @@ class _Plain:
         from .transforms import apply_matrix
 
         (m,) = dev
+        if isinstance(m, SlicedOperator):
+            return _unmove(_product(m, _move(a, axis)), axis)
         return apply_matrix(m, a, axis)
 
     def device_parts(self, to_dev):
@@ -300,7 +640,7 @@ class _PlainReflect(_Plain):
 
     def apply(self, dev, a, axis: int):
         (m,) = dev
-        y = jnp.tensordot(m, _move(a, axis), axes=([1], [0]), precision=self.precision)
+        y = _product(m, _move(a, axis), self.precision)
         if self.zero_fill is not None:
             parts, row = [], 0
             for kept, dead in self.zero_fill:
@@ -337,13 +677,12 @@ class _AnalysisFold:
         self.flops_factor = 0.5
 
     def device_parts(self, to_dev):
-        return (to_dev(self.m_e), to_dev(self.m_o))
+        return to_dev.group(self.m_e, self.m_o)
 
     def _combine(self, y_e, y_o):
         return _interleave(y_e, y_o, self.r)
 
     def apply(self, dev, a, axis: int):
-        m_e, m_o = dev
         x = _move(a, axis)
         h, n = self.h, self.n
         xr = x[::-1]
@@ -351,8 +690,7 @@ class _AnalysisFold:
         v = x[:h] - xr[:h]
         if n % 2 == 1:
             u = jnp.concatenate([u, x[h : h + 1]], axis=0)
-        y_e = jnp.tensordot(m_e, u, axes=([1], [0]), precision=self.precision)
-        y_o = jnp.tensordot(m_o, v, axes=([1], [0]), precision=self.precision)
+        y_e, y_o = _halves(dev, lambda: u, lambda: v, self.precision)
         return _unmove(self._combine(y_e, y_o), axis)
 
 
@@ -371,13 +709,11 @@ class _SynthesisFold:
         self.flops_factor = 0.5
 
     def device_parts(self, to_dev):
-        return (to_dev(self.m_e), to_dev(self.m_o))
+        return to_dev.group(self.m_e, self.m_o)
 
     def apply(self, dev, a, axis: int):
-        m_e, m_o = dev
         x = _move(a, axis)
-        A = jnp.tensordot(m_e, x[0::2], axes=([1], [0]))
-        B = jnp.tensordot(m_o, x[1::2], axes=([1], [0]))
+        A, B = _halves(dev, lambda: x[0::2], lambda: x[1::2])
         top = A + B
         floor = self.n // 2
         bottom = (A - B)[:floor][::-1]
@@ -399,14 +735,12 @@ class _CheckerFold:
         self.flops_factor = 0.5
 
     def device_parts(self, to_dev):
-        return (to_dev(self.m_e), to_dev(self.m_o))
+        return to_dev.group(self.m_e, self.m_o)
 
     def apply(self, dev, a, axis: int):
-        m_e, m_o = dev
         x = _move(a, axis)
         s = self.shift % 2
-        y_e = jnp.tensordot(m_e, x[s::2], axes=([1], [0]))
-        y_o = jnp.tensordot(m_o, x[(1 + s) % 2 :: 2], axes=([1], [0]))
+        y_e, y_o = _halves(dev, lambda: x[s::2], lambda: x[(1 + s) % 2 :: 2])
         return _unmove(_interleave(y_e, y_o, self.r), axis)
 
 
@@ -466,10 +800,8 @@ class _SynthesisSep(_SynthesisFold):
         self.sign = sign
 
     def apply(self, dev, a, axis: int):
-        m_e, m_o = dev
         x = _move(a, axis)
-        A = jnp.tensordot(m_e, x[: self.ce], axes=([1], [0]), precision=self.precision)
-        B = jnp.tensordot(m_o, x[self.ce :], axes=([1], [0]), precision=self.precision)
+        A, B = _halves(dev, lambda: x[: self.ce], lambda: x[self.ce :], self.precision)
         top = A + B
         floor = self.n // 2
         bottom = (self.sign * (A - B))[:floor][::-1]
@@ -502,14 +834,19 @@ class _StripTrapezoid:
         )
 
     def device_parts(self, to_dev):
+        if to_dev.sliced:
+            return to_dev.group(*self.mats)  # one sliced product for all strips
         return tuple(to_dev(m) for m in self.mats)
 
     def apply(self, dev, a, axis: int):
         x = _move(a, axis)
-        parts = [
-            jnp.tensordot(m, x[c0:], axes=([1], [0]))
-            for m, (_, _, c0) in zip(dev, self.bounds)
-        ]
+        if isinstance(dev, SlicedOperator):
+            parts = _products(dev, [x[c0:] for _, _, c0 in self.bounds])
+        else:
+            parts = [
+                _product(m, x[c0:])
+                for m, (_, _, c0) in zip(dev, self.bounds)
+            ]
         return _unmove(jnp.concatenate(parts, axis=0), axis)
 
 
@@ -587,13 +924,17 @@ class _SepBoth:
         )
 
     def device_parts(self, to_dev):
+        if to_dev.sliced and all(b.kind == "plain" for b in self.blocks):
+            return to_dev.group(*(b.mat for b in self.blocks))  # one sliced product
         return tuple(b.device_parts(to_dev) for b in self.blocks)
 
     def apply(self, dev, a, axis: int):
         x = _move(a, axis)
         x_e, x_o = x[: self.ce], x[self.ce :]
         b_e, b_o = self.blocks
-        if self.shift == 0:
+        if isinstance(dev, SlicedOperator):
+            y_e, y_o = _products(dev, (x_e, x_o) if self.shift == 0 else (x_o, x_e))
+        elif self.shift == 0:
             y_e = b_e.apply(dev[0], x_e, 0)
             y_o = b_o.apply(dev[1], x_o, 0)
         else:
@@ -753,10 +1094,8 @@ class FoldedMatrix:
         through it (input cast in, output cast back to the input dtype) —
         the f64-hybrid mode's f32 convection transforms (Base._sep_dev)."""
         self._cast = np.dtype(cast) if cast is not None else None
-        self._impl = _detect(
-            np.asarray(mat), sep_in, sep_out, keep_rows,
-            self._cast.itemsize if self._cast is not None else None,
-        )
+        itemsize = (self._cast or np.dtype(config.real_dtype())).itemsize
+        self._impl = _detect(np.asarray(mat), sep_in, sep_out, keep_rows, itemsize)
         if self._cast is None:
             place = to_dev
         else:
@@ -771,7 +1110,7 @@ class FoldedMatrix:
                 # bases._dev itself
                 with jax.ensure_compile_time_eval():
                     return jnp.asarray(np.asarray(m).astype(_c))
-        self._dev = self._impl.device_parts(place)
+        self._dev = self._impl.device_parts(_Place(place, _sliced(itemsize)))
         # drop the host copies — apply() reads only the device parts and the
         # scalar shape metadata (at 2049^2 f64 a retained inverse is ~33 MB);
         # recurse into wrapped impls (_CircBothFold holds an inner fold,
@@ -850,16 +1189,18 @@ class _CircAnalysisFold:
         self.flops_factor = 0.5
 
     def device_parts(self, to_dev):
-        return (to_dev(self.m_e), to_dev(self.m_o) if self.m_o is not None else None)
+        if self.m_o is None:
+            return (to_dev(self.m_e), None)
+        return to_dev.group(self.m_e, self.m_o)
 
     def apply(self, dev, a, axis: int):
-        m_e, m_o = dev
         x = _move(a, axis)
         u = jnp.concatenate([x[self._fixed], x[self._pair] + x[self._partner]])
-        parts = [jnp.tensordot(m_e, u, axes=([1], [0]))]
-        if m_o is not None:
-            v = x[self._pair] - x[self._partner]
-            parts.append(jnp.tensordot(m_o, v, axes=([1], [0])))
+        if isinstance(dev, SlicedOperator) or dev[1] is not None:
+            v = lambda: x[self._pair] - x[self._partner]  # noqa: E731
+            parts = list(_halves(dev, lambda: u, v))
+        else:
+            parts = [_product(dev[0], u)]
         out = jnp.concatenate(parts, axis=0)[self._inv]
         return _unmove(out, axis)
 
@@ -884,17 +1225,17 @@ class _CircSynthesisFold:
         self.flops_factor = 0.5
 
     def device_parts(self, to_dev):
-        return (to_dev(self.m_e), to_dev(self.m_o) if self.m_o is not None else None)
+        if self.m_o is None:
+            return (to_dev(self.m_e), None)
+        return to_dev.group(self.m_e, self.m_o)
 
     def apply(self, dev, a, axis: int):
-        m_e, m_o = dev
         x = _move(a, axis)
-        A = jnp.tensordot(m_e, x[self._cols_s], axes=([1], [0]))
-        if m_o is not None:
-            B = jnp.tensordot(m_o, x[self._cols_a], axes=([1], [0]))
+        if isinstance(dev, SlicedOperator) or dev[1] is not None:
+            A, B = _halves(dev, lambda: x[self._cols_s], lambda: x[self._cols_a])
             top, bottom = A + B, A - B
         else:
-            top = bottom = A
+            top = bottom = _product(dev[0], x[self._cols_s])
         out = jnp.concatenate([top, bottom[self._mirror]], axis=0)
         return _unmove(out, axis)
 

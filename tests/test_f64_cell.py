@@ -12,9 +12,10 @@ suite's process is float64, the configuration's own precision):
   process of its own: its reading beside the limit, whichever way it falls;
 * the driver's refusal of a process whose precision is not the
   configuration's;
-* the ``f64_products`` / ``f32_products`` counts of the ``model.update_n`` span
-  against a hand count of one confined step's products, the reader of the
-  first, and that ``RUSTPDE_SOLVE_PRECISION`` leaves a float64 step as it is.
+* the ``sliced_products`` / ``int8_products`` / ``f64_products`` /
+  ``f32_products`` counts of the ``model.update_n`` span against a hand count
+  of one confined step's products, the reader of ``f64_products``, and that
+  ``RUSTPDE_SOLVE_PRECISION`` leaves a float64 step as it is.
 """
 
 import importlib.util
@@ -31,6 +32,7 @@ from benchmark.drivers import interval_f64
 from benchmark.ic import smooth_fields
 from benchmark.layer_metrics import f64_products_per_step
 from rustpde_mpi_tpu import Navier2D, config
+from rustpde_mpi_tpu.ops import folded as folded_ops
 from rustpde_mpi_tpu.telemetry import FlightRecorder
 from rustpde_mpi_tpu.telemetry import tracing as ttracing
 from rustpde_mpi_tpu.utils.jit import dot_generals_by_operand
@@ -92,7 +94,7 @@ files = cell.small()
 res = cell.drive(files)
 args = tracing.spans("model.update_n")[-1][-1]
 print(json.dumps({{"compared": res["compared"], "f64_products": args["f64_products"],
-                  "f32_products": args["f32_products"]}}))
+                  "f32_products": args["f32_products"], "sliced_products": args["sliced_products"]}}))
 """
 
 
@@ -119,8 +121,9 @@ def test_hybrid_reads_beside_the_limit(files):
     # (a velocity's x-synthesis serves ux / uy and its own chain's d/dy, PR 36),
     # in float32 each one plain product below ops/folded.py's fold gate, of the
     # forced TPU path's 80 dot_generals at this size (folded they are 40 of
-    # 100; the float64 operators fold at every size)
-    assert (got["f64_products"], got["f32_products"]) == (60, 20), got
+    # 100; the float64 operators fold at every size, and on the TPU path each
+    # of theirs is a sliced product: ops/folded.py)
+    assert (got["f64_products"], got["f32_products"], got["sliced_products"]) == (0, 20, 60), got
 
 
 # -- the refusal ------------------------------------------------------------------
@@ -179,13 +182,19 @@ def test_span_counts_one_steps_products_by_operand_type(monkeypatch, ring, fold_
     model.init_random(0.1, seed=0)
     model.update_n(4)
     args = ttracing.spans("model.update_n")[-1][-1]
-    assert args["f64_products"] == products
+    # on the TPU path every float64 product is a sliced product (ops/folded.py):
+    # none is left to XLA's float64 dot; a parity fold's two halves are one
+    # sliced product, which states one int8 product for each run of slices
+    assert args["sliced_products"] == products
+    calls = products // 2 if folded else products
+    assert args["int8_products"] == len(folded_ops._GROUPS) * calls <= folded_ops.SLICES * products
+    assert args["f64_products"] == 0
     assert args["f32_products"] == 0
     assert args["reverses"] == (TRANSFORMS if folded else 0)
     # counted in the traced step, once: the chunk's scan does not multiply it
     model.update_n(8)
-    assert ttracing.spans("model.update_n")[-1][-1]["f64_products"] == products
-    assert f64_products_per_step.read({}, {"traced_dispatches": 2}) == float(products)
+    assert ttracing.spans("model.update_n")[-1][-1]["sliced_products"] == products
+    assert f64_products_per_step.read({}, {"traced_dispatches": 2}) == 0.0
     assert f64_products_per_step.read({}, {"traced_dispatches": 3}) is None
     # a span without the count (the parent commit's) reads nothing
     ring.add_complete("model.update_n", ring.now_us(), 5.0, {"id": 9, "parent": None, "steps": 8})

@@ -33,6 +33,7 @@ from benchmark.ic_periodic import smooth_periodic_fields
 from benchmark.reference import random_fields
 from benchmark.reference_periodic import Reference
 from rustpde_mpi_tpu import Navier2D, config
+from rustpde_mpi_tpu.ops import folded
 from rustpde_mpi_tpu.parallel.decomp import Decomp2d
 from rustpde_mpi_tpu.parallel.mesh import PHYS, SPEC, make_mesh
 from rustpde_mpi_tpu.telemetry import FlightRecorder
@@ -193,6 +194,10 @@ FLIPS = 15  # the hand count of test_meshed_chunk_sums_no_field_over_the_devices
 # less the two flips behind the x-synthesis of velx and of vely that ux / uy and the velocity's
 # own d/dy share since ISSUE 36 (23 all-to-alls before)
 CONFINED_17 = {"all-to-all": 21, "all-gather": 39, "collective-permute": 44, "all-reduce": 3}
+# the same chunk in float64 on the TPU path, every product a sliced product whose field is pinned
+# with its contracted axis whole (ops/folded.py): 16 more all-to-alls round the products where
+# this partitioner gathered and permuted round XLA's own dots, 43 collectives for 107
+CONFINED_17_SLICED = {"all-to-all": 37, "all-gather": 5, "all-reduce": 1}
 
 
 def chunk_text(model, n=4) -> str:
@@ -322,7 +327,8 @@ def test_the_chips_own_compiler_sums_no_field_either(grid):
     sums_no_field(done.stdout, nx, ny, flips=FLIPS, gathered_flips=0 if nx >= 256 else None)
 
 
-def test_meshed_confined_chunk_keeps_the_x_pencil_rest(monkeypatch, no_compile_cache, fold_gate):
+@pytest.mark.parametrize("products", ["dots", "sliced"])
+def test_meshed_confined_chunk_keeps_the_x_pencil_rest(monkeypatch, no_compile_cache, fold_gate, products):
     """The other side of the selection: a confined space (Chebyshev along x,
     dense x-operators) rests as x-pencils, its y-operators between a pair of
     flips, and its chunk on four devices holds the collectives the tree before
@@ -330,13 +336,19 @@ def test_meshed_confined_chunk_keeps_the_x_pencil_rest(monkeypatch, no_compile_c
     chunk's text was the same but for its metadata).  That tree folded every
     transform, so the fold gate of ops/folded.py is pinned below this size
     (with plain products this partitioner takes 8 of the 23 flips as 10
-    all-gathers: PERF.md section 7, found by PR 34)."""
+    all-gathers: PERF.md section 7).  ``dots``: every product
+    XLA's own dot, as the float32 cells' are; ``sliced``: the float64 step of
+    the TPU path, whose sliced products (ops/folded.py) state their
+    contracted axis whole on a device, as the transforms do (CONFINED_17_SLICED)."""
     monkeypatch.setenv("RUSTPDE_FORCE_TPU_PATH", "1")
+    if products == "dots":
+        monkeypatch.setattr(folded, "_sliced", lambda itemsize: False)
     fold_gate(4)
     model = Navier2D.new_confined(17, 17, *PHYSICS, "rbc", mesh=make_mesh(jax.devices()[:4]))
     assert model.temp_space.rest == SPEC and model.temp_space.synthesis_axes == (0, 1)
+    assert (model._step_products["sliced_products"] > 0) == (products == "sliced" and config.X64)
     counts = collections.Counter(kind for kind, _, _ in collectives(chunk_text(model)))
-    assert counts == CONFINED_17, counts
+    assert counts == (CONFINED_17_SLICED if model._step_products["sliced_products"] else CONFINED_17), counts
 
 
 def test_unmeshed_confined_chunk_states_no_layout(monkeypatch, no_compile_cache):

@@ -191,8 +191,10 @@ def test_launches_counts_leaves_copied_and_buckets(ring, kind, n):
 @pytest.mark.parametrize("kind", ["model", "ensemble"])
 @pytest.mark.parametrize("folded", [True, False])
 def test_update_n_spans_count_the_parity_folds_reverses(ring, monkeypatch, fold_gate, folded, kind):
-    """``reverses`` beside ``f32_products`` / ``f64_products``: the ``rev``
-    equations of one traced step, the member step's on the ensemble's span.
+    """``reverses`` beside ``f32_products`` / ``f64_products`` /
+    ``sliced_products`` (the float64 products of the TPU path, ops/folded.py):
+    the ``rev`` equations of one traced step, the member step's on the
+    ensemble's span.
     On the matmul-transform path a confined step applies 20 transforms
     (4 in ``synthesis``, 6 in each of the three convection chains, less the
     x-synthesis of ``velx`` and of ``vely``, which ``ux`` / ``uy`` and the
@@ -209,7 +211,8 @@ def test_update_n_spans_count_the_parity_folds_reverses(ring, monkeypatch, fold_
     sim.update_n(2)
     args = ttracing.spans(f"{kind}.update_n")[-1][-1]
     assert args["reverses"] == (20 if folded else 0)
-    assert args["f64_products"] + args["f32_products"] == (100 if folded else 100 - 20 - 24)
+    products = args["f64_products"] + args["f32_products"] + args["sliced_products"]
+    assert products == (100 if folded else 100 - 20 - 24)
     assert args["shared_syntheses"] == 2
 
 
