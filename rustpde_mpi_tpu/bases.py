@@ -978,15 +978,16 @@ class Space2:
 
     def forward(self, v):
         """Physical (..., n_x, n_y) -> spectral (..., m_x, m_y)."""
-        from .parallel.mesh import LOCAL, constrain
+        from .parallel.mesh import LOCAL, constrain, flip
 
         ax = self._batch_ax(v)
-        out = v
+        out, at = v, self.physical
         for axis in reversed(self.synthesis_axes):
             out = self.bases[axis].forward(
-                constrain(out, LOCAL[axis]), ax + axis, self._axis_method(axis),
+                flip(out, LOCAL[axis], at), ax + axis, self._axis_method(axis),
                 sep=self.sep[axis],
             )
+            at = LOCAL[axis]
         return constrain(out, self.rest)
 
     # A synthesis in two steps: the first axis of ``synthesis_axes`` alone,
@@ -1031,14 +1032,14 @@ class Space2:
         ``backward_gradient``, of which this step takes its own axis's (None:
         the plain ``backward``); ``fast`` and ``ortho`` as in
         ``_synthesise``."""
-        from .parallel.mesh import LOCAL, constrain
+        from .parallel.mesh import LOCAL, constrain, flip
 
         first, second = self.synthesis_axes
         out = self._synthesise(
             constrain(vhat, LOCAL[first]), first,
             None if deriv is None else deriv[first], fast, ortho,
         )
-        return constrain(out, LOCAL[second])
+        return flip(out, LOCAL[second], LOCAL[first])
 
     def synthesis_finish(self, partial, deriv=None, scale=None, fast=False, ortho=False):
         """The second step: derivative and synthesis along the second axis of
@@ -1076,15 +1077,15 @@ class Space2:
         vector multiply.  Callers keep a ``forward() * mask`` fallback for
         fully non-sep spaces.  ``fast=True`` selects the 3-pass variant
         gated by RUSTPDE_FWD_PRECISION (default off — see Base._sep_dev)."""
-        from .parallel.mesh import LOCAL, constrain
+        from .parallel.mesh import LOCAL, constrain, flip
 
         if not any(self.sep):
             raise ValueError("forward_dealiased requires at least one sep axis")
         ax = self._batch_ax(v)
         key = ("fwd_cut", "fast") if fast else "fwd_cut"
-        out = v
+        out, at = v, self.physical
         for axis in reversed(self.synthesis_axes):
-            out = constrain(out, LOCAL[axis])
+            out, at = flip(out, LOCAL[axis], at), LOCAL[axis]
             if self.sep[axis]:
                 out = self.bases[axis]._sep_dev(key).apply(out, ax + axis)
             else:
@@ -1145,12 +1146,14 @@ class Space2:
     # between them (a velocity and its own chain's derivative: 15 flips a
     # periodic step where each stating its own made 17).
 
-    def _pin(self, a, spec):
+    def _pin(self, a, spec, at=None):
         """``constrain`` for the spectral operators below, unless the model
-        that owns the space cleared ``states_layout`` at its build."""
-        from .parallel.mesh import constrain
+        that owns the space cleared ``states_layout`` at its build.  ``at``:
+        the pencil ``a`` is stated in, where it is another: the pin is then a
+        flip, and counted as one (``parallel.mesh.flip``)."""
+        from .parallel.mesh import flip
 
-        return constrain(a, spec) if self.states_layout else a
+        return flip(a, spec, at) if self.states_layout else a
 
     def _where_local(self, axis: int, apply, c):
         """``apply`` (operators that need the whole extent of ``axis``) on a
@@ -1158,7 +1161,8 @@ class Space2:
         axis local, else between a stated pair of flips."""
         from .parallel.mesh import LOCAL
 
-        return self._pin(apply(self._pin(c, LOCAL[axis])), self.rest)
+        there = LOCAL[axis]
+        return self._pin(apply(self._pin(c, there, self.rest)), self.rest, there)
 
     def _whole(self, axis: int, order: int) -> bool:
         """Whether the ``order``-th derivative along ``axis`` needs the whole

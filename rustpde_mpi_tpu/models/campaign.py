@@ -355,9 +355,11 @@ class CampaignModelBase:
             return converted, replicate(consts)
 
     def _exchanges_per_step(self) -> tuple:
-        """``(exchanges, bytes one device sends)`` of one step's hand-placed
-        pencil transposes; a model with manual regions says (``Navier2D``)."""
-        return 0, 0
+        """``(flips, bytes one device sends)`` of one step: the pencil flips
+        the step states (``parallel.mesh.flip``), counted where it is traced
+        (``_compile_entry_points``); the compiler places their all-to-alls.
+        A model with hand-placed regions counts those (``Navier2D``)."""
+        return getattr(self, "_step_flips", (0, 0))
 
     def _mesh_span_args(self) -> dict:
         """What a meshed model's ``model.update_n`` span says of its
@@ -444,6 +446,8 @@ class CampaignModelBase:
         import jax
         import jax.numpy as jnp
 
+        from ..parallel.mesh import flips
+
         example = self._state_example()
         self.recompile_count += 1
         self._step_n_jit = None
@@ -460,7 +464,9 @@ class CampaignModelBase:
         self._dig_cc = None
         self._dig_consts = None
         self._dig_fn = None
-        step_cc, step_consts = self._hoist(self._make_step(), example)
+        with flips() as tally:
+            step_cc, step_consts = self._hoist(self._make_step(), example)
+        self._step_flips = (tally["flips"], tally["bytes"])
         obs_cc, obs_consts = self._hoist(self._make_observables(), example)
         self._step_consts = step_consts
         self._obs_consts = obs_consts

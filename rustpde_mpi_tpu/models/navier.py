@@ -372,11 +372,11 @@ class Navier2D(CampaignModelBase, Integrate):
         pencil transposes, reckoned from the manual regions' block shapes
         (parallel/decomp.Sharded*): three convection chains (four with the
         passive scalar), the two convection-velocity syntheses and the
-        pressure-Poisson solve.  ``(0, 0)`` where no region is manual: on a
-        GSPMD-partitioned layout the compiler places the collectives and
-        nothing here can count them."""
+        pressure-Poisson solve.  Where no region is manual, the flips the
+        step states, as every model counts them (the compiler places their
+        all-to-alls)."""
         if getattr(self, "_manual_poisson", None) is None:
-            return 0, 0
+            return super()._exchanges_per_step()
         from ..parallel.decomp import sent_bytes
 
         conv_u = self._conv_impl[id(self.velx_space)].exchanges

@@ -25,6 +25,8 @@ duplicated navier_stokes vs navier_stokes_mpi modules collapse into one.
 
 from __future__ import annotations
 
+import threading
+
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
@@ -142,6 +144,45 @@ def constrain(x, spec: tuple):
 
 def _is_tracer(x) -> bool:
     return isinstance(x, jax.core.Tracer)
+
+
+# The flips a program states, counted where it is traced: ``flips()`` opens a
+# tally for the calling thread, and every ``flip`` traced under an active mesh
+# while it is open adds one flip and the bytes one device sends for it.  The
+# compiler places the all-to-alls, so the count is what the program asks for,
+# once per trace; a dispatch pays nothing for it.
+_TALLY = threading.local()
+
+
+class flips:
+    """Context manager: ``{"flips", "bytes"}`` of the ``flip`` calls this
+    thread traces while it is open (nested tallies count apart)."""
+
+    def __enter__(self):
+        self.prev = getattr(_TALLY, "open", None)
+        _TALLY.open = {"flips": 0, "bytes": 0}
+        return _TALLY.open
+
+    def __exit__(self, *exc):
+        _TALLY.open = self.prev
+        return False
+
+
+def flip(x, spec: tuple, at: tuple | None = None):
+    """``constrain(x, spec)`` for an ``x`` stated in the pencil ``at`` (None:
+    in ``spec`` already).  Where the two differ this is a pencil flip, and an
+    open tally (``flips``) counts it with the bytes one device sends, its
+    tiles of ``x`` that other devices hold (the sharded extents padded to the
+    mesh, as the all-to-all moves them), at ``x``'s own itemsize."""
+    out = constrain(x, spec)
+    tally, mesh = getattr(_TALLY, "open", None), active_mesh()
+    if tally is not None and mesh is not None and at not in (None, spec) and _is_tracer(x):
+        p = int(mesh.size)
+        *batch, n0, n1 = np.shape(x)
+        tile = int(np.prod(batch, dtype=np.int64)) * (-(-n0 // p)) * (-(-n1 // p))
+        tally["flips"] += 1
+        tally["bytes"] += tile * (p - 1) * np.dtype(x.dtype).itemsize
+    return out
 
 
 class ReplicatedPencilWarning(UserWarning):

@@ -311,7 +311,7 @@ class HholtzAdi:
         x-factor runs in the space's resting layout: x is local there, or it
         is a diagonal (a Fourier axis) and asks for no layout of its own, so
         such a solve enters, works and returns on the y-pencil."""
-        from .parallel.mesh import LOCAL, constrain
+        from .parallel.mesh import LOCAL, constrain, flip
 
         if rhs.ndim < 2:
             raise ValueError(
@@ -325,11 +325,11 @@ class HholtzAdi:
             out = constrain(rhs, rest)
             if self.matvec[0] is not None:
                 out = self.matvec[0].apply(out, ax)
-            out = constrain(out, LOCAL[1])
+            out = flip(out, LOCAL[1], rest)
             if self.matvec[1] is not None:
                 out = self.matvec[1].apply(out, ax + 1)
             out = self.solvers[1].solve(out, ax + 1)  # axis-1 recurrence
-            out = constrain(out, rest)
+            out = flip(out, rest, LOCAL[1])
             out = self.solvers[0].solve(out, ax)  # axis-0 recurrence
             return constrain(out, rest)
 
@@ -398,7 +398,7 @@ class TensorSolver:
         has no GEMM (its modes are the eigenvalue lanes already): the solve
         stays on the y-pencil its space rests in.  Extra leading dims are
         batch (the per-eigenvalue factors broadcast against them)."""
-        from .parallel.mesh import LOCAL, constrain
+        from .parallel.mesh import LOCAL, constrain, flip
 
         if rhs.ndim < 2:
             raise ValueError(
@@ -410,13 +410,14 @@ class TensorSolver:
             ax = rhs.ndim - 2
             rest = self.rest
             out = constrain(rhs, rest)
+            at = rest
             if self.matvec1 is not None:
-                out = self.matvec1.apply(constrain(out, LOCAL[1]), ax + 1)
-            out = constrain(out, rest)
+                out, at = self.matvec1.apply(flip(out, LOCAL[1], rest), ax + 1), LOCAL[1]
+            out = flip(out, rest, at)
             if self.fwd is not None:
                 out = self.fwd.apply(out, ax)
-            out = self.banded.solve(constrain(out, LOCAL[1]), ax + 1)
-            out = constrain(out, rest)
+            out = self.banded.solve(flip(out, LOCAL[1], rest), ax + 1)
+            out = flip(out, rest, LOCAL[1])
             if self.bwd is not None:
                 out = self.bwd.apply(out, ax)
             return constrain(out, rest)
@@ -474,7 +475,7 @@ class FastDiag:
         where the x-maps are None (a Fourier axis, modal already) there is no
         contraction along x and no flip: all of the work is on the y-pencil
         the space rests in."""
-        from .parallel.mesh import LOCAL, constrain
+        from .parallel.mesh import LOCAL, constrain, flip
 
         if rhs.ndim < 2:
             raise ValueError(
@@ -488,13 +489,13 @@ class FastDiag:
             out = constrain(rhs, rest)
             if self.fwd[0] is not None:
                 out = self.fwd[0].apply(out, ax)
-            out = constrain(out, LOCAL[1])
+            out = flip(out, LOCAL[1], rest)
             if self.fwd[1] is not None:
                 out = self.fwd[1].apply(out, ax + 1)
             out = out / self.denom.astype(out.dtype)
             if self.bwd[1] is not None:
                 out = self.bwd[1].apply(out, ax + 1)
-            out = constrain(out, rest)
+            out = flip(out, rest, LOCAL[1])
             if self.bwd[0] is not None:
                 out = self.bwd[0].apply(out, ax)
             return constrain(out, rest)

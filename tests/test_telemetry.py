@@ -182,15 +182,17 @@ def test_flight_recorder_spans_ring_and_dump(tmp_path):
 
 
 def test_span_records_and_annotates_errors():
-    before = len(ttracing.RECORDER.events())
-    with telemetry.span("outer", step=3):
+    with telemetry.span("outer", step=3) as outer:
         pass
     with pytest.raises(RuntimeError):
-        with telemetry.span("failing"):
+        with telemetry.span("failing") as failing:
             raise RuntimeError("boom")
-    events = ttracing.RECORDER.events()
-    assert len(events) >= before + 2
-    named = {e["name"]: e for e in events[-4:]}
+    # the ring is the process's, shared with every other span and bounded (a
+    # full one keeps its length): the two are found by their names and ids
+    mine = {("outer", outer.id), ("failing", failing.id)}
+    named = {e["name"]: e for e in ttracing.RECORDER.events()
+             if (e["name"], e["args"].get("id")) in mine}
+    assert set(named) == {"outer", "failing"}
     # the call site's args, beside the span's identity (id, parent)
     assert named["outer"]["args"]["step"] == 3
     assert named["outer"]["args"]["parent"] is None
