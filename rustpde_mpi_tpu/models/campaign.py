@@ -405,7 +405,7 @@ class CampaignModelBase:
         ROADMAP item needs that attribution separated from build time."""
         from ..parallel.mesh import unplaced
         from ..telemetry import compile_log
-        from ..ops.folded import sliced_products
+        from ..ops.folded import sliced_f64_multiplies, sliced_products
         from ..utils.jit import dot_generals_by_operand, gathers, reverses
 
         seam = _tr.timed("model.compile_entry_points", layer=_LAYER, consts=0, const_bytes=0)
@@ -426,6 +426,7 @@ class CampaignModelBase:
                     "f64_products": products.get("float64", 0),
                     "f32_products": products.get("float32", 0),
                     "sliced_products": sliced_products(self._step_cc.jaxpr),
+                    "sliced_f64_multiplies": sliced_f64_multiplies(self._step_cc.jaxpr),
                     "int8_products": products.get("int8", 0),
                     "reverses": reverses(self._step_cc.jaxpr),
                     "gathers": gathers(self._step_cc.jaxpr),

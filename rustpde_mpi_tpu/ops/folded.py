@@ -226,7 +226,8 @@ def _unmove(a, axis):
 # magnitudes under 1/2 and cut into ``SLICES`` balanced base-128 digits,
 # integers in [-64, 64]: ``x = sum_s d_s 2^(-7(s+1))`` up to 2^(-7 SLICES).
 # The operator is cut once, on the host, at build; a field once per product,
-# in one vectorised pass over its float32 pieces.
+# in one vectorised pass over its float32 pieces, which its powers of two
+# scale in float32.
 #
 # The slice pairs (s, t) with s + t = d make the partial ``P_d``, exact in
 # int32 ((d + 1) K terms of at most 64 x 96).  The pairs with
@@ -238,7 +239,14 @@ def _unmove(a, axis):
 # and each run is ONE product over the rows it reaches, which leaves
 # 45 + 16 of the 81 blocks a single product would stream, for two products.
 # The partials are summed with carries in int32 and turned into float64 in
-# four exact float32 pieces and three float64 additions.
+# four exact float32 pieces, each scaled by the row's and the column's powers
+# of two in float32, and three float64 additions.  A sliced product states no
+# float64 multiply (the lone columns' one-row updates aside): the chip would
+# emulate each as a general two-word product over a whole field, where a
+# power of two scales a normal float32 exactly.  So the result is bitwise that
+# of scaling the operand and the sum in float64 wherever every scaled piece is
+# a normal float32; a piece under 2^-126 is flushed, as the chip's float64
+# flushes its low word there (``_recombine``).
 #
 # A parity fold's two halves (and a trapezoid block's strips) are one sliced
 # product over a batch (the smaller operands padded with zeros), so a fold
@@ -345,70 +353,142 @@ def slice_operator(mats):
             for t in range(t0, min(t1, d + 1)):
                 blocks[:, d - t0, :, t - t0, :] = digits[:, d - t]
         toeplitz.append(blocks)
+    # the rows' scales multiply float32 pieces (``_recombine``)
+    if not np.all((scale >= 2.0**-126) & (scale < 2.0**127)):
+        raise ValueError("an operator row's scale is outside float32's normal range")
     return SlicedOperator(tuple(toeplitz), scale, balance, tuple(lone), rows, cols)
 
 
-def _column_digits(x, balance):
+def _column_scale(x, balance):
     """Fields ``(B, K, ...)`` float64 and the operators' ``balance``
-    ``(B, K)`` (float32: :func:`slice_operator`) -> the digits
-    ``(B, SLICES, K, ...)`` int8 of the balanced fields, most significant
-    first, and each column's scale ``(B, 1, ...)`` (float32): the power of
-    two that brings the column's largest balanced element under 1/2.
+    ``(B, K)`` (float32: :func:`slice_operator`) -> ``balance`` expanded to
+    the fields' rank and each column's scale ``(B, 1, ...)`` (float32): the
+    power of two that brings the column's largest balanced element into
+    [1/4, 1/2).
 
-    One float64 product scales a field (by ``balance`` times the column's
-    power of two) and two float64 subtractions cut it into three float32
-    pieces (``x = hi + mid + lo`` exactly, 72 bits for 53; on the chip a
-    float64 IS the pair hi + mid); the rest is float32 arithmetic on the
-    pieces, their digits summed.  A piece is under half an ulp of the one
-    before, so in one slot at most two pieces have digits, of at most 64 and
-    32: a slot's digit stays within [-96, 96].  Fields are held under 2^75."""
+    It is read off the exponent field E of that element in float32: a scale
+    2^(125 - E), held to [2^-76, 2^124] (E in [1, 201]: a column of zeros, or
+    of values float32 flushes, gets 2^124 and stays under 1/4, as
+    balance <= 1), so that ``balance * scale`` (balance >= 2^-48) is a
+    normal float32.  Fields are held under 2^75."""
     lax, f32 = jax.lax, jnp.float32
     balance = lax.expand_dims(balance, tuple(range(2, x.ndim)))
-    # the exponent field E of the column's largest balanced |x| (read off
-    # float32): a scale 2^(125 - E) brings it into [1/4, 1/2), held to
-    # [2^-76, 2^124] (E in [1, 201]: a column of zeros, or of values float32
-    # flushes, gets 2^124 and stays under 1/4, as balance <= 1).  Balance and
-    # scale are one float32 power of two in range, one float64 product,
-    # before the pieces are cut, so that no piece of a small column falls
-    # below float32's range
     largest = lax.reduce_max(lax.abs(lax.convert_element_type(x, f32)) * balance, (1,))
     biased = lax.shift_right_logical(lax.bitcast_convert_type(largest, jnp.int32), np.int32(23))
     biased = lax.clamp(np.int32(1), biased, np.int32(201))
     scale = lax.bitcast_convert_type(lax.shift_left(np.int32(252) - biased, np.int32(23)), f32)
-    scale = lax.expand_dims(scale, (1,))
-    x = x * lax.convert_element_type(balance * scale, x.dtype)
+    return balance, lax.expand_dims(scale, (1,))
+
+
+def _pieces(x):
+    """Float64 ``x`` -> three float32 pieces, ``x = hi + mid + lo`` exactly
+    (72 bits for 53, each piece under half an ulp of the one before) wherever
+    they are normal floats; on the chip a float64 IS the pair hi + mid."""
+    lax, f32 = jax.lax, jnp.float32
     hi = lax.convert_element_type(x, f32)
     rest = lax.sub(x, lax.convert_element_type(hi, x.dtype))
     mid = lax.convert_element_type(rest, f32)
     lo = lax.convert_element_type(lax.sub(rest, lax.convert_element_type(mid, x.dtype)), f32)
-    digits = [_digits(lax.expand_dims(piece, (1,)), jnp, axis=1) for piece in (hi, mid, lo)]
+    return hi, mid, lo
+
+
+def _column_digits(x, balance):
+    """Fields ``(B, K, ...)`` float64 and the operators' ``balance``
+    ``(B, K)`` -> the digits ``(B, SLICES, K, ...)`` int8 of the balanced
+    fields, most significant first, and each column's scale ``(B, 1, ...)``
+    (float32) inverted (:func:`_column_scale`).
+
+    Two float64 subtractions cut a field into its three float32 pieces
+    (:func:`_pieces`); each piece is multiplied by the float32 power of two
+    ``balance * scale``, and the rest is float32 arithmetic on the pieces,
+    their digits summed: no float64 multiply.  A piece is under half an ulp
+    of the one before, so in one slot at most two pieces have digits, of at
+    most 64 and 32: a slot's digit stays within [-96, 96].  A piece has
+    digits only where it is 2^-64 or more once scaled, so it is a normal
+    float32 before the scaling, and the digits are bitwise those of the
+    field scaled in float64, wherever ``scale`` is at most 2^62 (the
+    column's largest balanced element 2^-64 or more)."""
+    lax = jax.lax
+    balance, scale = _column_scale(x, balance)
+    factor = balance * scale
+    digits = [_digits(lax.expand_dims(piece * factor, (1,)), jnp, axis=1) for piece in _pieces(x)]
     digits = lax.add(lax.add(*digits[:2]), digits[2])
     return lax.convert_element_type(digits, jnp.int8), 1 / scale
 
 
-def _recombine(partials, scale, column):
-    """``sum_d 2^(-7 d) P_d`` times each row's and column's scale, in
-    float64: the int32 partials summed with carries into one integer and
-    ``SLICES - 1`` digits of 7 bits, the digits packed three to a float32 (21
-    bits: exact), and the four pieces added in float64."""
-    lax, f64 = jax.lax, jnp.float64
+def _whole_bound(k: int) -> int:
+    """A bound on ``|P_0 + carry|`` (:func:`_carried`) for ``k`` contracted
+    terms: ``P_d`` sums ``(d + 1) k`` products of an operator digit (at most
+    64) and a field digit (at most 96), and the carry into ``P_d`` is the
+    arithmetic shift of the sum below it by 7 bits."""
+    carry = 0
+    for d in range(SLICES - 1, 0, -1):
+        carry = -(-((d + 1) * k * 64 * 96 + carry) // 2**_SLICE_BITS)
+    return k * 64 * 96 + carry
+
+
+def _carried(partials):
+    """The int32 partials summed with carries: one integer ``P_0 + carry``
+    (int32) and ``SLICES - 1`` digits of 7 bits, packed three to a float32
+    (21 bits: exact) and weighted by their powers of two (exact), so that
+    ``sum_d 2^(-7 d) P_d`` is the integer plus the pieces."""
+    lax = jax.lax
     carry = None
     rest = [None] * SLICES
     for d in range(SLICES - 1, 0, -1):
         v = partials[d] if carry is None else lax.add(partials[d], carry)
         carry = lax.shift_right_arithmetic(v, np.int32(_SLICE_BITS))
         rest[d] = lax.sub(v, lax.shift_left(carry, np.int32(_SLICE_BITS)))  # in [0, 128)
-    total = lax.convert_element_type(lax.add(partials[0], carry), f64)
+    pieces = []
     for first in range(1, SLICES, 3):
         run = range(first, min(first + 3, SLICES))
         packed = rest[run[0]]
         for d in run[1:]:
             packed = lax.add(lax.shift_left(packed, np.int32(_SLICE_BITS)), rest[d])
         weight = np.float32(2.0 ** (-_SLICE_BITS * run[-1]))
-        piece = lax.mul(lax.convert_element_type(packed, jnp.float32), weight)
-        total = lax.add(total, lax.convert_element_type(piece, f64))
-    rows = lax.expand_dims(scale, tuple(range(2, total.ndim)))
-    return total * (rows * lax.convert_element_type(column, f64))
+        pieces.append(lax.mul(lax.convert_element_type(packed, jnp.float32), weight))
+    return lax.add(partials[0], carry), pieces
+
+
+#: low bits of the integer ``P_0 + carry`` split off into a word of its own
+#: where it may not be exact in float32 (:func:`_recombine`)
+_WHOLE_SPLIT = 12
+
+
+def _recombine(partials, scale, column, k: int):
+    """``sum_d 2^(-7 d) P_d`` (:func:`_carried`) times each row's and
+    column's scale, in float64, for ``k`` contracted terms.
+
+    The two scales are one float32 power of two an element, ``factor``, and
+    multiply the float32 pieces before each is turned into float64: the
+    integer whole (exact in float32 while |P_0 + carry| < 2^24, which
+    :func:`_whole_bound` holds for K up to ~2600; a wider contraction splits
+    it into two exact float32 words, whose float64 sum is exact) and the
+    three packed runs of digits.  The float64 additions are the only float64
+    arithmetic, in the order the pieces come.  A power of two scales a
+    normal float32 exactly and a float64 sum's rounding with it, so the
+    result is bitwise that of scaling the float64 sum, wherever every scaled
+    piece is a normal float32: the smallest is 2^-56 of ``factor``, normal
+    while ``factor`` is at least 2^-70.  Below that (an operator row and a
+    field column whose largest elements multiply to under ~2^-60) a piece
+    under 2^-126 is flushed, which moves an output by under 2^-124: a loss
+    of relative precision only in outputs under ~2^-70, and under ~2^-102
+    none that the chip's two-word float64 holds (its low word is flushed
+    there too)."""
+    lax, f32, f64 = jax.lax, jnp.float32, jnp.float64
+    whole, pieces = _carried(partials)
+    rows = lax.expand_dims(lax.convert_element_type(scale, f32), tuple(range(2, whole.ndim)))
+    factor = rows * column
+    if _whole_bound(k) < 2**24:
+        total = lax.convert_element_type(lax.convert_element_type(whole, f32) * factor, f64)
+    else:
+        split = np.int32(_WHOLE_SPLIT)
+        high = lax.shift_left(lax.shift_right_arithmetic(whole, split), split)
+        words = [lax.convert_element_type(w, f32) * factor for w in (high, lax.sub(whole, high))]
+        total = lax.add(*(lax.convert_element_type(w, f64) for w in words))
+    for piece in pieces:
+        total = lax.add(total, lax.convert_element_type(piece * factor, f64))
+    return total
 
 
 @partial(jax.jit, static_argnames=("lone", "rows"))
@@ -432,7 +512,7 @@ def sliced_product(toeplitz, scale, balance, xs, lone, rows):
         for d in range(t0, SLICES):
             part = lax.index_in_dim(out, d - t0, axis=1, keepdims=False)
             partials[d] = part if partials[d] is None else lax.add(partials[d], part)
-    y = _recombine(partials, scale, column)
+    y = _recombine(partials, scale, column, k)
     out = [lax.slice_in_dim(y[b], 0, r, axis=0) for b, r in enumerate(rows)]
     for b, row, col, value in lone:  # static: slices, no gather
         update = out[b][row : row + 1] + np.float64(value) * xs[b][col : col + 1]
@@ -452,6 +532,27 @@ def sliced_products(jaxpr) -> int:
         for eqn in equations(jaxpr)
         if eqn.params.get("name") == SLICED_PRODUCT
     )
+
+
+def sliced_f64_multiplies(jaxpr) -> int:
+    """The float64 ``mul`` equations inside the sliced products that
+    ``jaxpr`` states (:func:`sliced_products`), the lone columns' one-row
+    updates left out: those are of a field's rank, where the stacked fields
+    and everything made of them have one axis more."""
+    from ..utils.jit import equations
+
+    count = 0
+    for eqn in equations(jaxpr):
+        if eqn.params.get("name") != SLICED_PRODUCT:
+            continue
+        rank = eqn.invars[len(_GROUPS) + 2].aval.ndim  # a field
+        count += sum(
+            inner.primitive.name == "mul"
+            and inner.outvars[0].aval.dtype == np.float64
+            and inner.outvars[0].aval.ndim > rank
+            for inner in equations(eqn.params["jaxpr"].jaxpr)
+        )
+    return count
 
 
 def _products(ops, xs, precision=None):

@@ -14,8 +14,9 @@ suite's process is float64, the configuration's own precision):
   configuration's;
 * the ``sliced_products`` / ``int8_products`` / ``f64_products`` /
   ``f32_products`` counts of the ``model.update_n`` span against a hand count
-  of one confined step's products, the reader of ``f64_products``, and that
-  ``RUSTPDE_SOLVE_PRECISION`` leaves a float64 step as it is.
+  of one confined step's products, the readers of ``f64_products`` and of
+  ``sliced_f64_multiplies``, and that ``RUSTPDE_SOLVE_PRECISION`` leaves a
+  float64 step as it is.
 """
 
 import importlib.util
@@ -30,7 +31,7 @@ import pytest
 from benchmark import check, run
 from benchmark.drivers import interval_f64
 from benchmark.ic import smooth_fields
-from benchmark.layer_metrics import f64_products_per_step
+from benchmark.layer_metrics import f64_products_per_step, sliced_f64_multiplies_per_step
 from rustpde_mpi_tpu import Navier2D, config
 from rustpde_mpi_tpu.ops import folded as folded_ops
 from rustpde_mpi_tpu.telemetry import FlightRecorder
@@ -190,6 +191,7 @@ def test_span_counts_one_steps_products_by_operand_type(monkeypatch, ring, fold_
     assert args["int8_products"] == len(folded_ops._GROUPS) * calls <= folded_ops.SLICES * products
     assert args["f64_products"] == 0
     assert args["f32_products"] == 0
+    assert args["sliced_f64_multiplies"] == 0
     assert args["reverses"] == (TRANSFORMS if folded else 0)
     # counted in the traced step, once: the chunk's scan does not multiply it
     model.update_n(8)
@@ -199,6 +201,26 @@ def test_span_counts_one_steps_products_by_operand_type(monkeypatch, ring, fold_
     # a span without the count (the parent commit's) reads nothing
     ring.add_complete("model.update_n", ring.now_us(), 5.0, {"id": 9, "parent": None, "steps": 8})
     assert f64_products_per_step.read({}, {"traced_dispatches": 1}) is None
+
+
+def test_sliced_f64_multiplies_reader(monkeypatch, ring):
+    """``sliced_f64_multiplies_per_step`` reads the mean of the span's count
+    over the traced dispatches: 0 where a step's sliced products state no
+    float64 multiply; nothing where a traced span lacks the count (the
+    parent commit's), or where the ring holds fewer spans than were traced."""
+    monkeypatch.setenv("RUSTPDE_FORCE_TPU_PATH", "1")
+    model = Navier2D.new_confined(17, 17, RA, PR, DT, ASPECT, "rbc")
+    model.init_random(0.1, seed=0)
+    model.update_n(2)
+    model.update_n(4)
+    assert model._step_products["sliced_products"] > 0
+    assert sliced_f64_multiplies_per_step.read({}, {"traced_dispatches": 2}) == 0.0
+    assert sliced_f64_multiplies_per_step.read({}, {"traced_dispatches": 3}) is None
+    args = {"parent": None, "steps": 8, "sliced_f64_multiplies": 126}
+    ring.add_complete("model.update_n", ring.now_us(), 5.0, dict(args, id=7))
+    assert sliced_f64_multiplies_per_step.read({}, {"traced_dispatches": 2}) == 63.0
+    ring.add_complete("model.update_n", ring.now_us(), 5.0, {"id": 9, "parent": None, "steps": 8})
+    assert sliced_f64_multiplies_per_step.read({}, {"traced_dispatches": 1}) is None
 
 
 def test_products_are_counted_inside_nested_programs():

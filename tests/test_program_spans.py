@@ -639,4 +639,4 @@ def test_benchmark_selfcheck_passes(capsys):
     from benchmark import selfcheck
 
     assert selfcheck.main([]) == 0
-    assert "8 cells, 25 readers agree" in capsys.readouterr().out
+    assert "8 cells, 26 readers agree" in capsys.readouterr().out

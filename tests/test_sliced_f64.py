@@ -16,6 +16,7 @@ import json
 import os
 import subprocess
 import sys
+import types
 import weakref
 
 import jax
@@ -27,6 +28,7 @@ from rustpde_mpi_tpu import Navier2D, bases, config, solver
 from rustpde_mpi_tpu.models.swift_hohenberg import SwiftHohenberg2D
 from rustpde_mpi_tpu.ops import chebyshev as chb
 from rustpde_mpi_tpu.ops import folded
+from rustpde_mpi_tpu.utils.jit import equations
 
 pytestmark = pytest.mark.skipif(not config.X64, reason="float64 products")
 
@@ -68,23 +70,29 @@ def _product_groups(impl):
     return [[impl.mat]] if getattr(impl, "mat", None) is not None else []
 
 
-def _field(kind: str, k: int, rng) -> np.ndarray:
+def _field(kind: str, k: int, rng, columns=None) -> np.ndarray:
+    """Six columns of ``k`` values: physical, or spectral coefficients
+    decaying from 1 to 1e-16 along the contraction; ``columns`` multiplies
+    column j by ``columns[j]``."""
     if kind == "physical":
-        return rng.uniform(-1.0, 1.0, (k, 6))
-    decay = 10.0 ** (-16.0 * np.arange(k) / max(k - 1, 1))
-    return rng.standard_normal((k, 6)) * decay[:, None]
+        x = rng.uniform(-1.0, 1.0, (k, 6))
+    else:
+        decay = 10.0 ** (-16.0 * np.arange(k) / max(k - 1, 1))
+        x = rng.standard_normal((k, 6)) * decay[:, None]
+    return x if columns is None else x * np.asarray(columns)[None, :]
 
 
 def _rel(y, exact) -> float:
     return float(np.linalg.norm(np.asarray(y, np.longdouble) - exact) / np.linalg.norm(exact))
 
 
+#: the operator kinds of the 513 x 513 float64 step (:func:`_cell_operators`)
+KINDS = ["synthesis", "analysis", "helmholtz", "fastdiag_fwd", "fastdiag_bwd",
+         "projection_gradient", "trapezoid"]
+
+
 @pytest.mark.parametrize("field", ["physical", "spectral"])
-@pytest.mark.parametrize(
-    "kind",
-    ["synthesis", "analysis", "helmholtz", "fastdiag_fwd", "fastdiag_bwd",
-     "projection_gradient", "trapezoid"],
-)
+@pytest.mark.parametrize("kind", KINDS)
 def test_sliced_product_is_as_accurate_as_a_float64_dot(kind, field):
     mat, sep_in, sep_out, keep = _cell_operators()[kind]
     impl = folded._detect(np.asarray(mat), sep_in, sep_out, keep, 8)
@@ -150,6 +158,181 @@ def test_slices_carry_53_bits_and_the_guard_bits():
     assert np.abs((weights[:, None] * digits).sum(axis=0) - x).max() <= 2.0**-64
     widest = (N + 1) // 2  # a fold's half of the 513-point axis
     assert folded.SLICES * widest * 64 * 96 < 2**31
+
+
+# -- the scalings on the float32 pieces -------------------------------------------------
+
+
+def _multiplied_digits(x, balance):
+    """The field side with its scaling as a float64 product: the field scaled
+    by ``balance * scale``, then cut into its pieces."""
+    balance, scale = folded._column_scale(x, balance)
+    x = x * jax.lax.convert_element_type(balance * scale, x.dtype)
+    digits = sum(folded._digits(jnp.expand_dims(p, 1), jnp, axis=1) for p in folded._pieces(x))
+    return digits.astype(jnp.int8), 1 / scale
+
+
+def _multiplied_sum(partials, scale, column, k):
+    """The output side with its scalings as float64 products: the float64
+    sum of the pieces made first, then times the row's and column's scale."""
+    whole, pieces = folded._carried(partials)
+    total = whole.astype(jnp.float64)
+    for piece in pieces:
+        total = total + piece.astype(jnp.float64)
+    rows = jnp.expand_dims(scale, tuple(range(2, total.ndim)))
+    return total * (rows * column.astype(jnp.float64))
+
+
+def _multiplied_product(monkeypatch):
+    """:func:`folded.sliced_product` with its scalings as float64 products,
+    traced afresh: a new function object of the same code and name (jit's
+    trace cache keys on the function), whose module names are the two
+    above while ``monkeypatch`` holds them."""
+    monkeypatch.setattr(folded, "_column_digits", _multiplied_digits)
+    monkeypatch.setattr(folded, "_recombine", _multiplied_sum)
+    f = folded.sliced_product.__wrapped__
+    fresh = types.FunctionType(f.__code__, f.__globals__, f.__name__, f.__defaults__, f.__closure__)
+    return jax.jit(fresh, static_argnames=("lone", "rows"))
+
+
+def _both_forms(op, xs, monkeypatch):
+    """The sliced product of ``op`` on ``xs`` as it is and with its
+    scalings as float64 products (the oracle), the lone columns left out of
+    both: their one-row update is the same code in either, and the CPU's
+    compiler contracts it into a fused multiply-add with whichever product
+    it meets."""
+    args = (op.toeplitz, op.scale, op.balance, tuple(jnp.asarray(x) for x in xs))
+    got = folded.sliced_product(*args, lone=(), rows=op.rows)
+    with monkeypatch.context() as m:
+        want = _multiplied_product(m)(*args, lone=(), rows=op.rows)
+    return [np.asarray(y) for y in got], [np.asarray(y) for y in want]
+
+
+def _normal_pieces(op, xs):
+    """``(B, r, ...)``: where every scaled piece of the product is a normal
+    float32.  On the field side a piece that has digits is at least 2^-64
+    after scaling, so it is normal before it wherever the column's scale is
+    at most 2^62; on the output side the smallest piece is 2^-56 of the
+    row's and column's scales (the last run's weight), normal wherever they
+    multiply to at least 2^-70."""
+    k = op.toeplitz[0].shape[-1]
+    x = np.stack([np.pad(v, [(0, k - v.shape[0])] + [(0, 0)] * (v.ndim - 1)) for v in xs])
+    _, scale = folded._column_scale(jnp.asarray(x), jnp.asarray(op.balance))
+    column = 1 / np.asarray(scale, np.float64)
+    rows = np.asarray(op.scale).reshape(op.scale.shape + (1,) * (x.ndim - 2))
+    return (column >= 2.0**-62) & (rows * column >= 2.0**-70)
+
+
+def _flushed(m):
+    """What flushed pieces can move each output row of ``m``'s product: under
+    2^-124 on the output side (four pieces, each under 2^-126), and on the
+    field side under 2^-125 an element (two pieces) times the row's
+    absolute sum."""
+    return 2.0**-124 * (1 + np.abs(m).sum(axis=1))
+
+
+def _bits(a):
+    return np.asarray(a, np.float64).view(np.int64)
+
+
+@pytest.mark.parametrize("field", ["physical", "spectral", "columns 1e-20 to 1e20"])
+@pytest.mark.parametrize("kind", KINDS)
+def test_scalings_on_the_pieces_are_bitwise_the_float64_products(kind, field, monkeypatch):
+    """Applying the powers of two to the float32 pieces in float32, in place
+    of float64 products on the field and on the sum, changes no bit of the
+    sliced product wherever every scaled piece is a normal float32: a power
+    of two scales a normal float32 exactly, the cut into pieces and each
+    float64 sum's rounding with it.  That holds for every element of
+    physical and decaying fields; of columns scaled from 1e-20 to 1e20 it
+    leaves out the column under 2^-64 and the outputs whose row and column
+    scales multiply to under 2^-70 (:func:`_normal_pieces`), where a scaled
+    piece under 2^-126 may be flushed (the CPU flushes float32 subnormals,
+    and the chip's float64 holds no word below 2^-126), which moves such an
+    output by no more than :func:`_flushed`."""
+    mat, sep_in, sep_out, keep = _cell_operators()[kind]
+    impl = folded._detect(np.asarray(mat), sep_in, sep_out, keep, 8)
+    rng = np.random.default_rng(abs(hash((kind, field))) % 2**32)
+    columns = np.logspace(-20, 20, 6) if field.startswith("columns") else None
+    place = folded._Place(jnp.asarray, sliced=True)
+    normal = []
+    for mats in _product_groups(impl):
+        xs = [_field("physical" if field == "physical" else "spectral", m.shape[1], rng, columns)
+              for m in mats]
+        op = place.group(*mats)
+        got, want = _both_forms(op, xs, monkeypatch)
+        mask = _normal_pieces(op, xs)
+        for b, (m, y, oracle) in enumerate(zip(mats, got, want)):
+            inside = np.broadcast_to(mask[b, : y.shape[0]], y.shape)
+            assert np.array_equal(_bits(y)[inside], _bits(oracle)[inside]), (kind, field, b)
+            flushed = _flushed(m)[:, None] + 0 * y
+            assert np.all(np.abs(y - oracle)[~inside] <= flushed[~inside]), (kind, field, b)
+            normal.append(inside)
+    normal = np.concatenate(normal)
+    if columns is None:
+        assert normal.all()
+    else:  # only outputs of the columns at 1e-20 and 1e-12 fall outside
+        assert normal[:, columns >= 1e-4].all() and not normal.all()
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_columns_far_from_one_are_as_accurate_as_a_float64_dot(kind):
+    """Field columns from 1e-30 up to 1e22, the top of the fields' range
+    (the exponent read-off holds a field under 2^75, as it did before the
+    scalings moved to the pieces): where a scaled piece falls below 2^-126
+    it is flushed, which a column at 1e-30 meets on both sides.  The product
+    stays within twice numpy's float64 product's error, normwise, and each
+    column within that plus what the flushed pieces can move its outputs
+    (:func:`_flushed`)."""
+    mat, sep_in, sep_out, keep = _cell_operators()[kind]
+    impl = folded._detect(np.asarray(mat), sep_in, sep_out, keep, 8)
+    rng = np.random.default_rng(abs(hash(kind)) % 2**32)
+    columns = np.logspace(-30, 22, 6)
+    place = folded._Place(jnp.asarray, sliced=True)
+    for mats in _product_groups(impl):
+        xs = [_field("spectral", m.shape[1], rng, columns) for m in mats]
+        got = folded._products(place.group(*mats), [jnp.asarray(x) for x in xs])
+        for m, x, y in zip(mats, xs, got):
+            exact = m.astype(np.longdouble) @ x.astype(np.longdouble)
+            sliced, native = _rel(y, exact), _rel(m @ x, exact)
+            assert sliced <= 2 * native, (kind, m.shape, sliced, native)
+            err = np.linalg.norm(np.asarray(y, np.longdouble) - exact, axis=0)
+            numpy_err = np.linalg.norm((m @ x).astype(np.longdouble) - exact, axis=0)
+            flushed = np.linalg.norm(_flushed(m))
+            assert np.all(err <= 2 * numpy_err + flushed), (kind, m.shape, err, numpy_err, flushed)
+
+
+def test_a_sliced_product_states_no_float64_multiply(monkeypatch):
+    """One sliced product's program holds no float64 multiply but the lone
+    columns' one-row updates (which ``sliced_f64_multiplies`` leaves out),
+    where the form with float64 products held three, each over a whole
+    field; the integer ``P_0 + carry`` is exact in one float32 at the widest
+    contraction of the float64 cells (a 1025-point Chebyshev axis), and a
+    wider one splits it into two words, still bitwise the float64 sum."""
+    mat, sep_in, sep_out, keep = _cell_operators()["fastdiag_bwd"]
+    impl = folded._detect(np.asarray(mat), sep_in, sep_out, keep, 8)
+    (mats, *_) = _product_groups(impl)
+    op = folded._Place(jnp.asarray, sliced=True).group(*mats)
+    assert op.lone  # the null mode's column
+    xs = tuple(jax.ShapeDtypeStruct((m.shape[1], 6), jnp.float64) for m in mats)
+    jaxpr = jax.make_jaxpr(lambda *xs: folded._products(op, xs))(*xs).jaxpr
+    assert folded.sliced_products(jaxpr) == len(mats)
+    assert folded.sliced_f64_multiplies(jaxpr) == 0
+    muls = [e for e in equations(jaxpr) if e.primitive.name == "mul"
+            and e.outvars[0].aval.dtype == np.float64]
+    assert len(muls) == len(op.lone) and all(e.outvars[0].aval.shape[0] == 1 for e in muls)
+    with monkeypatch.context() as m:
+        fresh = _multiplied_product(m)
+        old = jax.make_jaxpr(lambda *xs: fresh(op.toeplitz, op.scale, op.balance, xs,
+                                               lone=op.lone, rows=op.rows))(*xs).jaxpr
+    assert folded.sliced_f64_multiplies(old) == 3
+    assert folded._whole_bound(2 * N - 1) < 2**24
+    # a contraction past ~2600 terms: the integer in two exact float32 words
+    wide = np.random.default_rng(5).standard_normal((7, 3000))
+    assert folded._whole_bound(wide.shape[1]) >= 2**24
+    op = folded._Place(jnp.asarray, sliced=True).group(wide)
+    x = np.random.default_rng(6).uniform(-1.0, 1.0, (3000, 4))
+    (got,), (want,) = _both_forms(op, [x], monkeypatch)
+    assert np.array_equal(_bits(got), _bits(want))
 
 
 # -- whole models on the forced TPU path ----------------------------------------------
@@ -259,15 +442,21 @@ def test_cell_step_states_its_float64_products_as_sliced_products(monkeypatch, f
     """At 513 x 513 (a CPU count, nothing runs): the float64 step states as
     sliced products the products the float32 step states as float32 dots,
     leaves none to XLA's float64 dot, and needs at most ``SLICES`` int8
-    products for each."""
+    products for each; its sliced products state no float64 multiply, where
+    with their scalings as float64 products they stated three a call."""
     monkeypatch.setenv("RUSTPDE_FORCE_TPU_PATH", "1")
     monkeypatch.setattr(bases, "_BASE_CACHE", weakref.WeakValueDictionary())
-    counts = Navier2D.new_confined(N, N, 1e5, 1.0, 0.01, 1.0, "rbc")._step_products
+    build = lambda: Navier2D.new_confined(N, N, 1e5, 1.0, 0.01, 1.0, "rbc")._step_products  # noqa: E731
+    counts = build()
     f32 = float32_programs["products 513"]
     assert f32["f64_products"] == 0 and f32["f32_products"] == 84
-    assert f32["sliced_products"] == f32["int8_products"] == 0
+    assert f32["sliced_products"] == f32["int8_products"] == f32["sliced_f64_multiplies"] == 0
     assert counts["sliced_products"] == f32["f32_products"]
     assert counts["f64_products"] == counts["f32_products"] == 0
     assert 0 < counts["int8_products"] <= folded.SLICES * counts["sliced_products"]
     assert counts["reverses"] == f32["reverses"]
+    assert counts["sliced_f64_multiplies"] == 0
+    calls = counts["int8_products"] // len(folded._GROUPS)
+    monkeypatch.setattr(folded, "sliced_product", _multiplied_product(monkeypatch))
+    assert build()["sliced_f64_multiplies"] == 3 * calls == 126
 
