@@ -405,7 +405,7 @@ class CampaignModelBase:
         ROADMAP item needs that attribution separated from build time."""
         from ..parallel.mesh import unplaced
         from ..telemetry import compile_log
-        from ..ops.folded import sliced_f64_multiplies, sliced_products
+        from ..ops.folded import int8_blocks, sliced_f64_multiplies, sliced_products
         from ..utils.jit import dot_generals_by_operand, gathers, reverses
 
         seam = _tr.timed("model.compile_entry_points", layer=_LAYER, consts=0, const_bytes=0)
@@ -417,8 +417,8 @@ class CampaignModelBase:
                 # type, counted once per pass for the ``update_n`` spans (the
                 # ensemble's too): which arithmetic the step's products were
                 # compiled in (float64 ones on the TPU path as sliced products,
-                # ops/folded.py, and the int8 products behind them), how many
-                # array flips its parity folds brought,
+                # ops/folded.py, the int8 products behind them and the operator
+                # slices those stream), how many array flips its parity folds brought,
                 # how many index gathers (the circular folds of the periodic
                 # axes), and how many first-axis syntheses served two consumers
                 products = dot_generals_by_operand(self._step_cc.jaxpr)
@@ -428,6 +428,7 @@ class CampaignModelBase:
                     "sliced_products": sliced_products(self._step_cc.jaxpr),
                     "sliced_f64_multiplies": sliced_f64_multiplies(self._step_cc.jaxpr),
                     "int8_products": products.get("int8", 0),
+                    "int8_blocks": int8_blocks(self._step_cc.jaxpr),
                     "reverses": reverses(self._step_cc.jaxpr),
                     "gathers": gathers(self._step_cc.jaxpr),
                     "shared_syntheses": self._shared_syntheses,

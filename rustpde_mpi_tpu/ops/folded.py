@@ -66,12 +66,15 @@ Enable/disable with RUSTPDE_FOLDED (default on).
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
+
 from .. import config
 
 # Structure detection tolerance.  Every foldable matrix in this framework is
@@ -232,14 +235,13 @@ def _unmove(a, axis):
 # The slice pairs (s, t) with s + t = d make the partial ``P_d``, exact in
 # int32 ((d + 1) K terms of at most 64 x 96).  The pairs with
 # s + t >= SLICES are left out: each is below 2^(-7 SLICES) of the row's and
-# the column's scale, as is what the cut leaves of either operand.  The
-# partials are int8 products of the operator's slices laid out as a block
-# Toeplitz matrix (block (d, t) holds slice d - t, zero above the diagonal)
-# against the field's slices: ``_GROUPS`` splits the field's slices into runs
-# and each run is ONE product over the rows it reaches, which leaves
-# 45 + 16 of the 81 blocks a single product would stream, for two products.
-# The partials are summed with carries in int32 and turned into float64 in
-# four exact float32 pieces, each scaled by the row's and the column's powers
+# the column's scale, as is what the cut leaves of either operand.  Each of
+# the 45 pairs that are left is one int8 product of an operator slice and a
+# field slice, and each partial is the int32 sum of its pairs' products,
+# carried (:func:`_carried`) where it is made: the compiler sums and carries
+# in the products' own output fusions, and no stack of partials is written
+# out and read back.  The carried partials are turned into float64 in four
+# exact float32 pieces, each scaled by the row's and the column's powers
 # of two in float32, and three float64 additions.  A sliced product states no
 # float64 multiply (the lone columns' one-row updates aside): the chip would
 # emulate each as a general two-word product over a whole field, where a
@@ -263,8 +265,9 @@ _SLICE_BITS = 7
 _GUARD_BITS = 10
 #: slices an operand is cut into, set by accuracy alone: 53 + 10 bits, 9 x 7
 SLICES = -(-(53 + _GUARD_BITS) // _SLICE_BITS)
-#: runs of the field's slices, each one int8 product (see above)
-_GROUPS = ((0, 5), (5, SLICES))
+#: the slice pairs ``(s, t)``, operator slice s and field slice t, that make
+#: the partials: ``s + t < SLICES``, each one int8 product (see above)
+PAIRS = tuple((d - t, t) for d in range(SLICES) for t in range(d + 1))
 #: the ``jax.jit`` name of the sliced product: one traced program for each
 #: signature, and what the step's ``sliced_products`` count reads
 SLICED_PRODUCT = "sliced_product"
@@ -273,8 +276,8 @@ SLICED_PRODUCT = "sliced_product"
 class SlicedOperator:
     """One float64 operator ``(r, K)``, or several applied side by side (a
     parity fold's two halves, a trapezoid block's strips), as the sliced
-    product reads them: ``toeplitz`` holds, for each run of
-    ``_GROUPS``, the int8 blocks ``(B, SLICES - t0, r, t1 - t0, K)``,
+    product reads them: ``digits`` holds the ``SLICES`` int8 digit
+    matrices ``(B, SLICES, r, K)`` of the operators, most significant first,
     ``scale`` the float64 ``(B, r)`` power of two of each row times 2^-14
     (the weight of the partial ``P_0``), ``balance`` the float32 ``(B, K)``
     power of two of each column relative to the largest, in [2^-48, 1] (0
@@ -288,10 +291,10 @@ class SlicedOperator:
     may be ten decades above the rest and would otherwise set the scale
     every other mode is cut to."""
 
-    __slots__ = ("toeplitz", "scale", "balance", "lone", "rows", "cols")
+    __slots__ = ("digits", "scale", "balance", "lone", "rows", "cols")
 
-    def __init__(self, toeplitz, scale, balance, lone, rows, cols):
-        self.toeplitz, self.scale, self.balance = toeplitz, scale, balance
+    def __init__(self, digits, scale, balance, lone, rows, cols):
+        self.digits, self.scale, self.balance = digits, scale, balance
         self.lone, self.rows, self.cols = lone, rows, cols
 
 
@@ -320,8 +323,7 @@ def slice_operator(mats):
     decays along it on even terms, and a column with one nonzero is left to
     ``lone``; then each
     row is scaled by the power of two that brings its largest element into
-    [1/4, 1/2), exactly, cut into ``SLICES`` digit matrices, laid out as the
-    block Toeplitz runs of ``_GROUPS``."""
+    [1/4, 1/2), exactly, and cut into ``SLICES`` digit matrices."""
     mats = [np.asarray(m, dtype=np.float64) for m in mats]
     rows = tuple(m.shape[0] for m in mats)
     cols = tuple(m.shape[1] for m in mats)
@@ -346,17 +348,10 @@ def slice_operator(mats):
         scale[i, : m.shape[0]] = np.ldexp(1.0, e + 1 - 2 * _SLICE_BITS)
         row = np.ldexp(1.0, e + 1)[:, None]
         digits[i, :, : m.shape[0], : m.shape[1]] = _digits((m / row)[None], np)
-    toeplitz = []
-    for t0, t1 in _GROUPS:
-        blocks = np.zeros((len(mats), SLICES - t0, r, t1 - t0, c), np.int8)
-        for d in range(t0, SLICES):
-            for t in range(t0, min(t1, d + 1)):
-                blocks[:, d - t0, :, t - t0, :] = digits[:, d - t]
-        toeplitz.append(blocks)
     # the rows' scales multiply float32 pieces (``_recombine``)
     if not np.all((scale >= 2.0**-126) & (scale < 2.0**127)):
         raise ValueError("an operator row's scale is outside float32's normal range")
-    return SlicedOperator(tuple(toeplitz), scale, balance, tuple(lone), rows, cols)
+    return SlicedOperator(digits, scale, balance, tuple(lone), rows, cols)
 
 
 def _column_scale(x, balance):
@@ -491,28 +486,45 @@ def _recombine(partials, scale, column, k: int):
     return total
 
 
-@partial(jax.jit, static_argnames=("lone", "rows"))
-def sliced_product(toeplitz, scale, balance, xs, lone, rows):
+def _partials(digits, field, row_major: bool = False):
+    """The int32 partials ``P_d = sum_(s + t = d) A_s X_t``, ``d <`` ``SLICES``,
+    of the operators' digits ``(B, SLICES, r, K)`` and the fields' ``(B,
+    SLICES, K, ...)``: one int8 product for each slice pair of ``PAIRS``,
+    summed in int32 (exact: any order).  ``row_major``: each partial is held
+    in the row-major layout its products write best.  Left free, the
+    compiler gives the partials, and so the products, the layout of a caller
+    that reads the output transposed (a product along a field's second
+    axis): the products then write their int32 outputs with the rows minor,
+    at 1.7 to 4 times the cycles of the same product written row-major.
+    Held, the transposition falls to the recombination (on a v5e at 513²
+    the step is 7 % shorter: PERF.md section 6)."""
+    lax = jax.lax
+    partials = [None] * SLICES
+    for s, t in PAIRS:
+        a = lax.index_in_dim(digits, s, axis=1, keepdims=False)
+        x = lax.index_in_dim(field, t, axis=1, keepdims=False)
+        p = lax.dot_general(a, x, (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.int32)
+        partials[s + t] = p if partials[s + t] is None else lax.add(partials[s + t], p)
+    if not row_major:
+        return partials
+    layout = Layout(tuple(range(partials[0].ndim)))
+    return [with_layout_constraint(p, layout) for p in partials]
+
+
+@partial(jax.jit, static_argnames=("lone", "rows", "row_major"))
+def sliced_product(digits, scale, balance, xs, lone, rows, row_major=False):
     """``[tensordot(A_b, x_b, axes=([1], [0])) for b]`` for the ``B``
-    float64 operators of a :class:`SlicedOperator` (``toeplitz``, ``scale``,
+    float64 operators of a :class:`SlicedOperator` (``digits``, ``scale``,
     ``balance``, ``lone``, ``rows``) and the float64 fields
     ``xs`` ``(K_b, ...)``: stacked (padded with zeros to one length),
-    balanced, sliced, multiplied run by run, recombined, the lone columns
-    added."""
+    balanced, sliced, multiplied pair by pair, recombined, the lone columns
+    added; ``row_major``: :func:`_partials`, the result the same bit for
+    bit."""
     lax = jax.lax
-    k = toeplitz[0].shape[-1]
+    k = digits.shape[-1]
     x = jnp.stack([lax.pad(v, 0.0, [(0, k - v.shape[0], 0)] + [(0, 0, 0)] * (v.ndim - 1)) for v in xs])
     field, column = _column_digits(x, balance)
-    partials = [None] * SLICES
-    for (t0, t1), blocks in zip(_GROUPS, toeplitz):
-        out = lax.dot_general(
-            blocks, lax.slice_in_dim(field, t0, t1, axis=1),
-            (((3, 4), (1, 2)), ((0,), (0,))), preferred_element_type=jnp.int32,
-        )  # (B, SLICES - t0, r, ...)
-        for d in range(t0, SLICES):
-            part = lax.index_in_dim(out, d - t0, axis=1, keepdims=False)
-            partials[d] = part if partials[d] is None else lax.add(partials[d], part)
-    y = _recombine(partials, scale, column, k)
+    y = _recombine(_partials(digits, field, row_major), scale, column, k)
     out = [lax.slice_in_dim(y[b], 0, r, axis=0) for b, r in enumerate(rows)]
     for b, row, col, value in lone:  # static: slices, no gather
         update = out[b][row : row + 1] + np.float64(value) * xs[b][col : col + 1]
@@ -520,18 +532,43 @@ def sliced_product(toeplitz, scale, balance, xs, lone, rows):
     return tuple(out)
 
 
-def sliced_products(jaxpr) -> int:
-    """The float64 products ``jaxpr`` states as sliced products, sub-programs
-    included (``utils/jit.equations``): each call of :func:`sliced_product`
-    counts its operators (the leading extent of ``scale``, which no ``vmap``
-    batches: it is a constant)."""
+def _sliced_calls(jaxpr):
+    """The :func:`sliced_product` calls of ``jaxpr``, sub-programs included
+    (``utils/jit.equations``): their invars are the operators' ``digits``,
+    ``scale``, ``balance``, then the fields."""
     from ..utils.jit import equations
 
-    return sum(
-        eqn.invars[len(_GROUPS)].aval.shape[0]  # scale
-        for eqn in equations(jaxpr)
-        if eqn.params.get("name") == SLICED_PRODUCT
-    )
+    return [eqn for eqn in equations(jaxpr) if eqn.params.get("name") == SLICED_PRODUCT]
+
+
+def sliced_products(jaxpr) -> int:
+    """The float64 products ``jaxpr`` states as sliced products: each call of
+    :func:`sliced_product` counts its operators (the leading extent of
+    ``scale``, which no ``vmap`` batches: it is a constant)."""
+    return sum(eqn.invars[1].aval.shape[0] for eqn in _sliced_calls(jaxpr))
+
+
+def int8_blocks(jaxpr) -> int:
+    """The int8 operator slices the sliced products of ``jaxpr`` stream, in
+    units of one slice ``(r, K)`` of one operator: each int8 ``dot_general``
+    of a :func:`sliced_product` call counts its operand made of the call's
+    ``digits`` (whichever side it is on) over the rows of ``scale`` times the
+    contraction of ``balance``, so that a call reads ``len(PAIRS)`` for each
+    of its operators."""
+    from jax.extend.core import Var
+
+    count = 0
+    for eqn in _sliced_calls(jaxpr):
+        (_, r), (_, k) = eqn.invars[1].aval.shape, eqn.invars[2].aval.shape
+        inner = eqn.params["jaxpr"].jaxpr
+        operator = {inner.invars[0]}  # the digits, and what is cut from them
+        for e in inner.eqns:
+            mine = [v for v in e.invars if isinstance(v, Var) and v in operator]
+            if mine and e.primitive.name == "dot_general":
+                count += sum(math.prod(v.aval.shape) // (r * k) for v in mine)
+            elif mine:
+                operator.update(e.outvars)
+    return count
 
 
 def sliced_f64_multiplies(jaxpr) -> int:
@@ -542,10 +579,8 @@ def sliced_f64_multiplies(jaxpr) -> int:
     from ..utils.jit import equations
 
     count = 0
-    for eqn in equations(jaxpr):
-        if eqn.params.get("name") != SLICED_PRODUCT:
-            continue
-        rank = eqn.invars[len(_GROUPS) + 2].aval.ndim  # a field
+    for eqn in _sliced_calls(jaxpr):
+        rank = eqn.invars[3].aval.ndim  # a field
         count += sum(
             inner.primitive.name == "mul"
             and inner.outvars[0].aval.dtype == np.float64
@@ -568,7 +603,7 @@ def _products(ops, xs, precision=None):
         # operators) is its real and imaginary parts, each a float64 field
         parts = [_products(ops, [x.real for x in xs]), _products(ops, [x.imag for x in xs])]
         return [jax.lax.complex(re, im) for re, im in zip(*parts)]
-    from ..parallel.mesh import LOCAL, constrain
+    from ..parallel.mesh import LOCAL, active_mesh, constrain
 
     # under a mesh the contracted axis of a field is whole on a device and
     # the other is cut: stated, so that the digits and partials keep that
@@ -578,7 +613,11 @@ def _products(ops, xs, precision=None):
     manual = any(getattr(jax.typeof(x), "vma", None) for x in xs)
     pin = lambda v: v if manual or v.ndim < 2 else constrain(v, LOCAL[0])  # noqa: E731
     xs = tuple(pin(x.astype(jnp.float64)) for x in xs)
-    out = sliced_product(ops.toeplitz, ops.scale, ops.balance, xs, lone=ops.lone, rows=ops.rows)
+    # the partials are held row-major (:func:`_partials`) where no mesh is
+    # active: the partitioner does not split a layout constraint, and under
+    # a mesh it gathers every held partial
+    out = sliced_product(ops.digits, ops.scale, ops.balance, xs, lone=ops.lone, rows=ops.rows,
+                         row_major=active_mesh() is None and not manual)
     return [pin(y) for y in out]
 
 
@@ -619,7 +658,7 @@ class _Place:
     def _slice(self, mats):
         op = slice_operator(mats)
         with jax.ensure_compile_time_eval():
-            op.toeplitz = tuple(jnp.asarray(t) for t in op.toeplitz)
+            op.digits = jnp.asarray(op.digits)
             op.scale = self.plain(op.scale)
             op.balance = jnp.asarray(op.balance, jnp.float32)
         return op

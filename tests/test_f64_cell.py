@@ -31,7 +31,7 @@ import pytest
 from benchmark import check, run
 from benchmark.drivers import interval_f64
 from benchmark.ic import smooth_fields
-from benchmark.layer_metrics import f64_products_per_step, sliced_f64_multiplies_per_step
+from benchmark.layer_metrics import f64_products_per_step, int8_blocks_per_step, sliced_f64_multiplies_per_step
 from rustpde_mpi_tpu import Navier2D, config
 from rustpde_mpi_tpu.ops import folded as folded_ops
 from rustpde_mpi_tpu.telemetry import FlightRecorder
@@ -185,10 +185,12 @@ def test_span_counts_one_steps_products_by_operand_type(monkeypatch, ring, fold_
     args = ttracing.spans("model.update_n")[-1][-1]
     # on the TPU path every float64 product is a sliced product (ops/folded.py):
     # none is left to XLA's float64 dot; a parity fold's two halves are one
-    # sliced product, which states one int8 product for each run of slices
+    # sliced product, which states one int8 product for each slice pair and
+    # streams that many operator slices for each of its operators
     assert args["sliced_products"] == products
     calls = products // 2 if folded else products
-    assert args["int8_products"] == len(folded_ops._GROUPS) * calls <= folded_ops.SLICES * products
+    assert args["int8_products"] == len(folded_ops.PAIRS) * calls
+    assert args["int8_blocks"] == len(folded_ops.PAIRS) * products
     assert args["f64_products"] == 0
     assert args["f32_products"] == 0
     assert args["sliced_f64_multiplies"] == 0
@@ -221,6 +223,27 @@ def test_sliced_f64_multiplies_reader(monkeypatch, ring):
     assert sliced_f64_multiplies_per_step.read({}, {"traced_dispatches": 2}) == 63.0
     ring.add_complete("model.update_n", ring.now_us(), 5.0, {"id": 9, "parent": None, "steps": 8})
     assert sliced_f64_multiplies_per_step.read({}, {"traced_dispatches": 1}) is None
+
+
+def test_int8_blocks_reader(monkeypatch, ring):
+    """``int8_blocks_per_step`` reads the mean of the span's count over the
+    traced dispatches: 45 operator slices for each sliced operator of the
+    step; nothing where a traced span lacks the count (the parent commit's),
+    or where the ring holds fewer spans than were traced."""
+    monkeypatch.setenv("RUSTPDE_FORCE_TPU_PATH", "1")
+    model = Navier2D.new_confined(17, 17, RA, PR, DT, ASPECT, "rbc")
+    model.init_random(0.1, seed=0)
+    model.update_n(2)
+    model.update_n(4)
+    blocks = 45 * model._step_products["sliced_products"]
+    assert blocks > 0
+    assert int8_blocks_per_step.read({}, {"traced_dispatches": 2}) == blocks
+    assert int8_blocks_per_step.read({}, {"traced_dispatches": 3}) is None
+    args = {"parent": None, "steps": 8, "int8_blocks": blocks + 2}
+    ring.add_complete("model.update_n", ring.now_us(), 5.0, dict(args, id=7))
+    assert int8_blocks_per_step.read({}, {"traced_dispatches": 2}) == blocks + 1
+    ring.add_complete("model.update_n", ring.now_us(), 5.0, {"id": 9, "parent": None, "steps": 8})
+    assert int8_blocks_per_step.read({}, {"traced_dispatches": 1}) is None
 
 
 def test_products_are_counted_inside_nested_programs():

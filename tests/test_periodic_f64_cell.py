@@ -27,6 +27,7 @@ import json
 import os
 import subprocess
 import sys
+import weakref
 
 import jax
 import pytest
@@ -34,7 +35,7 @@ import pytest
 from benchmark import check, run
 from benchmark.drivers import periodic_interval_f64
 from benchmark.ic_periodic import smooth_periodic_fields
-from rustpde_mpi_tpu import Navier2D, config
+from rustpde_mpi_tpu import Navier2D, bases, config
 from rustpde_mpi_tpu.parallel.mesh import make_mesh
 from rustpde_mpi_tpu.telemetry import FlightRecorder
 from rustpde_mpi_tpu.telemetry import tracing as ttracing
@@ -76,6 +77,10 @@ def model_at(monkeypatch, nx, ny, layout):
     if layout == "cpu":
         return Navier2D.new_periodic(nx, ny, RA, PR, DT, ASPECT, "rbc")
     monkeypatch.setenv("RUSTPDE_FORCE_TPU_PATH", "1")
+    # a base cache of its own: a ``Base`` keeps the operators it built, so a
+    # CPU-path model of the same size still alive would hand back its float64
+    # dots in place of sliced products
+    monkeypatch.setattr(bases, "_BASE_CACHE", weakref.WeakValueDictionary())
     monkeypatch.delenv("RUSTPDE_SEP", raising=False)
     model = Navier2D.new_periodic(nx, ny, RA, PR, DT, ASPECT, "rbc", mesh=make_mesh(jax.devices()[:4]))
     assert model.temp_space.bases[0].kind.is_split and model._manual_poisson is None

@@ -192,7 +192,8 @@ def test_launches_counts_leaves_copied_and_buckets(ring, kind, n):
 @pytest.mark.parametrize("folded", [True, False])
 def test_update_n_spans_count_the_parity_folds_reverses(ring, monkeypatch, fold_gate, folded, kind):
     """``reverses`` beside ``f32_products`` / ``f64_products`` /
-    ``sliced_products`` (the float64 products of the TPU path, ops/folded.py):
+    ``sliced_products`` (the float64 products of the TPU path, ops/folded.py)
+    and ``int8_blocks`` (the operator slices those stream):
     the ``rev`` equations of one traced step, the member step's on the
     ensemble's span.
     On the matmul-transform path a confined step applies 20 transforms
@@ -213,6 +214,9 @@ def test_update_n_spans_count_the_parity_folds_reverses(ring, monkeypatch, fold_
     assert args["reverses"] == (20 if folded else 0)
     products = args["f64_products"] + args["f32_products"] + args["sliced_products"]
     assert products == (100 if folded else 100 - 20 - 24)
+    # the int8 operator slices the sliced products stream: one for each slice
+    # pair (45) of each sliced operator, none where no product is sliced
+    assert args["int8_blocks"] == 45 * args["sliced_products"]
     assert args["shared_syntheses"] == 2
 
 
@@ -639,4 +643,4 @@ def test_benchmark_selfcheck_passes(capsys):
     from benchmark import selfcheck
 
     assert selfcheck.main([]) == 0
-    assert "8 cells, 26 readers agree" in capsys.readouterr().out
+    assert "8 cells, 27 readers agree" in capsys.readouterr().out

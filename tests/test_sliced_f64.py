@@ -28,7 +28,7 @@ from rustpde_mpi_tpu import Navier2D, bases, config, solver
 from rustpde_mpi_tpu.models.swift_hohenberg import SwiftHohenberg2D
 from rustpde_mpi_tpu.ops import chebyshev as chb
 from rustpde_mpi_tpu.ops import folded
-from rustpde_mpi_tpu.utils.jit import equations
+from rustpde_mpi_tpu.utils.jit import dot_generals_by_operand, equations
 
 pytestmark = pytest.mark.skipif(not config.X64, reason="float64 products")
 
@@ -183,16 +183,21 @@ def _multiplied_sum(partials, scale, column, k):
     return total * (rows * column.astype(jnp.float64))
 
 
-def _multiplied_product(monkeypatch):
-    """:func:`folded.sliced_product` with its scalings as float64 products,
-    traced afresh: a new function object of the same code and name (jit's
-    trace cache keys on the function), whose module names are the two
-    above while ``monkeypatch`` holds them."""
-    monkeypatch.setattr(folded, "_column_digits", _multiplied_digits)
-    monkeypatch.setattr(folded, "_recombine", _multiplied_sum)
+def _retraced(monkeypatch, **names):
+    """:func:`folded.sliced_product` traced afresh: a new function object of
+    the same code and name (jit's trace cache keys on the function), whose
+    module names ``names`` replace while ``monkeypatch`` holds them."""
+    for name, value in names.items():
+        monkeypatch.setattr(folded, name, value)
     f = folded.sliced_product.__wrapped__
     fresh = types.FunctionType(f.__code__, f.__globals__, f.__name__, f.__defaults__, f.__closure__)
-    return jax.jit(fresh, static_argnames=("lone", "rows"))
+    return jax.jit(fresh, static_argnames=("lone", "rows", "row_major"))
+
+
+def _multiplied_product(monkeypatch):
+    """:func:`folded.sliced_product` with its scalings as float64 products
+    (the two functions above)."""
+    return _retraced(monkeypatch, _column_digits=_multiplied_digits, _recombine=_multiplied_sum)
 
 
 def _both_forms(op, xs, monkeypatch):
@@ -201,11 +206,18 @@ def _both_forms(op, xs, monkeypatch):
     both: their one-row update is the same code in either, and the CPU's
     compiler contracts it into a fused multiply-add with whichever product
     it meets."""
-    args = (op.toeplitz, op.scale, op.balance, tuple(jnp.asarray(x) for x in xs))
+    args = (op.digits, op.scale, op.balance, tuple(jnp.asarray(x) for x in xs))
     got = folded.sliced_product(*args, lone=(), rows=op.rows)
     with monkeypatch.context() as m:
         want = _multiplied_product(m)(*args, lone=(), rows=op.rows)
     return [np.asarray(y) for y in got], [np.asarray(y) for y in want]
+
+
+def _stacked(op, xs):
+    """The fields ``xs`` stacked as the sliced product stacks them, padded
+    with zeros to the operators' contraction."""
+    k = op.digits.shape[-1]
+    return np.stack([np.pad(v, [(0, k - v.shape[0])] + [(0, 0)] * (v.ndim - 1)) for v in xs])
 
 
 def _normal_pieces(op, xs):
@@ -215,8 +227,7 @@ def _normal_pieces(op, xs):
     at most 2^62; on the output side the smallest piece is 2^-56 of the
     row's and column's scales (the last run's weight), normal wherever they
     multiply to at least 2^-70."""
-    k = op.toeplitz[0].shape[-1]
-    x = np.stack([np.pad(v, [(0, k - v.shape[0])] + [(0, 0)] * (v.ndim - 1)) for v in xs])
+    x = _stacked(op, xs)
     _, scale = folded._column_scale(jnp.asarray(x), jnp.asarray(op.balance))
     column = 1 / np.asarray(scale, np.float64)
     rows = np.asarray(op.scale).reshape(op.scale.shape + (1,) * (x.ndim - 2))
@@ -322,7 +333,7 @@ def test_a_sliced_product_states_no_float64_multiply(monkeypatch):
     assert len(muls) == len(op.lone) and all(e.outvars[0].aval.shape[0] == 1 for e in muls)
     with monkeypatch.context() as m:
         fresh = _multiplied_product(m)
-        old = jax.make_jaxpr(lambda *xs: fresh(op.toeplitz, op.scale, op.balance, xs,
+        old = jax.make_jaxpr(lambda *xs: fresh(op.digits, op.scale, op.balance, xs,
                                                lone=op.lone, rows=op.rows))(*xs).jaxpr
     assert folded.sliced_f64_multiplies(old) == 3
     assert folded._whole_bound(2 * N - 1) < 2**24
@@ -333,6 +344,128 @@ def test_a_sliced_product_states_no_float64_multiply(monkeypatch):
     x = np.random.default_rng(6).uniform(-1.0, 1.0, (3000, 4))
     (got,), (want,) = _both_forms(op, [x], monkeypatch)
     assert np.array_equal(_bits(got), _bits(want))
+
+
+# -- one int8 product per slice pair ---------------------------------------------------
+
+#: the runs of the field's slices in the block-Toeplitz form, the reference
+RUNS = ((0, 5), (5, folded.SLICES))
+
+
+def _toeplitz_partials(digits, field, row_major=False):
+    """The partials as the block-Toeplitz form made them (in the layout the
+    compiler chose: ``row_major`` is not read): for each run of
+    the field's slices (``RUNS``) the operator's slices laid out as a block
+    Toeplitz matrix (block (d, t) holds slice d - t, zero above the diagonal)
+    and ONE int8 product over the rows the run reaches, whose stacked int32
+    outputs are summed; 45 + 16 of the 81 blocks streamed."""
+    zero = jnp.zeros_like(digits[:, 0])
+    partials = [None] * folded.SLICES
+    for t0, t1 in RUNS:
+        blocks = jnp.stack([jnp.stack([digits[:, d - t] if t <= d else zero for t in range(t0, t1)], 2)
+                            for d in range(t0, folded.SLICES)], 1)  # (B, SLICES - t0, r, t1 - t0, K)
+        out = jax.lax.dot_general(blocks, field[:, t0:t1], (((3, 4), (1, 2)), ((0,), (0,))),
+                                  preferred_element_type=jnp.int32)
+        for d in range(t0, folded.SLICES):
+            partials[d] = out[:, d - t0] if partials[d] is None else partials[d] + out[:, d - t0]
+    return partials
+
+
+def _sliced_groups(impl):
+    """The host matrices of each sliced product an impl states, as ``place``
+    takes them: :func:`_product_groups`, but a trapezoid block's strips
+    together, one sliced product as in the step."""
+    blocks = getattr(impl, "blocks", None)
+    if blocks is not None and not all(b.kind == "plain" for b in blocks):
+        return [g for b in blocks for g in _sliced_groups(b)]
+    if getattr(impl, "mats", None) is not None:
+        return [list(impl.mats)]
+    return _product_groups(impl)
+
+
+#: the operator kinds by the fields they meet, and one contraction past the
+#: split of ``_whole_bound`` (3000 terms: the integer in two float32 words)
+PAIR_CASES = [(kind, field) for kind in KINDS
+              for field in ("physical", "spectral", "columns 1e-30 to 1e22")] + [("wide", "physical")]
+
+
+@pytest.mark.parametrize("kind, field", PAIR_CASES)
+def test_pair_products_are_bitwise_the_block_toeplitz_runs(kind, field, monkeypatch):
+    """One int8 product for each slice pair, each partial summed where its
+    pairs' products are made, gives the block-Toeplitz runs' float64 result
+    bit for bit (int32 sums are exact in any order), lone columns included,
+    on a parity fold's pair, a trapezoid's strips in one product, columns
+    from 1e-30 to 1e22 and a contraction past the integer's split, with its
+    partials held row-major or left to the compiler (``row_major``); and each
+    partial is the int64 sum of ``A_s X_t`` over ``s + t = d``."""
+    if kind == "wide":
+        groups = [[np.random.default_rng(5).standard_normal((7, 3000))]]
+        assert folded._whole_bound(3000) >= 2**24
+    else:
+        mat, sep_in, sep_out, keep = _cell_operators()[kind]
+        impl = folded._detect(np.asarray(mat), sep_in, sep_out, keep, 8)
+        assert ("trapezoid" in impl.kind) == (kind == "trapezoid"), impl.kind
+        groups = _sliced_groups(impl)  # a trapezoid's strips in one product
+    rng = np.random.default_rng(abs(hash((kind, field, "pairs"))) % 2**32)
+    columns = np.logspace(-30, 22, 6) if field.startswith("columns") else None
+    place = folded._Place(jnp.asarray, sliced=True)
+    lone = 0
+    for mats in groups:
+        op = place.group(*mats)
+        lone += len(op.lone)
+        xs = tuple(jnp.asarray(_field(field if field == "physical" else "spectral", m.shape[1], rng, columns))
+                   for m in mats)
+        args = (op.digits, op.scale, op.balance, xs)
+        with monkeypatch.context() as m:
+            want = _retraced(m, _partials=_toeplitz_partials)(*args, lone=op.lone, rows=op.rows)
+        field_digits, _ = folded._column_digits(jnp.asarray(_stacked(op, xs)), op.balance)
+        a, x = np.asarray(op.digits, np.int64), np.asarray(field_digits, np.int64)
+        for row_major in (False, True):
+            got = folded.sliced_product(*args, lone=op.lone, rows=op.rows, row_major=row_major)
+            for y, oracle in zip(got, want):
+                assert np.array_equal(_bits(y), _bits(oracle)), (kind, field, row_major)
+            partials = jax.jit(folded._partials, static_argnums=2)(op.digits, field_digits, row_major)
+            for d, p in enumerate(partials):
+                exact = sum(np.einsum("brk,bkn->brn", a[:, d - t], x[:, t]) for t in range(d + 1))
+                assert p.dtype == jnp.int32 and np.array_equal(np.asarray(p), exact), (kind, field, d)
+    assert lone > 0 if kind in ("fastdiag_bwd", "projection_gradient") else True
+
+
+def test_one_call_states_an_int8_product_per_slice_pair(monkeypatch):
+    """A sliced product states one int8 ``dot_general`` for each of the 45
+    slice pairs ``s + t < SLICES`` and streams 45 operator slices for each of
+    its operators (``int8_blocks``); the block-Toeplitz runs stated two and
+    streamed 61."""
+    assert len(folded.PAIRS) == 45 == len(set(folded.PAIRS))
+    assert all(s + t < folded.SLICES for s, t in folded.PAIRS)
+    mat, sep_in, sep_out, keep = _cell_operators()["helmholtz"]
+    (mats,) = _product_groups(folded._detect(np.asarray(mat), sep_in, sep_out, keep, 8))
+    op = folded._Place(jnp.asarray, sliced=True).group(*mats)
+    xs = tuple(jax.ShapeDtypeStruct((m.shape[1], N), jnp.float64) for m in mats)
+    jaxpr = jax.make_jaxpr(lambda *xs: folded._products(op, xs))(*xs).jaxpr
+    assert dot_generals_by_operand(jaxpr) == {"int8": 45}
+    assert folded.sliced_products(jaxpr) == len(mats) == 2
+    assert folded.int8_blocks(jaxpr) == 45 * len(mats)
+    with monkeypatch.context() as m:
+        fresh = _retraced(m, _partials=_toeplitz_partials)
+        runs = jax.make_jaxpr(lambda *xs: fresh(op.digits, op.scale, op.balance, xs,
+                                                lone=op.lone, rows=op.rows))(*xs).jaxpr
+    assert dot_generals_by_operand(runs) == {"int8": len(RUNS)}
+    assert folded.int8_blocks(runs) == 61 * len(mats)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_an_operator_is_kept_as_its_nine_slices(kind):
+    """``slice_operator`` keeps each operator as its ``SLICES`` int8 digit
+    matrices, one byte an element a slice: 9 slices where the block-Toeplitz
+    runs held 61 blocks of the same size."""
+    mat, sep_in, sep_out, keep = _cell_operators()[kind]
+    for mats in _sliced_groups(folded._detect(np.asarray(mat), sep_in, sep_out, keep, 8)):
+        op = folded.slice_operator(mats)
+        r, k = max(m.shape[0] for m in mats), max(m.shape[1] for m in mats)
+        assert op.digits.dtype == np.int8 and op.digits.shape == (len(mats), folded.SLICES, r, k)
+        assert op.digits.nbytes == len(mats) * 9 * r * k
+        assert np.abs(op.digits).max() <= 64
 
 
 # -- whole models on the forced TPU path ----------------------------------------------
@@ -441,9 +574,11 @@ def test_float32_programs_trace_as_before(float32_programs):
 def test_cell_step_states_its_float64_products_as_sliced_products(monkeypatch, float32_programs):
     """At 513 x 513 (a CPU count, nothing runs): the float64 step states as
     sliced products the products the float32 step states as float32 dots,
-    leaves none to XLA's float64 dot, and needs at most ``SLICES`` int8
-    products for each; its sliced products state no float64 multiply, where
-    with their scalings as float64 products they stated three a call."""
+    leaves none to XLA's float64 dot, states one int8 product for each slice
+    pair of each of its 42 calls and streams 45 operator slices for each
+    sliced operator (61 in the block-Toeplitz runs before); its sliced
+    products state no float64 multiply, where with their scalings as float64
+    products they stated three a call."""
     monkeypatch.setenv("RUSTPDE_FORCE_TPU_PATH", "1")
     monkeypatch.setattr(bases, "_BASE_CACHE", weakref.WeakValueDictionary())
     build = lambda: Navier2D.new_confined(N, N, 1e5, 1.0, 0.01, 1.0, "rbc")._step_products  # noqa: E731
@@ -451,12 +586,14 @@ def test_cell_step_states_its_float64_products_as_sliced_products(monkeypatch, f
     f32 = float32_programs["products 513"]
     assert f32["f64_products"] == 0 and f32["f32_products"] == 84
     assert f32["sliced_products"] == f32["int8_products"] == f32["sliced_f64_multiplies"] == 0
+    assert f32["int8_blocks"] == 0
     assert counts["sliced_products"] == f32["f32_products"]
     assert counts["f64_products"] == counts["f32_products"] == 0
-    assert 0 < counts["int8_products"] <= folded.SLICES * counts["sliced_products"]
+    calls = counts["int8_products"] // len(folded.PAIRS)
+    assert counts["int8_products"] == len(folded.PAIRS) * calls == 45 * 42
+    assert counts["int8_blocks"] == len(folded.PAIRS) * counts["sliced_products"] == 45 * 84
     assert counts["reverses"] == f32["reverses"]
     assert counts["sliced_f64_multiplies"] == 0
-    calls = counts["int8_products"] // len(folded._GROUPS)
     monkeypatch.setattr(folded, "sliced_product", _multiplied_product(monkeypatch))
     assert build()["sliced_f64_multiplies"] == 3 * calls == 126
 
